@@ -186,17 +186,19 @@ class DataFeed:
 def make_parser(config: DataFeedConfig, parse_ins_id: bool = False,
                 parse_logkey_: bool = False, use_native: bool = True,
                 input_table=None):
-    """Return the native C++ parser when built, else the python fallback.
-    String (InputTable) slots force the python parser — the table's
-    string→index map lives in the python process."""
+    """Return the native C++ parser when built, else the python fallback
+    (native/build.py warns and sets ``native.lib_ok`` when it is the
+    fallback).  String (InputTable) slots force the python parser — the
+    table's string→index map lives in the python process."""
     if use_native and not config.string_slots:
+        from paddlebox_tpu.native import build
         try:
             from paddlebox_tpu.native import slot_parser as native_parser
             if native_parser.available():
                 return native_parser.NativeSlotParser(
                     config, parse_ins_id, parse_logkey_)
-        except Exception:
-            pass
+        except Exception as e:  # noqa: BLE001 — the Python parser serves
+            build.warn_fallback("slot_parser", e)
     return SlotParser(config, parse_ins_id, parse_logkey_,
                       input_table=input_table)
 

@@ -54,6 +54,23 @@ def init_distributed(coordinator: Optional[str] = None,
     return rank
 
 
+def one_chip_per_rank(env: Dict[str, str], rank: int, nproc: int) -> None:
+    """A TPU chip belongs to one process.  One worker takes every chip of
+    the host (the layout for a mesh: a single process drives all of them).
+    Several workers on one host get one chip each, fixed in the
+    environment before JAX starts: rank r sees chip r alone, as an
+    isolated one-chip device, and a rank past the host's last chip fails
+    at backend init instead of contending for a chip already held.  Left
+    alone: a world pinned to the CPU, and an operator's own
+    ``TPU_VISIBLE_CHIPS``."""
+    if nproc <= 1 or env.get("JAX_PLATFORMS") == "cpu" \
+            or "TPU_VISIBLE_CHIPS" in env:
+        return
+    env["TPU_VISIBLE_CHIPS"] = str(rank)
+    env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
+    env["TPU_PROCESS_BOUNDS"] = "1,1,1"
+
+
 class ClusterScraper:
     """Supervisor-side cluster aggregation: a periodic thread pulling
     every worker's ``/statz?raw=1``, folding the live scrapes through
@@ -155,6 +172,22 @@ class ClusterScraper:
                 "latest": latest[0]["stats"] if latest else {}}
 
 
+def _stop_workers(procs, grace_s: float = 15.0) -> None:
+    """SIGTERM every live worker and WAIT for it to be gone (SIGKILL past
+    the grace): a worker that outlives the launcher still holds its chip,
+    and whatever the operator starts next finds the device busy."""
+    live = [q for q in procs if q is not None and q.poll() is None]
+    for q in live:
+        q.send_signal(signal.SIGTERM)
+    deadline = time.monotonic() + grace_s
+    for q in live:
+        try:
+            q.wait(timeout=max(0.0, deadline - time.monotonic()))
+        except subprocess.TimeoutExpired:
+            q.kill()
+            q.wait()
+
+
 def launch(script: str, script_args: List[str], nproc: int,
            coordinator: str = "127.0.0.1:12355",
            max_restarts: int = 0, log_dir: str = "",
@@ -179,6 +212,7 @@ def launch(script: str, script_args: List[str], nproc: int,
             "PBOX_WORLD_SIZE": str(nproc),
             "PBOX_COORDINATOR": coordinator,
         })
+        one_chip_per_rank(env, rank, nproc)
         if obs_port:
             # pboxlint: disable-next=PB203 -- env export to spawned workers
             env["FLAGS_obs_port"] = str(obs_port + rank)
@@ -246,11 +280,8 @@ def launch(script: str, script_args: List[str], nproc: int,
                     alive += 1
                 elif ret != 0:
                     # fatal: kill the rest (≙ controller abort)
-                    exit_code = ret
-                    for q in procs:
-                        if q is not None and q.poll() is None:
-                            q.send_signal(signal.SIGTERM)
-                    return exit_code
+                    _stop_workers(procs)
+                    return ret
                 else:
                     procs[r] = None
             if alive == 0:
@@ -258,9 +289,7 @@ def launch(script: str, script_args: List[str], nproc: int,
             obs_scrape()
             time.sleep(0.2)
     except KeyboardInterrupt:
-        for q in procs:
-            if q is not None and q.poll() is None:
-                q.send_signal(signal.SIGTERM)
+        _stop_workers(procs)
         return 130
     finally:
         obs_scrape(final=True)
@@ -332,6 +361,7 @@ def launch_elastic(script: str, script_args: List[str], nproc: int,
             "PBOX_ELASTIC_DIR": elastic_dir,
             "PBOX_ELASTIC_GEN": str(generation),
         })
+        one_chip_per_rank(env, rank, world_size)
         if obs_port:
             # rank-based, so ports are stable across generations
             # pboxlint: disable-next=PB203 -- env export to spawned workers
@@ -372,15 +402,7 @@ def launch_elastic(script: str, script_args: List[str], nproc: int,
         return val
 
     def stop_all(procs):
-        for p in procs.values():
-            if p.poll() is None:
-                p.send_signal(signal.SIGTERM)
-        deadline = time.time() + 10
-        for p in procs.values():
-            while p.poll() is None and time.time() < deadline:
-                time.sleep(0.05)
-            if p.poll() is None:
-                p.kill()
+        _stop_workers(list(procs.values()), grace_s=10.0)
 
     procs = {r: spawn(r, world, gen) for r in range(world)}
     scraper: Optional[ClusterScraper] = None

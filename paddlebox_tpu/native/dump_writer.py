@@ -42,9 +42,10 @@ def _load():
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
                 ctypes.c_void_p, ctypes.c_void_p,
                 ctypes.c_longlong, ctypes.c_longlong]
-        except (OSError, AttributeError):
-            # a stale prebuilt .so without this symbol must degrade to
-            # the Python fallback, not crash the one caller that has one
+        except (OSError, AttributeError) as e:
+            # a library without this symbol must degrade to the Python
+            # fallback, not crash the one caller that has one
+            build.warn_fallback("dump_writer", e)
             return None
         _lib = lib
         return _lib

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddlebox_tpu import flags
+from paddlebox_tpu.utils.monitor import stat_observe
 
 log = logging.getLogger(__name__)
 
@@ -76,23 +77,22 @@ def best_mode(take_rows: int, sort_n: int, w: int, backend: str,
 @functools.lru_cache(maxsize=None)
 def _measure(take_rows: int, sort_n: int, w: int, backend: str,
              dtype: str = "float32") -> str:
+    t0 = time.perf_counter()
     rng = np.random.default_rng(0)
     src = jnp.asarray(rng.normal(0, 1, (sort_n, w)).astype(
         np.float32)).astype(dtype)
     idx = jnp.asarray(
         rng.integers(0, sort_n, take_rows).astype(np.int32))
     dest = jnp.asarray(rng.permutation(sort_n).astype(np.int32))
+    # both lowerings must compile on the live backend: a failure here is
+    # a finding about the device, so it propagates (a silent "take" would
+    # hide it behind the slower crossing)
     t_take = _bench_once(lambda v, i: jnp.take(v, i, axis=0), (src, idx))
-    try:
-        t_sort = _bench_once(
-            lambda v, d: permute_by_dest(tuple(v.T), d), (src, dest))
-    except Exception as e:  # noqa: BLE001 — a lowering failure on an
-        # unusual backend must degrade to the safe default, not kill the
-        # step build (the sort mode is a pure optimization)
-        log.warning("crossing auto-tune: sort lowering failed (%s: %s) — "
-                    "using take", type(e).__name__, e)
-        return "take"
+    t_sort = _bench_once(
+        lambda v, d: permute_by_dest(tuple(v.T), d), (src, dest))
     mode = "sort" if t_sort < t_take else "take"
+    # two compiles + timed runs per geometry, paid at step-build time
+    stat_observe("ops.crossing.autotune_s", time.perf_counter() - t0)
     log.info("crossing auto-tune (take_rows=%d sort_n=%d w=%d %s): "
              "take=%.2fms sort=%.2fms -> %s", take_rows, sort_n, w, backend,
              t_take * 1e3, t_sort * 1e3, mode)

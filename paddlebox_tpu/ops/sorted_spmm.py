@@ -42,6 +42,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 512     # occurrences per work-item (lane dim of payload blocks)
 TILE = 2048     # table rows per tile (lane dim of table blocks)
+# stable kernel names: the Mosaic custom calls carry them (kernel_name), so
+# a trace reduction or chip_smoke.py finds the kernels after a refactor
+GATHER_KERNEL = "sorted_spmm_gather"
+SCATTER_KERNEL = "sorted_spmm_scatter"
 
 
 def _round_up(n: int, a: int) -> int:
@@ -257,6 +261,7 @@ def gather_sorted(table_fm: jnp.ndarray, rows2d: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((w, dims.p_pad), jnp.float32),
         interpret=interpret,
+        name=GATHER_KERNEL,
     )(chunk_ids, tile_ids, first_g, rows2d, table_fm)
 
 
@@ -283,6 +288,7 @@ def scatter_add_sorted(payload_fm: jnp.ndarray, rows2d: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((w, dims.n_kernel), jnp.float32),
         interpret=interpret,
+        name=SCATTER_KERNEL,
     )(chunk_ids, tile_ids, first_s, rows2d, payload_fm)
 
 

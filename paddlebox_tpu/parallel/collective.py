@@ -27,12 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
-try:
-    from jax import shard_map
-    _SHARD_MAP_KW = "check_vma"
-except ImportError:     # pre-0.6 jax ships it under experimental
-    from jax.experimental.shard_map import shard_map
-    _SHARD_MAP_KW = "check_rep"
+from jax import shard_map
 
 from paddlebox_tpu import flags
 from paddlebox_tpu.utils.monitor import stat_add, stat_observe
@@ -221,7 +216,6 @@ def shift_right(x, axis: str, axis_size: int):
 def shard_mapped(mesh, in_specs, out_specs, check_vma: bool = False):
     """Decorator shorthand for shard_map over the framework mesh."""
     def wrap(fn):
-        kw = {_SHARD_MAP_KW: check_vma}
         return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, **kw)
+                         out_specs=out_specs, check_vma=check_vma)
     return wrap

@@ -71,6 +71,7 @@ class PassKeyMapper:
     def _native_hash(self):
         if not self._native_tried:
             self._native_tried = True
+            from paddlebox_tpu.native import build
             try:
                 from paddlebox_tpu.native import hash_map
                 if hash_map.available():
@@ -79,7 +80,8 @@ class PassKeyMapper:
                     # the searchsorted contract exactly
                     h.upsert(self.sorted_keys)
                     self._native = h
-            except Exception:
+            except Exception as e:  # noqa: BLE001 — searchsorted serves
+                build.warn_fallback("pass_key_hash", e)
                 self._native = None
         return self._native
 

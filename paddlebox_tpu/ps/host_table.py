@@ -127,6 +127,7 @@ class _Shard:
         with self.lock:
             if not self._hash_tried:
                 self._hash_tried = True
+                from paddlebox_tpu.native import build
                 try:
                     from paddlebox_tpu.native import hash_map
                     if hash_map.available():
@@ -134,7 +135,8 @@ class _Shard:
                         if self._len:
                             h.upsert(self.keys)
                         self._hash = h
-                except Exception:
+                except Exception as e:  # noqa: BLE001 — sorted view serves
+                    build.warn_fallback("host_table_hash", e)
                     self._hash = None
             return self._hash
 

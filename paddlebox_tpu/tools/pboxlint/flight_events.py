@@ -5,13 +5,13 @@
          * built dynamically (f-string / ``+`` concatenation) from a
            part that is not a KNOWN BOUNDED FIELD — ``counts()``,
            ``events(kind=...)`` and every postmortem group by kind, so
-           an unbounded kind (a rid, a path, a key) shreds the taxonomy
+           an unbounded kind (a rid, a path, a key) shreds the vocabulary
            into one-off buckets and defeats ring triage, or
          * a literal that is not a lowercase identifier
            (``[a-z0-9_]``) — mixed-case/dotted kinds fracture the
            closed event vocabulary that /flightz filters key on, or
          * a lowercase literal that is not in :data:`KNOWN_KINDS` — the
-           taxonomy is CLOSED: a new event kind is a deliberate
+           vocabulary is CLOSED: a new event kind is a deliberate
            vocabulary change (postmortem tooling, /flightz dashboards
            and the ``?kind=`` filters all key on it), so it lands by
            adding the name here in the same change, not by ad-hoc
@@ -40,7 +40,7 @@ from paddlebox_tpu.tools.pboxlint.metric_names import (_BOUNDED_FIELDS,
 _KIND_OK = re.compile(r"[a-z0-9_]*\Z")
 _FLIGHT_MOD = "paddlebox_tpu.utils.flight"
 
-# The closed event-kind taxonomy.  Every whole-literal kind passed to
+# The closed event-kind vocabulary.  Every whole-literal kind passed to
 # flight.record must be one of these; adding an event kind means adding
 # it HERE in the same change (the /flightz ?kind= filters, postmortem
 # groupers and dashboard queries all key on this vocabulary).
@@ -107,7 +107,7 @@ def _findings_for_kind(mod: Module, call: ast.Call,
         out.append(Finding(
             mod.path, call.lineno, "PB206",
             f"{dotted_name(call.func) or '<call>'}(...) flight event kind "
-            f"{reason} — kinds are the closed taxonomy /flightz filters "
+            f"{reason} — kinds are the closed vocabulary /flightz filters "
             f"and postmortems group by; unbounded values go in event "
             f"fields, bounded dynamic parts are {sorted(_BOUNDED_FIELDS)}, "
             f"or suppress with a reason"))
@@ -117,7 +117,7 @@ def _findings_for_kind(mod: Module, call: ast.Call,
             flag(f"literal {arg.value!r} is not a lowercase identifier")
         elif arg.value not in KNOWN_KINDS:
             flag(f"literal {arg.value!r} is not in the closed KNOWN_KINDS "
-                 f"taxonomy (tools/pboxlint/flight_events.py) — new event "
+                 f"vocabulary (tools/pboxlint/flight_events.py) — new event "
                  f"kinds are added there in the same change")
         return out
     if isinstance(arg, ast.JoinedStr):

@@ -9,7 +9,7 @@ utils/sketch.py discipline).
            component is key-like (``key`` / ``keys`` / ``feasign`` /
            ``fid`` / ``slot_key`` / ``hot_key``) — a 10^11-cardinality
            key space minted into names/kinds grows the registry (or
-           shreds the event taxonomy) without bound, one entry per hot
+           shreds the event vocabulary) without bound, one entry per hot
            key, or
          * in obs modules — a dict grows per key: a subscript
            store/augassign or ``setdefault`` whose index terminal is

@@ -18,8 +18,15 @@ Slots follow the e2e layout: dense ``label`` (dim 1), dense ``dense0``
 
 On success prints ONE line to stdout::
 
-    FLEETMAIN {"rank": ..., "wall_s": ..., "restarts": ...,
-               "history": [...], "stats": {trainer.* snapshot}}
+    FLEETMAIN {"rank": ..., "platform": ..., "wall_s": ...,
+               "restarts": ..., "history": [...],
+               "stats": {trainer.* snapshot}}
+
+One process per chip: a rank takes every device its environment shows
+(``jax.devices()``), so ranks that share a host must each be started with
+a disjoint ``TPU_VISIBLE_CHIPS`` (``paddlebox_tpu.launch`` does that) or
+with ``JAX_PLATFORMS=cpu`` (bench.py's host-plane phase does that); the
+``platform`` field says what the rank actually ran on.
 
 ``stats`` is the whole-process ``trainer.`` snapshot — per-rank by
 construction because each rank IS a process, which is exactly why the
@@ -75,6 +82,9 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "critical-path basis needs compiled-steady-state "
                          "numbers, and cpu_s needs the compile excluded")
     args = ap.parse_args(argv)
+
+    from paddlebox_tpu.utils import compile_cache
+    compile_cache.enable()      # before the first jit
 
     from paddlebox_tpu.config import (DataFeedConfig, EmbeddingTableConfig,
                                       SlotConfig, SparseSGDConfig)
@@ -151,7 +161,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     result = sup.join()
     wall = time.monotonic() - t0
     cpu = time.process_time() - cpu0
-    out = {"rank": args.rank, "wall_s": round(wall, 3),
+    import jax
+    out = {"rank": args.rank, "platform": jax.default_backend(),
+           "wall_s": round(wall, 3),
            "cpu_s": round(cpu, 3),     # contention-free busy basis
            "restarts": sup.restarts,
            "history": [{k: m.get(k) for k in ("loss", "auc", "batches")}

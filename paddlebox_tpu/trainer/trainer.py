@@ -1018,8 +1018,10 @@ class SparseTrainer:
         out = self._finalize_metrics(self.auc_state)
         out["batches"] = n_batches
         # one stacked device->host sync, not one RPC per batch scalar
-        out["loss"] = float(jnp.mean(jnp.stack(losses))) \
-            if losses else float("nan")
+        per_step = np.asarray(jnp.stack(losses)) if losses \
+            else np.zeros((0,), np.float32)
+        out["loss"] = float(per_step.mean()) if losses else float("nan")
+        out["losses"] = [float(x) for x in per_step]
         return out
 
     def _save_state(self, ws, params, opt_state, auc_state):

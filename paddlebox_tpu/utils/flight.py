@@ -36,7 +36,7 @@ Design constraints (same discipline as utils/trace.py):
   and every producer site is a RARE event (a retry, a pass boundary),
   never per-row/per-chunk hot-path work.
 * **Bounded cardinality** — event *kinds* are lowercase literal tokens
-  from a closed taxonomy (lint rule PB206, the flight-ring face of
+  from a closed vocabulary (lint rule PB206, the flight-ring face of
   PB204's metric-name discipline).  Unbounded values (rids, paths,
   errors) belong in event FIELDS, never in the kind.
 """
@@ -92,7 +92,7 @@ class FlightRecorder:
         return out if n is None else out[:max(0, int(n))]
 
     def counts(self) -> Dict[str, int]:
-        """Events currently retained, per kind (bounded taxonomy)."""
+        """Events currently retained, per kind (bounded vocabulary)."""
         out: Dict[str, int] = {}
         with self._lock:
             for e in self._ring:

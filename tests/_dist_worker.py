@@ -15,10 +15,7 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"   # a test worker never takes the chip
 
 import numpy as np  # noqa: E402
 

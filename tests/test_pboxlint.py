@@ -313,7 +313,7 @@ def test_pb205_dynamic_reads_disarm_the_rule():
 
 def test_pb206_flight_kind_unbounded_fstring():
     # the regression this rule exists for: an event kind minted from an
-    # unbounded value (a rid) — shreds the /flightz taxonomy
+    # unbounded value (a rid) — shreds the /flightz vocabulary
     src = """
     from paddlebox_tpu.utils import flight
 
@@ -336,8 +336,8 @@ def test_pb206_literal_kind_must_be_lowercase_identifier():
     assert codes(src) == ["PB206"]
 
 
-def test_pb206_literal_kind_must_be_in_closed_taxonomy():
-    # the taxonomy is CLOSED: a lowercase literal kind that is not in
+def test_pb206_literal_kind_must_be_in_closed_vocabulary():
+    # the vocabulary is CLOSED: a lowercase literal kind that is not in
     # KNOWN_KINDS is minted ad hoc — new kinds land by editing
     # flight_events.KNOWN_KINDS in the same change
     src = """
@@ -345,7 +345,7 @@ def test_pb206_literal_kind_must_be_in_closed_taxonomy():
 
     def f():
         flight.record("totally_new_kind")
-        flight.record("heat_snapshot")      # in the taxonomy: fine
+        flight.record("heat_snapshot")      # in the vocabulary: fine
     """
     assert codes(src) == ["PB206"]
 
