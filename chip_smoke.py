@@ -69,8 +69,9 @@ def require(cond, msg) -> None:
 class CompileLog:
     """What JAX itself says it compiled: every XLA compile request (a
     persistent-cache hit is still a request) with its seconds, and the
-    cache's hits and misses.  `trainer.step_compile_s` only sees the
-    rebuilds the trainer asks for; a silent shape retrace shows here."""
+    cache's hits and misses, heard by this script's own listeners.  The
+    program counts the same events (`jit.compile_s`, registered in
+    `fleet.init`); the smoke reports both."""
     BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
     HIT = "/jax/compilation_cache/cache_hits"
     MISS = "/jax/compilation_cache/cache_misses"
@@ -156,8 +157,8 @@ def make_trainer_class():
     from paddlebox_tpu.utils.monitor import stat_snapshot
 
     def compile_count() -> int:
-        return int(stat_snapshot("trainer.step_compile_s").get(
-            "trainer.step_compile_s.count", 0))
+        return int(stat_snapshot("jit.compile_s").get(
+            "jit.compile_s.count", 0))
 
     class ObservedTrainer(SparseTrainer):
         def __init__(self, *a, expect_mosaic: bool,

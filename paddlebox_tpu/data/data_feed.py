@@ -23,6 +23,7 @@ import numpy as np
 
 from paddlebox_tpu.config import DataFeedConfig, SlotConfig
 from paddlebox_tpu.data.slot_record import SlotRecordBlock
+from paddlebox_tpu.utils import trace
 from paddlebox_tpu.utils.monitor import stat_add
 
 
@@ -172,15 +173,18 @@ class DataFeed:
         with open_file(path, self.config.pipe_command) as f:
             while True:
                 lines = []
-                for line in f:
-                    line = line.strip()
-                    if line:
-                        lines.append(line)
-                    if len(lines) >= self.chunk_lines:
-                        break
+                with trace.span("data.read.lines"):
+                    for line in f:
+                        line = line.strip()
+                        if line:
+                            lines.append(line)
+                        if len(lines) >= self.chunk_lines:
+                            break
                 if not lines:
                     return
-                yield self._parser.parse_block(lines)
+                with trace.span("data.read.parse"):
+                    block = self._parser.parse_block(lines)
+                yield block
 
 
 def make_parser(config: DataFeedConfig, parse_ins_id: bool = False,

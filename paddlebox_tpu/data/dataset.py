@@ -23,7 +23,7 @@ import numpy as np
 from paddlebox_tpu.config import DataFeedConfig
 from paddlebox_tpu.data.data_feed import DataFeed
 from paddlebox_tpu.data.slot_record import SlotRecordBlock
-from paddlebox_tpu.utils import lockdep
+from paddlebox_tpu.utils import lockdep, trace
 from paddlebox_tpu.utils.channel import Channel
 from paddlebox_tpu.utils.monitor import stat_add
 from paddlebox_tpu import flags
@@ -162,10 +162,11 @@ class SlotDataset:
                     block = block.select(keep)
                     if block.n == 0:
                         continue
-                for consumer in self._key_consumers:
-                    consumer(block.all_keys())
-                with lock:
-                    blocks.append(block)
+                with trace.span("data.read.key_tap"):
+                    for consumer in self._key_consumers:
+                        consumer(block.all_keys())
+                    with lock:
+                        blocks.append(block)
 
         with concurrent.futures.ThreadPoolExecutor(
                 max_workers=max(1, self.read_threads),
@@ -174,7 +175,8 @@ class SlotDataset:
         return blocks
 
     def load_into_memory(self) -> None:
-        self._blocks = self._read_all()
+        with trace.span("data.load_into_memory", files=len(self.filelist)):
+            self._blocks = self._read_all()
         self._pv_grouped = False   # fresh records: re-run preprocess_instance
         stat_add("stat_dataset_instances", self.instance_num())
 

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from paddlebox_tpu.config import DataFeedConfig, SlotConfig
 from paddlebox_tpu.data.batch_pack import BatchPacker
 from paddlebox_tpu.data.slot_record import SlotRecordBlock
-from paddlebox_tpu.utils import intervals, workpool
+from paddlebox_tpu.utils import intervals, trace, workpool
 from paddlebox_tpu.utils.monitor import stat_observe
 
 
@@ -500,6 +500,7 @@ class PlaneStager:
         intervals.record("upload", t0, time.monotonic())
 
 
+@trace.span("data.feed.upload_enqueue")
 def upload_pass(host_arrays: HostPassArrays, keep_host: bool = False,
                 sharding=None, staged=None) -> PackedPassFeed:
     """H2D once + one relayout jit into the step-ready stacked layout.
@@ -514,8 +515,10 @@ def upload_pass(host_arrays: HostPassArrays, keep_host: bool = False,
     staged: optional PlaneStager (or its dict) holding planes whose H2D
     was already dispatched during pack — those skip the put here; with no
     stager every plane uploads all-at-once (the parallel-packer-off
-    path)."""
-    t_up = time.perf_counter()
+    path).
+
+    The span times the ENQUEUE of the transfers and the relayout (jax
+    returns before the device has the bytes), hence its name."""
     m_up = time.monotonic()
     h = host_arrays
     N, B = h.n_batches, h.batch_size
@@ -544,7 +547,6 @@ def upload_pass(host_arrays: HostPassArrays, keep_host: bool = False,
         data = {k: jax.device_put(v, sharding[k]) if k in sharding else v
                 for k, v in data.items()}
     intervals.record("upload", m_up, time.monotonic())
-    stat_observe("data.pass_feed.upload_s", time.perf_counter() - t_up)
     return PackedPassFeed(data=data, n_batches=N, batch_size=B,
                           num_real=h.num_real,
                           host=h if keep_host else None, uid=h.uid,

@@ -60,7 +60,7 @@ from typing import Callable, Optional
 from paddlebox_tpu import flags
 from paddlebox_tpu.utils import flight, lockdep, trace
 from paddlebox_tpu.utils.channel import Channel, ChannelClosed
-from paddlebox_tpu.utils.monitor import stat_add, stat_observe
+from paddlebox_tpu.utils.monitor import stat_add
 
 flags.define_flag(
     "pass_prefetch", True,
@@ -149,12 +149,10 @@ class PassPrefetcher:
         keep = self._keep_host if keep_host is None else keep_host
         self._specs.put(_Spec(load_fn, tag, keep, date))
 
-    def _wait(self, counter: str, need: int) -> float:
-        t0 = time.monotonic()
+    def _wait(self, counter: str, need: int) -> None:
         with self._cond:
             while getattr(self, counter) < need and not self._closing:
                 self._cond.wait(timeout=1.0)
-        return time.monotonic() - t0
 
     def _run(self) -> None:
         idx = 0
@@ -167,23 +165,23 @@ class PassPrefetcher:
             # pending obs window — wait until the previous pass adopted
             # both.  Adoption happens at the START of its training, so
             # this whole chain still overlaps that training.
-            gate_s = self._wait("_adopted_n", idx)
-            if spec.date is not None and spec.date != self.engine.day_id:
-                # day boundary: end_day decays the WHOLE table, so it must
-                # order strictly between the old day's last write-back and
-                # the new day's first pull — drain the pipeline
-                gate_s += self._wait("_ended_n", idx)
-                if not self._closing:
-                    self.engine.set_date(spec.date)
-            elif spec.date is not None:
-                self.engine.set_date(spec.date)     # same day: no decay
-            stat_observe("data.prefetch.gate_wait_s", gate_s)
+            with trace.span("data.prefetch.gate_wait"):
+                self._wait("_adopted_n", idx)
+                if spec.date is not None \
+                        and spec.date != self.engine.day_id:
+                    # day boundary: end_day decays the WHOLE table, so it
+                    # must order strictly between the old day's last
+                    # write-back and the new day's first pull — drain the
+                    # pipeline
+                    self._wait("_ended_n", idx)
             if self._closing:
                 return
+            if spec.date is not None:
+                self.engine.set_date(spec.date)   # a new day: end_day here
             idx += 1
             try:
                 t0 = time.monotonic()
-                with trace.span("data.prefetch.feed", tag=spec.tag or ""):
+                with trace.span("data.prefetch.build", tag=spec.tag or ""):
                     self.engine.begin_feed_pass()
                     dataset = spec.load_fn()
                     self.engine.end_feed_pass(async_build=True)
@@ -192,11 +190,10 @@ class PassPrefetcher:
                     mapper = self.engine.peek_next_mapper()
                     arrays = self.trainer.pack_pass_host(dataset,
                                                          mapper=mapper)
-                dt = time.monotonic() - t0
                 stat_add("data.prefetch.passes")
-                stat_observe("data.prefetch.build_s", dt)
                 flight.record("prefetch_pass_ready", tag=spec.tag,
-                              records=arrays.num_real, build_s=round(dt, 3))
+                              records=arrays.num_real,
+                              build_s=round(time.monotonic() - t0, 3))
                 if not self._ready.put((arrays, dataset, spec, None)):
                     return            # closed mid-shutdown: drop and exit
             except BaseException as e:
@@ -216,9 +213,8 @@ class PassPrefetcher:
 
         The blocked time here is the pipeline's residual — feed seconds
         the training pass could NOT hide (``data.prefetch.wait_s``)."""
-        t0 = time.monotonic()
-        arrays, dataset, spec, err = self._ready.get()
-        stat_observe("data.prefetch.wait_s", time.monotonic() - t0)
+        with trace.span("data.prefetch.wait"):
+            arrays, dataset, spec, err = self._ready.get()
         if err is not None:
             raise RuntimeError(
                 f"pass prefetch failed (spec {spec.tag or '?'})") from err

@@ -30,6 +30,7 @@ from paddlebox_tpu.metrics.auc import MetricGroup
 from paddlebox_tpu.parallel.topology import HybridTopology
 from paddlebox_tpu.ps.pass_manager import BoxPSEngine
 from paddlebox_tpu.trainer.trainer import SparseTrainer
+from paddlebox_tpu.utils import compile_cache
 
 _GLOBAL: Dict = {"fleet": None}
 
@@ -61,6 +62,7 @@ class Fleet:
 
 def init(strategy: Optional[DistributedStrategy] = None,
          topology: Optional[HybridTopology] = None) -> Fleet:
+    compile_cache.watch_compiles()      # jit.compile_s, jit.cache_*
     f = Fleet(strategy, topology)
     _GLOBAL["fleet"] = f
     return f

@@ -197,7 +197,7 @@ class BoxPSEngine:
                 self._agent_keys.append(np.asarray(keys, np.uint64))
 
     def _dedup_agent_keys(self) -> np.ndarray:
-        with self.timers("dedup_keys"):
+        with self.timers("dedup_keys"), trace.span("ps.engine.dedup_keys"):
             with self._agent_lock:
                 parts = self._agent_keys
                 self._agent_keys = []
@@ -213,7 +213,7 @@ class BoxPSEngine:
         # beside the ps.wire.* byte counters (ps/service.py)
         snap = self._feed_cache_snap
         with self.timers("build_pull"), \
-                trace.span("ps.engine.build_pull", keys=len(uniq)):
+                trace.span("ps.engine.pull", keys=len(uniq)):
             t0 = time.monotonic()
             plan = None
             if snap is not None and len(snap.keys) and len(uniq):
@@ -353,7 +353,8 @@ class BoxPSEngine:
 
     def _build(self, uniq: np.ndarray) -> tuple:
         mapper, n, host_rows, plan = self._build_host(uniq)
-        return mapper, n, self._adopt(mapper, n, host_rows, plan)
+        with trace.span("ps.engine.upload_ws", rows=n):
+            return mapper, n, self._adopt(mapper, n, host_rows, plan)
 
     def end_feed_pass(self, async_build: bool = False) -> None:
         """Dedup pass keys, pull host rows, build the device working set.
@@ -401,7 +402,8 @@ class BoxPSEngine:
         first surfaces the error; a stale previous working set must never
         silently train in place of the failed pass."""
         if self._build_thread is not None:
-            self._build_thread.join()
+            with trace.span("ps.engine.wait_build"):
+                self._build_thread.join()
             self._build_thread = None
         err = getattr(self, "_build_error", None)
         if err is not None:
@@ -431,10 +433,12 @@ class BoxPSEngine:
                 self.wait_feed_pass_done()  # raises if async build failed
                 assert self._next is not None
                 self.mapper, self.num_keys, host_rows, plan = self._next
-                self.ws = self._adopt(self.mapper, self.num_keys,
-                                      host_rows, plan)
+                with trace.span("ps.engine.upload_ws", rows=self.num_keys):
+                    self.ws = self._adopt(self.mapper, self.num_keys,
+                                          host_rows, plan)
                 self._next = None
-                self._refresh_stale_rows()
+                with trace.span("ps.engine.refresh_stale"):
+                    self._refresh_stale_rows()
                 self._cache_fresh_keys = None
             assert self.ws is not None, \
                 "end_feed_pass must run before begin_pass"
@@ -490,6 +494,7 @@ class BoxPSEngine:
                     self.ws[f] = self.ws[f].at[rows].set(
                         jnp.asarray(fresh[f], self.ws[f].dtype))
 
+    @trace.span("ps.engine.end_pass")
     def end_pass(self, need_save_delta: bool = False,
                  delta_path: str = "") -> None:
         """Write the trained working set back to the DRAM tier.
@@ -513,25 +518,24 @@ class BoxPSEngine:
                 "is an int16 grid, not the f32 store) — a frozen pass ends "
                 "by discarding the device copy (engine.ws = None) or "
                 "rebuilding the pass")
-        with self.timers("dump_to_cpu"), \
-                trace.span("ps.engine.end_pass_write",
-                           pass_id=self.pass_id, keys=self.num_keys):
-            soa = embedding.dump_working_set(self.ws, self.num_keys)
-            soa["unseen_days"] = np.zeros((self.num_keys,), np.float32)
-            if getattr(self, "_pulled_stats", None) is not None:
-                # f64 base + the exact per-pass delta accumulators — the
-                # absolute device copy may have rounded (f32 at 2^24+),
-                # the small-magnitude delta did not
-                for f in ("show", "click"):
-                    soa[f] = self._pulled_stats[f] + \
-                        soa[f + "_acc"].astype(np.float64)
-                    del soa[f + "_acc"]
+        with self.timers("dump_to_cpu"):
+            with trace.span("ps.engine.dump_to_cpu"):
+                soa = embedding.dump_working_set(self.ws, self.num_keys)
+                soa["unseen_days"] = np.zeros((self.num_keys,), np.float32)
+                if getattr(self, "_pulled_stats", None) is not None:
+                    # f64 base + the exact per-pass delta accumulators —
+                    # the absolute device copy may have rounded (f32 at
+                    # 2^24+), the small-magnitude delta did not
+                    for f in ("show", "click"):
+                        soa[f] = self._pulled_stats[f] + \
+                            soa[f + "_acc"].astype(np.float64)
+                        del soa[f + "_acc"]
             try:
                 t0 = time.monotonic()
-                self.table.bulk_write(self.mapper.sorted_keys, soa)
-                t1 = time.monotonic()
-                intervals.record("write", t0, t1)
-                stat_add("ps.engine.end_pass_write_s", t1 - t0)
+                with trace.span("ps.engine.end_pass_write",
+                                pass_id=self.pass_id, keys=self.num_keys):
+                    self.table.bulk_write(self.mapper.sorted_keys, soa)
+                intervals.record("write", t0, time.monotonic())
             except Exception:
                 # keep _pulled_stats/ws/mapper: a re-driven end_pass must
                 # rebuild the IDENTICAL soa (clearing the stats first used

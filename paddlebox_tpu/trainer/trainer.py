@@ -37,7 +37,7 @@ from paddlebox_tpu.ops.seqpool_cvm import fused_seqpool_cvm
 from paddlebox_tpu.parallel.topology import HybridTopology
 from paddlebox_tpu.ps import embedding, optimizer as sparse_opt
 from paddlebox_tpu.ps.pass_manager import BoxPSEngine
-from paddlebox_tpu.utils import intervals, trace
+from paddlebox_tpu.utils import compile_cache, intervals, trace
 from paddlebox_tpu.utils.channel import Channel, ChannelClosed
 from paddlebox_tpu.utils.monitor import stat_observe, stat_snapshot
 from paddlebox_tpu.utils.timer import TimerRegistry
@@ -145,12 +145,12 @@ class SparseTrainer:
         self._step_fn = None
         self._packed_step_fn = None
         self._packed_sig = None
-        # set by the step builders, cleared by the first dispatch after a
-        # (re)build: jax.jit traces+compiles on that call, so its latency
-        # is compile cost, not steady-state dispatch — it gets its own
-        # metric (trainer.step_compile_s) to keep the SLO throughput-stall
-        # rule and the dispatch p99 on steady-state numbers only
-        self._compile_pending = False
+        # a dispatch during which JAX compiled (the first after a (re)build,
+        # or a silent retrace on a new shape) is compile cost, not
+        # steady-state dispatch: jit.compile_s has its seconds, and it stays
+        # out of trainer.step_dispatch_s (the SLO throughput-stall rule and
+        # the dispatch p99 read steady-state numbers only)
+        compile_cache.watch_compiles()
         self._mxu_crossing = ("take", "take")
         self._check_nan = flags.get_flags("check_nan_inf")
 
@@ -332,7 +332,6 @@ class SparseTrainer:
                         dense, labels, valid, None, extras)
 
         self._step_fn = jax.jit(step, donate_argnums=(0, 1, 2, 3))
-        self._compile_pending = True
 
     def _pooled_dense_half(self):
         """Shared back half of the pooled-based steps (mxu/fast): dense
@@ -664,6 +663,7 @@ class SparseTrainer:
     # INDEX and dynamic-slices device-resident stacked arrays; plans for
     # the mxu path are precomputed at pass-build time, so the hot step
     # contains no sorts and no host work at all.
+    @trace.span("trainer.pack_pass_host")
     def pack_pass_host(self, dataset: SlotDataset, mapper=None,
                        on_plane=None) -> "pass_feed.HostPassArrays":
         """Host half of :meth:`build_pass_feed`: pack + translate the
@@ -718,6 +718,7 @@ class SparseTrainer:
             shardings[k] = t.sharding(None, dp, None)
         return shardings
 
+    @trace.span("trainer.finish_pass_feed")
     def finish_pass_feed(self, arrays, keep_host: bool = False,
                          staged=None) -> PackedPassFeed:
         """Device half of :meth:`build_pass_feed`: upload + relayout the
@@ -731,34 +732,35 @@ class SparseTrainer:
         feed = pf.upload_pass(arrays, keep_host=keep,
                               sharding=self.pass_shardings(arrays),
                               staged=staged)
-        path = self._resolve_path()
-        if path == "mxu":
-            from paddlebox_tpu.ops import sorted_spmm as sp
-            from paddlebox_tpu.ps import mxu_path
-            n, s, l, b = feed.data["indices"].shape
-            dims = mxu_path.make_dims(s * l * b,
-                                      self.engine.ws["show"].shape[0])
-            # padding occurrences (row 0) are dead kernel work — trim the
-            # plans to the widest batch's real-occurrence count (host
-            # lengths are exact, so this is a static bound for the pass)
-            per_batch = arrays.lengths.reshape(s, n, b).sum(axis=(0, 2))
-            eff = sp.trimmed_dims(dims, int(per_batch.max()))
-            pf.precompute_plans(feed, dims, eff, slot_ids=self.slot_ids)
-        elif path == "mxu_sharded":
-            self._precompute_sharded_plans(feed)
-        elif path == "ragged":
-            # fail at feed-build time, not first train step: an invalid
-            # config (mf_ex / topology) should not cost a CSR build first
-            self._validate_path(path)
-            csr = arrays.csr
-            if csr is None:
-                # serial path (no prefetch worker ran pack_pass_host with
-                # the ragged path selected) — build now, same plans
-                csr = pf.build_csr_plans(arrays.indices, self.slot_ids,
-                                         arrays.n_batches,
-                                         arrays.batch_size)
-            feed.plans = {k: jnp.asarray(v) for k, v in csr.items()}
-            feed.plan_dims = self._ragged_plan_key(feed)
+        with trace.span("data.feed.plans"):
+            path = self._resolve_path()
+            if path == "mxu":
+                from paddlebox_tpu.ops import sorted_spmm as sp
+                from paddlebox_tpu.ps import mxu_path
+                n, s, l, b = feed.data["indices"].shape
+                dims = mxu_path.make_dims(s * l * b,
+                                          self.engine.ws["show"].shape[0])
+                # padding occurrences (row 0) are dead kernel work — trim the
+                # plans to the widest batch's real-occurrence count (host
+                # lengths are exact, so this is a static bound for the pass)
+                per_batch = arrays.lengths.reshape(s, n, b).sum(axis=(0, 2))
+                eff = sp.trimmed_dims(dims, int(per_batch.max()))
+                pf.precompute_plans(feed, dims, eff, slot_ids=self.slot_ids)
+            elif path == "mxu_sharded":
+                self._precompute_sharded_plans(feed)
+            elif path == "ragged":
+                # fail at feed-build time, not first train step: an invalid
+                # config (mf_ex / topology) should not cost a CSR build first
+                self._validate_path(path)
+                csr = arrays.csr
+                if csr is None:
+                    # serial path (no prefetch worker ran pack_pass_host with
+                    # the ragged path selected) — build now, same plans
+                    csr = pf.build_csr_plans(arrays.indices, self.slot_ids,
+                                             arrays.n_batches,
+                                             arrays.batch_size)
+                feed.plans = {k: jnp.asarray(v) for k, v in csr.items()}
+                feed.plan_dims = self._ragged_plan_key(feed)
         return feed
 
     def build_pass_feed(self, dataset: SlotDataset,
@@ -886,7 +888,6 @@ class SparseTrainer:
                         bt["valid"], plan, extras)
 
         self._packed_step_fn = jax.jit(step, donate_argnums=(0, 1, 2, 3))
-        self._compile_pending = True
         # n_rows + feed geometry drive retrace via shapes, but the plan
         # presence/path/async/crossing flags are trace-structural — key them
         self._packed_sig = sig
@@ -953,61 +954,61 @@ class SparseTrainer:
                 f"{self.trainer_config.dump_path}/dump-pass-"
                 f"{self.engine.pass_id}.txt", "w")
         try:
-            for i in range(feed.n_batches):
-                t_step = time.perf_counter()
-                m_step = time.monotonic()
-                with self.timers("step"):
-                    out = self._packed_step_fn(ws, params, opt_state,
-                                               auc_state, np.int32(i),
-                                               feed.data, plans)
-                # device-busy window for feed-gap attribution (dispatch
-                # window; on async backends the device may still be
-                # executing past it — a lower bound, not an overcount)
-                intervals.record("device", m_step, time.monotonic())
-                # per-batch dispatch latency distribution (the loss
-                # readback below is the sync point, so this is dispatch
-                # cost, not device step time); the first dispatch after a
-                # (re)build is jit compile — its own metric
-                dt_step = time.perf_counter() - t_step
-                if self._compile_pending:
-                    self._compile_pending = False
-                    stat_observe("trainer.step_compile_s", dt_step)
-                else:
-                    stat_observe("trainer.step_dispatch_s", dt_step)
-                if async_dense:
-                    (ws, params, opt_state, auc_state, loss, preds,
-                     d_params) = out
-                    self.async_dense.push(d_params)
-                    if (i + 1) % max(
-                            self.trainer_config.sync_weight_step, 1) == 0:
-                        params = jax.device_put(self.async_dense.pull())
-                else:
-                    ws, params, opt_state, auc_state, loss, preds = out
-                if self._check_nan and not np.isfinite(float(loss)):
-                    raise FloatingPointError(f"NaN/Inf loss at batch {i}")
-                if dump_file is not None:
-                    h = feed.host
-                    lo, cnt, base = h.real_range(i)
-                    if cnt:
-                        p = np.asarray(preds)[:cnt]
-                        lbl = np.asarray(h.labels[lo:lo + cnt])
-                        ids = (h.ins_ids[base:base + cnt] if h.ins_ids
-                               else [""] * cnt)
-                        for j in range(cnt):
-                            dump_file.write(
-                                f"{ids[j]}\t{lbl[j]:g}\t{p[j]:.6f}\n")
-                if self.wuauc is not None:
-                    sl = slice(i * feed.batch_size,
-                               (i + 1) * feed.batch_size)
-                    lbl = feed.host_labels[sl]
-                    if lbl.ndim > 1:
-                        lbl = lbl[:, 0]
-                    self.wuauc.add_data(np.asarray(preds), lbl,
-                                        feed.uid[sl], feed.host_valid[sl])
-                losses.append(loss)
-                n_batches += 1
-                if progress is not None:
-                    progress(n_batches)
+            with trace.span("trainer.dispatch_steps",
+                            steps=feed.n_batches):
+                for i in range(feed.n_batches):
+                    t_step = time.perf_counter()
+                    m_step = time.monotonic()
+                    compiled = compile_cache.compile_requests
+                    with self.timers("step"):
+                        out = self._packed_step_fn(ws, params, opt_state,
+                                                   auc_state, np.int32(i),
+                                                   feed.data, plans)
+                    # device-busy window for feed-gap attribution (dispatch
+                    # window; on async backends the device may still be
+                    # executing past it — a lower bound, not an overcount)
+                    intervals.record("device", m_step, time.monotonic())
+                    # per-batch dispatch latency distribution (the loss
+                    # readback below is the sync point, so this is dispatch
+                    # cost, not device step time); a dispatch that compiled
+                    # is in jit.compile_s instead
+                    if compile_cache.compile_requests == compiled:
+                        stat_observe("trainer.step_dispatch_s",
+                                     time.perf_counter() - t_step)
+                    if async_dense:
+                        (ws, params, opt_state, auc_state, loss, preds,
+                         d_params) = out
+                        self.async_dense.push(d_params)
+                        if (i + 1) % max(
+                                self.trainer_config.sync_weight_step, 1) == 0:
+                            params = jax.device_put(self.async_dense.pull())
+                    else:
+                        ws, params, opt_state, auc_state, loss, preds = out
+                    if self._check_nan and not np.isfinite(float(loss)):
+                        raise FloatingPointError(f"NaN/Inf loss at batch {i}")
+                    if dump_file is not None:
+                        h = feed.host
+                        lo, cnt, base = h.real_range(i)
+                        if cnt:
+                            p = np.asarray(preds)[:cnt]
+                            lbl = np.asarray(h.labels[lo:lo + cnt])
+                            ids = (h.ins_ids[base:base + cnt] if h.ins_ids
+                                   else [""] * cnt)
+                            for j in range(cnt):
+                                dump_file.write(
+                                    f"{ids[j]}\t{lbl[j]:g}\t{p[j]:.6f}\n")
+                    if self.wuauc is not None:
+                        sl = slice(i * feed.batch_size,
+                                   (i + 1) * feed.batch_size)
+                        lbl = feed.host_labels[sl]
+                        if lbl.ndim > 1:
+                            lbl = lbl[:, 0]
+                        self.wuauc.add_data(np.asarray(preds), lbl,
+                                            feed.uid[sl], feed.host_valid[sl])
+                    losses.append(loss)
+                    n_batches += 1
+                    if progress is not None:
+                        progress(n_batches)
         finally:
             if dump_file is not None:
                 dump_file.close()
@@ -1015,11 +1016,12 @@ class SparseTrainer:
         if async_dense:
             self.async_dense.drain()
             self.params = jax.device_put(self.async_dense.pull())
-        out = self._finalize_metrics(self.auc_state)
-        out["batches"] = n_batches
-        # one stacked device->host sync, not one RPC per batch scalar
-        per_step = np.asarray(jnp.stack(losses)) if losses \
-            else np.zeros((0,), np.float32)
+        with trace.span("trainer.readback"):
+            out = self._finalize_metrics(self.auc_state)
+            out["batches"] = n_batches
+            # one stacked device->host sync, not one RPC per batch scalar
+            per_step = np.asarray(jnp.stack(losses)) if losses \
+                else np.zeros((0,), np.float32)
         out["loss"] = float(per_step.mean()) if losses else float("nan")
         out["losses"] = [float(x) for x in per_step]
         return out
@@ -1101,7 +1103,6 @@ class SparseTrainer:
         # "train" seconds land in the ENGINE's registry so the per-pass
         # PrintSyncTimer report shows pull/train/write side by side
         self.engine.timers.add("train", dt)
-        stat_observe("trainer.train_pass_s", dt)
         if getattr(self.engine, "cache", None) is not None:
             # this pass's HBM-tier hit rate (set at adoption) rides along
             # with the training metrics for drivers like fleet/bench
@@ -1158,58 +1159,59 @@ class SparseTrainer:
                 f"{self.trainer_config.dump_path}/dump-pass-"
                 f"{self.engine.pass_id}.txt", "w")
         try:
-            while True:
-                try:
-                    batch = ch.get().result()
-                except ChannelClosed:
-                    break
-                dev = self._put_batch(batch)
-                t_step = time.perf_counter()
-                m_step = time.monotonic()
-                with self.timers("step"):
-                    out = self._step_fn(ws, params, opt_state, auc_state,
-                                        *dev)
-                intervals.record("device", m_step, time.monotonic())
-                # same per-batch dispatch distribution as the packed loop:
-                # the SLO watchdog's throughput-stall rule rates this
-                # counter, so BOTH train paths must feed it — and both
-                # route the first post-build dispatch (jit compile) to
-                # trainer.step_compile_s instead
-                dt_step = time.perf_counter() - t_step
-                if self._compile_pending:
-                    self._compile_pending = False
-                    stat_observe("trainer.step_compile_s", dt_step)
-                else:
-                    stat_observe("trainer.step_dispatch_s", dt_step)
-                if self.async_dense is not None:
-                    (ws, params, opt_state, auc_state, loss, preds,
-                     d_params) = out
-                    # ≙ PushDense (boxps_worker.cc:252): grads to the table
-                    self.async_dense.push(d_params)
-                    if (n_batches + 1) % max(
-                            self.trainer_config.sync_weight_step, 1) == 0:
-                        # ≙ PullDense snapshot refresh (boxps_worker.cc:1301)
-                        params = jax.device_put(self.async_dense.pull())
-                else:
-                    ws, params, opt_state, auc_state, loss, preds = out
-                if self._check_nan and not np.isfinite(float(loss)):
-                    raise FloatingPointError(
-                        f"NaN/Inf loss at batch {n_batches}")
-                if dump_file is not None:
-                    p = np.asarray(preds)[:batch.num_real]
-                    lbl = batch.labels[:batch.num_real]
-                    ids = batch.ins_ids or [""] * batch.num_real
-                    for i in range(batch.num_real):
-                        dump_file.write(f"{ids[i]}\t{lbl[i]:g}\t{p[i]:.6f}\n")
-                if self.wuauc is not None:
-                    lblh = (batch.labels if batch.labels.ndim == 1
-                            else batch.labels[:, 0])
-                    self.wuauc.add_data(np.asarray(preds), lblh,
-                                        batch.uid, batch.valid)
-                losses.append(loss)
-                n_batches += 1
-                if progress is not None:
-                    progress(n_batches)
+            with trace.span("trainer.dispatch_steps"):
+                while True:
+                    try:
+                        batch = ch.get().result()
+                    except ChannelClosed:
+                        break
+                    dev = self._put_batch(batch)
+                    t_step = time.perf_counter()
+                    m_step = time.monotonic()
+                    compiled = compile_cache.compile_requests
+                    with self.timers("step"):
+                        out = self._step_fn(ws, params, opt_state, auc_state,
+                                            *dev)
+                    intervals.record("device", m_step, time.monotonic())
+                    # same per-batch dispatch distribution as the packed loop:
+                    # the SLO watchdog's throughput-stall rule rates this
+                    # counter, so BOTH train paths must feed it — and both
+                    # leave a dispatch that compiled to jit.compile_s
+                    if compile_cache.compile_requests == compiled:
+                        stat_observe("trainer.step_dispatch_s",
+                                     time.perf_counter() - t_step)
+                    if self.async_dense is not None:
+                        (ws, params, opt_state, auc_state, loss, preds,
+                         d_params) = out
+                        # ≙ PushDense (boxps_worker.cc:252): grads to the
+                        # table
+                        self.async_dense.push(d_params)
+                        if (n_batches + 1) % max(
+                                self.trainer_config.sync_weight_step, 1) == 0:
+                            # ≙ PullDense snapshot refresh
+                            # (boxps_worker.cc:1301)
+                            params = jax.device_put(self.async_dense.pull())
+                    else:
+                        ws, params, opt_state, auc_state, loss, preds = out
+                    if self._check_nan and not np.isfinite(float(loss)):
+                        raise FloatingPointError(
+                            f"NaN/Inf loss at batch {n_batches}")
+                    if dump_file is not None:
+                        p = np.asarray(preds)[:batch.num_real]
+                        lbl = batch.labels[:batch.num_real]
+                        ids = batch.ins_ids or [""] * batch.num_real
+                        for i in range(batch.num_real):
+                            dump_file.write(
+                                f"{ids[i]}\t{lbl[i]:g}\t{p[i]:.6f}\n")
+                    if self.wuauc is not None:
+                        lblh = (batch.labels if batch.labels.ndim == 1
+                                else batch.labels[:, 0])
+                        self.wuauc.add_data(np.asarray(preds), lblh,
+                                            batch.uid, batch.valid)
+                    losses.append(loss)
+                    n_batches += 1
+                    if progress is not None:
+                        progress(n_batches)
         finally:
             # on any exit — including a pack-future exception or the NaN
             # guard — unblock the producer (close is idempotent; its own
@@ -1226,11 +1228,12 @@ class SparseTrainer:
             params = jax.device_put(self.async_dense.pull())
             self.params = params
 
-        out = self._finalize_metrics(auc_state)
-        out["batches"] = n_batches
-        # one stacked device->host sync, not one RPC per batch scalar
-        out["loss"] = float(jnp.mean(jnp.stack(losses))) \
-            if losses else float("nan")
+        with trace.span("trainer.readback"):
+            out = self._finalize_metrics(auc_state)
+            out["batches"] = n_batches
+            # one stacked device->host sync, not one RPC per batch scalar
+            out["loss"] = float(jnp.mean(jnp.stack(losses))) \
+                if losses else float("nan")
         return out
 
     def _finalize_metrics(self, auc_state) -> Dict[str, float]:

@@ -10,9 +10,7 @@ the per-pass wall-time report (≙ PrintSyncTimer box_wrapper.h:795).
 
 from __future__ import annotations
 
-import contextlib
 import os
-import threading
 from typing import Optional
 
 import jax
@@ -21,30 +19,10 @@ from paddlebox_tpu.utils import trace
 from paddlebox_tpu.utils.timer import TimerRegistry
 
 
-class RecordEvent:
-    """≙ platform::RecordEvent span; shows up in the device trace — and,
-    when the host tracer is enabled (utils/trace.py), as a host span too,
-    so the merged Chrome trace carries both layers."""
-
-    def __init__(self, name: str):
-        self.name = name
-        self._ctx = None
-        self._span = None
-        self._tracer = None
-
-    def __enter__(self):
-        self._ctx = jax.profiler.TraceAnnotation(self.name)
-        self._ctx.__enter__()
-        self._tracer = trace.ACTIVE
-        if self._tracer is not None:
-            self._span = self._tracer.start_span(self.name)
-        return self
-
-    def __exit__(self, *exc):
-        if self._span is not None:
-            self._tracer.finish(self._span)
-            self._span = None
-        self._ctx.__exit__(*exc)
+# ≙ platform::RecordEvent span / paddle.profiler annotate: both are the
+# program's one span primitive (a ``pbx:<name>`` annotation in the device
+# trace, a ``<name>_s`` sample, a ring span when the host tracer is on)
+RecordEvent = annotate = trace.span
 
 
 class Profiler:
@@ -88,9 +66,3 @@ class Profiler:
 
     def __exit__(self, *exc):
         self.stop()
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    with jax.profiler.TraceAnnotation(name):
-        yield

@@ -6,11 +6,31 @@ link, and the PS wire protocol forwards ``trace_id:span_id`` so a server
 dispatch span parents to the originating client span across processes —
 PAPERS.md, Dapper + Prometheus exposition).
 
+One primitive, three sinks.  ``span(name)`` is the only way the program
+marks a stretch of host work, and every span
+
+* enters ``jax.profiler.TraceAnnotation("pbx:" + name)`` (a TraceMe: one
+  atomic check while no profiler session runs), so under ANY
+  ``jax.profiler`` session the span lies on ``/host:CPU`` on the clock of
+  the device's ``XLA Ops`` and idle gaps of the chip can be laid under it;
+* records its duration into the histogram ``<name>_s`` on
+  ``perf_counter`` (utils/monitor.py), so ``/statz``, the timeline sampler
+  and a benchmark's stat deltas see ``<name>_s.count`` / ``.sum`` with no
+  flag set;
+* with the tracer enabled (``FLAGS_obs_trace``) goes into the ring with
+  its parent link and wire context, and yields the ``Span`` (else None).
+
+"Off" is "no profiler session and ``obs_trace`` off", and then costs a
+few microseconds a span: spans go around chunks, passes and waits, never
+inside the step loop's body.  ``utils/profiler.py``'s ``RecordEvent`` and
+``annotate`` are this function under their old names.
+
 Design constraints:
 
-* **Zero hot-path cost when disabled.**  Instrumentation sites guard on
-  the module-level ``ACTIVE`` handle (the ps/faults.py pattern): one
-  ``is None`` check per site, no allocation, no lock.
+* **Zero ring cost when disabled.**  Per-request sites (the PS server's
+  dispatch) guard on the module-level ``ACTIVE`` handle (the
+  ps/faults.py pattern): one ``is None`` check per site, no allocation,
+  no lock.
 * **Bounded memory.**  Finished spans land in a ring buffer
   (``FLAGS_obs_trace_ring``); retention is newest-N, exactly what
   ``/tracez`` (utils/obs_server.py) serves.
@@ -37,13 +57,18 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from paddlebox_tpu import flags
+from paddlebox_tpu.utils.monitor import stat_observe
 
 flags.define_flag(
     "obs_trace", False,
-    "enable the host-side span tracer at import of the worker entry "
-    "points (init_distributed / obs exporter start); off = every "
-    "instrumentation site is a single is-None check")
+    "keep finished spans in the host-side ring (parent links, wire "
+    "context, /tracez) from the worker entry points on (init_distributed "
+    "/ obs exporter start); off = a span still annotates a running "
+    "jax.profiler session and feeds <name>_s, and a per-request site is "
+    "a single is-None check")
 flags.define_flag(
     "obs_trace_ring", 4096,
     "finished-span ring-buffer retention of the host tracer (newest N "
@@ -235,12 +260,26 @@ def wire_context() -> Optional[str]:
     return ACTIVE.current_context() if ACTIVE is not None else None
 
 
+# what every span is called in a profiler trace: "pbx:" + name
+ANNOTATION_PREFIX = "pbx:"
+
+
 @contextlib.contextmanager
 def span(name: str, parent: Optional[str] = None, **attrs):
-    """No-op-when-disabled span context manager for call sites that
-    don't want to hold a tracer reference."""
-    if ACTIVE is None:
-        yield None
-        return
-    with ACTIVE.span(name, parent=parent, **attrs) as s:
-        yield s
+    """A stretch of host work called ``name`` (a lowercase dotted
+    literal, PB204): a ``pbx:<name>`` annotation in any running
+    ``jax.profiler`` session (``attrs`` are its arguments there), one
+    sample of ``<name>_s``, and with the tracer enabled a ring span
+    (yielded; None when the tracer is off)."""
+    tracer = ACTIVE
+    s = None if tracer is None else \
+        tracer.start_span(name, parent=parent, **attrs)
+    t0 = time.perf_counter()
+    try:
+        with TraceAnnotation(ANNOTATION_PREFIX + name, **attrs):
+            yield s
+    finally:
+        # pboxlint: disable-next=PB204 -- the span's own name, a literal checked at its call site
+        stat_observe(name + "_s", time.perf_counter() - t0)
+        if s is not None:
+            tracer.finish(s)

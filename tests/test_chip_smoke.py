@@ -84,3 +84,31 @@ def test_cache_dir_default_is_fixed_in_checkout_across_calls_and_pids():
                           capture_output=True, text=True, timeout=120,
                           check=True).stdout.strip() for _ in range(2)]
     assert got[0] == got[1] == os.path.join(REPO, ".jax_cache")
+
+
+# -- what JAX says it compiled (compile_cache.watch_compiles) ---------------
+
+def test_a_silent_retrace_counts_in_jit_compile_s():
+    """A jitted function that meets a new shape compiles again without
+    anybody asking: the program's own counter sees it, and a caller that
+    times the dispatch can tell (``compile_requests`` moved)."""
+    import jax
+    import numpy as np
+    from paddlebox_tpu import fleet
+    from paddlebox_tpu.utils import compile_cache
+    from paddlebox_tpu.utils.monitor import stat_snapshot
+
+    def count():
+        return stat_snapshot("jit").get("jit.compile_s.count", 0)
+
+    fleet.init()
+    fleet.init()                        # registers once: no double count
+    f = jax.jit(lambda x: (x * 3 + 1).sum())
+    f(np.ones(7, np.float32)).block_until_ready()
+    seen, requests = count(), compile_cache.compile_requests
+    assert seen >= 1
+    f(np.ones(7, np.float32)).block_until_ready()   # same shape: nothing
+    assert (count(), compile_cache.compile_requests) == (seen, requests)
+    f(np.ones(11, np.float32)).block_until_ready()  # new shape: a retrace
+    assert count() == seen + 1
+    assert compile_cache.compile_requests == requests + 1
