@@ -10,6 +10,16 @@ first (pipe_command ≙ fs_open_read with pipe, data_feed.cc:330).
 The hot parser has a native C++ implementation (see
 paddlebox_tpu/native/slot_parser.cc) loaded via ctypes; this module falls
 back to a pure-Python parser when the shared object is unavailable.
+
+The reader's contract (``DataFeed.read_file``): a source's bytes in, blocks
+of ``chunk_lines`` records out (a file's last block holds the rest), a
+record being a line with the blanks, tabs and ``\r`` around it stripped and
+empty lines skipped.  A parser that offers ``takes_bytes`` (the native one)
+is handed the bytes as they are read, a reused buffer at a time, and makes
+no Python object per line; one that offers ``parse_block(lines)`` alone
+(string/InputTable slots, no native library, a Python plug-in, a plug-in
+.so without ``<symbol>_bytes``) is fed by the text loop.  Both carriers cut
+the same blocks; the choice is made from the parser object, by no flag.
 """
 
 from __future__ import annotations
@@ -130,10 +140,11 @@ class SlotParser:
         return block
 
 
-def open_file(path: str, pipe_command: str = "") -> io.TextIOBase:
+def open_bytes(path: str, pipe_command: str = "") -> io.BufferedIOBase:
     """≙ fs_open_read (framework/io/fs.cc): optional shell pipe, gz
     support, and scheme-dispatched remote filesystems (hdfs://... through
-    the registered ShellFS — paddlebox_tpu/io/fs.py)."""
+    the registered ShellFS — paddlebox_tpu/io/fs.py).  The bytes of the
+    records, whatever carried them."""
     from paddlebox_tpu.io import fs as pfs
     scheme, _ = pfs.split_scheme(path)
     if scheme and scheme != "file":
@@ -144,21 +155,30 @@ def open_file(path: str, pipe_command: str = "") -> io.TextIOBase:
         raw = io.BufferedReader(pfs.open_read(path))
         if path.endswith(".gz"):
             import gzip
-            return io.TextIOWrapper(gzip.GzipFile(fileobj=raw))
-        return io.TextIOWrapper(raw)
+            return gzip.GzipFile(fileobj=raw)
+        return raw
     if pipe_command:
         cmd = f"cat '{path}' | {pipe_command}" if path else pipe_command
-        proc = subprocess.Popen(cmd, shell=True, stdout=subprocess.PIPE)
-        return io.TextIOWrapper(proc.stdout)
+        return subprocess.Popen(cmd, shell=True,
+                                stdout=subprocess.PIPE).stdout
     if path.endswith(".gz"):
-        proc = subprocess.Popen(["zcat", path], stdout=subprocess.PIPE)
-        return io.TextIOWrapper(proc.stdout)
-    return open(path, "r")
+        return subprocess.Popen(["zcat", path],
+                                stdout=subprocess.PIPE).stdout
+    return open(path, "rb")
+
+
+def open_file(path: str, pipe_command: str = "") -> io.TextIOBase:
+    """``open_bytes`` decoded into lines, for a parser that takes lines."""
+    return io.TextIOWrapper(open_bytes(path, pipe_command))
 
 
 class DataFeed:
     """File → SlotRecordBlock stream (≙ InMemoryDataFeed::LoadIntoMemory,
     data_feed.cc:560-587)."""
+
+    # the read buffer of the bytes path: holds a 4,096-record chunk of any
+    # cell's lines (412-600 B) with room, and doubles for a longer record
+    buffer_bytes = 4 << 20
 
     def __init__(self, config: DataFeedConfig, parse_ins_id: bool = False,
                  parse_logkey: bool = False, chunk_lines: int = 4096,
@@ -170,6 +190,13 @@ class DataFeed:
                                    input_table=input_table)
 
     def read_file(self, path: str) -> Iterator[SlotRecordBlock]:
+        """Blocks of ``chunk_lines`` records (the last of a file: the
+        rest), the same whichever way the chunk reaches the parser."""
+        if getattr(self._parser, "takes_bytes", False):
+            return self._read_bytes(path)
+        return self._read_lines(path)
+
+    def _read_lines(self, path: str) -> Iterator[SlotRecordBlock]:
         with open_file(path, self.config.pipe_command) as f:
             while True:
                 lines = []
@@ -182,9 +209,71 @@ class DataFeed:
                             break
                 if not lines:
                     return
+                stat_add("data.read.text_lines", len(lines))
                 with trace.span("data.read.parse"):
                     block = self._parser.parse_block(lines)
                 yield block
+
+    def _read_bytes(self, path: str) -> Iterator[SlotRecordBlock]:
+        chunk = self._parser.new_chunk()
+        with open_bytes(path, self.config.pipe_command) as f:
+            window = _ReadWindow(f, self.buffer_bytes)
+            try:
+                while True:
+                    with trace.span("data.read.lines"):
+                        stat_add("data.read.raw_bytes", window.top_up())
+                    if window.lo == window.hi:
+                        return
+                    with trace.span("data.read.parse"):
+                        window.lo += chunk.feed_bytes(
+                            window.data, window.lo, window.hi,
+                            self.chunk_lines)
+                        if chunk.n < self.chunk_lines and not window.eof:
+                            continue    # the chunk goes on past the buffer
+                        block = chunk.take()
+                    if block.n == 0:    # blank lines were all that was left
+                        return
+                    yield block
+            finally:
+                chunk.close()
+
+
+class _ReadWindow:
+    """One reused buffer over a byte source: ``data[lo:hi]`` is read and
+    not yet parsed.  The source's last line ends in a newline here whether
+    or not it does in the source (``str.strip()`` semantics downstream)."""
+
+    def __init__(self, f, size: int):
+        self._f = f
+        self.data = np.empty(size + 1, np.uint8)    # + that last newline
+        self._view = memoryview(self.data)
+        self.lo = self.hi = 0
+        self.eof = False
+
+    def top_up(self) -> int:
+        """Carry the unparsed tail (a cut record among it) to the front and
+        read behind it until the buffer is full or the source ends; the
+        buffer doubles when one record fills it.  Returns the bytes read."""
+        if self.eof:
+            return 0
+        kept = self.hi - self.lo
+        self._view[:kept] = self._view[self.lo:self.hi]
+        if kept == len(self.data) - 1:
+            self.data = np.concatenate(
+                [self.data[:kept], np.empty(kept + 1, np.uint8)])
+            self._view = memoryview(self.data)
+        self.lo, self.hi = 0, kept
+        while self.hi < len(self.data) - 1:
+            got = self._f.readinto(self._view[self.hi:-1])
+            if not got:
+                self.eof = True
+                break
+            self.hi += got
+        read = self.hi - kept
+        if self.eof and self.hi and self.data[self.hi - 1] != 10:
+            self.data[self.hi] = 10
+            self.hi += 1
+        return read
 
 
 def make_parser(config: DataFeedConfig, parse_ins_id: bool = False,

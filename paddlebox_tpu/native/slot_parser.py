@@ -11,6 +11,7 @@ import numpy as np
 from paddlebox_tpu.config import DataFeedConfig
 from paddlebox_tpu.data.slot_record import SlotRecordBlock
 from paddlebox_tpu.native import build
+from paddlebox_tpu.utils.monitor import stat_add
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -29,6 +30,12 @@ def _load():
             ctypes.c_char_p, ctypes.c_int64, ctypes.c_int32,
             ctypes.POINTER(ctypes.c_uint8), ctypes.c_int32, ctypes.c_int32,
             ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int32)]
+        lib.pbox_parse_block_bytes.restype = ctypes.c_void_p
+        lib.pbox_parse_block_bytes.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int32, ctypes.POINTER(ctypes.c_uint8), ctypes.c_int32,
+            ctypes.c_int32, ctypes.POINTER(ctypes.c_int64),
+            ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int32)]
         lib.pbox_slot_total.restype = ctypes.c_int64
         lib.pbox_slot_total.argtypes = [ctypes.c_void_p, ctypes.c_int32]
         for name in ("pbox_fill_slot_u64", "pbox_fill_slot_f32"):
@@ -43,6 +50,7 @@ def _load():
         lib.pbox_fill_insids.argtypes = [ctypes.c_void_p, ctypes.c_void_p,
                                          ctypes.c_void_p]
         lib.pbox_free.argtypes = [ctypes.c_void_p]
+        lib.pbox_clear.argtypes = [ctypes.c_void_p]
         _lib = lib
         return _lib
 
@@ -51,8 +59,13 @@ def available() -> bool:
     return _load() is not None
 
 
+BYTES_SUFFIX = "_bytes"     # <entry>_bytes takes a file's bytes as they lie
+
+
 class NativeSlotParser:
-    """Drop-in replacement for data_feed.SlotParser.parse_block."""
+    """Drop-in replacement for data_feed.SlotParser.parse_block, and a
+    parser that takes a file's bytes as they are (``takes_bytes``,
+    ``new_chunk``), with no Python string per line."""
 
     def __init__(self, config: DataFeedConfig, parse_ins_id: bool = False,
                  parse_logkey: bool = False):
@@ -67,67 +80,147 @@ class NativeSlotParser:
     _lib = None
     _entry = "pbox_parse_block"
 
-    def parse_block(self, lines) -> SlotRecordBlock:
-        # accessors (slot_total/fill_*) always come from the canonical lib
-        # — a plugin .so only overrides the *parse* entry and must return a
-        # handle compatible with the canonical block layout
-        lib = _load()
-        entry = getattr(self._lib, self._entry) \
-            if self._lib is not None else lib.pbox_parse_block
-        if self._lib is not None:
-            # ctypes defaults restype to c_int (truncates the handle
-            # pointer) — stamp the block-parser ABI on the plugin symbol
-            entry.restype = ctypes.c_void_p
-            entry.argtypes = lib.pbox_parse_block.argtypes
-        buf = ("\n".join(lines) + "\n").encode()
-        n_rec = ctypes.c_int64(0)
-        status = ctypes.c_int32(0)
-        handle = entry(
-            buf, len(buf), len(self.config.slots),
-            self._is_float.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-            int(self.parse_ins_id), int(self.parse_logkey),
-            ctypes.byref(n_rec), ctypes.byref(status))
-        if not handle:
-            raise ValueError(
-                f"native parse failed (status={status.value}); check slot "
-                f"config against the data (n_slots={len(self.config.slots)})")
+    def _resolve(self, suffix: str = ""):
+        """The parse entry ``<_entry><suffix>``: the canonical library's,
+        or a plugin .so's with the canonical ABI stamped on it.  Accessors
+        (slot_total/fill_*) always come from the canonical lib — a plugin
+        .so only overrides the *parse* entries and must return a handle
+        compatible with the canonical block layout."""
+        canonical = getattr(_load(), "pbox_parse_block" + suffix)
+        if self._lib is None:
+            return canonical
+        entry = getattr(self._lib, self._entry + suffix)
+        # ctypes defaults restype to c_int (truncates the handle pointer)
+        entry.restype = ctypes.c_void_p
+        entry.argtypes = canonical.argtypes
+        return entry
+
+    @property
+    def takes_bytes(self) -> bool:
+        """A plugin .so written against the block ABI alone has no
+        ``<entry>_bytes``: its files keep the text loop."""
         try:
-            n = n_rec.value
-            block = SlotRecordBlock(n=n)
-            for si, slot in enumerate(self.config.slots):
-                total = lib.pbox_slot_total(handle, si)
-                offsets = np.empty(n + 1, np.int64)
-                if slot.dtype == "float":
-                    values = np.empty(total, np.float32)
-                    lib.pbox_fill_slot_f32(handle, si,
-                                           values.ctypes.data,
-                                           offsets.ctypes.data)
-                    block.float_slots[slot.name] = (values, offsets)
-                else:
-                    values = np.empty(total, np.uint64)
-                    lib.pbox_fill_slot_u64(handle, si,
-                                           values.ctypes.data,
-                                           offsets.ctypes.data)
-                    block.uint64_slots[slot.name] = (values, offsets)
-            if self.parse_logkey:
-                sids = np.empty(n, np.uint64)
-                cm = np.empty(n, np.int32)
-                rk = np.empty(n, np.int32)
-                lib.pbox_fill_logkeys(handle, sids.ctypes.data,
-                                      cm.ctypes.data, rk.ctypes.data)
-                block.search_ids, block.cmatch, block.rank = sids, cm, rk
-            if self.parse_ins_id or self.parse_logkey:
-                nbytes = lib.pbox_insid_bytes(handle)
-                chars = ctypes.create_string_buffer(max(nbytes, 1))
-                offs = np.empty(n + 1, np.int64)
-                lib.pbox_fill_insids(handle, chars, offs.ctypes.data)
-                raw = chars.raw[:nbytes].decode()
-                block.ins_ids = [raw[offs[i]:offs[i + 1]] for i in range(n)]
-            from paddlebox_tpu.utils.monitor import stat_add
-            stat_add("stat_total_feasign_num_in_mem", block.feasign_count)
-            return block
+            self._resolve(BYTES_SUFFIX)
+        except AttributeError:
+            return False
+        return True
+
+    def new_chunk(self) -> "NativeChunk":
+        return NativeChunk(self)
+
+    def parse_block(self, lines) -> SlotRecordBlock:
+        chunk = NativeChunk(self)
+        try:
+            chunk.feed_lines(lines)
+            return chunk.take()
         finally:
-            lib.pbox_free(handle)
+            chunk.close()
+
+
+class NativeChunk:
+    """One block under construction: a native handle that takes records
+    from lines or from successive byte ranges of a file, and that ``take``
+    turns into a SlotRecordBlock.  The handle lives until ``close`` and is
+    emptied, not freed, between blocks: its columns keep their memory, so
+    a file's later chunks grow nothing and fault no fresh page.  One
+    thread at a time."""
+
+    def __init__(self, parser: NativeSlotParser):
+        self._parser = parser
+        self._bytes_entry = None
+        self._handle = None
+        self.n = 0                  # records held
+
+    def _config_args(self):
+        p = self._parser
+        return (len(p.config.slots),
+                p._is_float.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+                int(p.parse_ins_id), int(p.parse_logkey))
+
+    def _adopt(self, handle, n_records: int, status: int) -> None:
+        if not handle:      # the native side freed what it held
+            self._handle, self.n = None, 0
+            raise ValueError(
+                f"native parse failed (status={status}); check slot "
+                f"config against the data "
+                f"(n_slots={len(self._parser.config.slots)})")
+        self._handle, self.n = handle, n_records
+
+    def feed_lines(self, lines) -> None:
+        """All of ``lines``, into a fresh handle (the block ABI)."""
+        self.close()
+        buf = ("\n".join(lines) + "\n").encode()
+        n_rec, status = ctypes.c_int64(0), ctypes.c_int32(0)
+        handle = self._parser._resolve()(
+            buf, len(buf), *self._config_args(),
+            ctypes.byref(n_rec), ctypes.byref(status))
+        self._adopt(handle, n_rec.value, status.value)
+
+    def feed_bytes(self, buf: np.ndarray, lo: int, hi: int,
+                   max_records: int) -> int:
+        """Append whole lines of ``buf[lo:hi]`` (uint8) until the chunk
+        holds ``max_records``; returns the bytes consumed.  A last line
+        that lacks its newline is left for the caller to carry over."""
+        if buf.dtype != np.uint8 or not buf.flags.c_contiguous \
+                or not 0 <= lo <= hi <= buf.shape[0]:
+            raise ValueError("feed_bytes takes buf[lo:hi] of a contiguous "
+                             "uint8 array")
+        if self._bytes_entry is None:
+            self._bytes_entry = self._parser._resolve(BYTES_SUFFIX)
+        n_rec, status = ctypes.c_int64(0), ctypes.c_int32(0)
+        consumed = ctypes.c_int64(0)
+        handle = self._bytes_entry(
+            self._handle, buf.ctypes.data + lo, hi - lo, max_records,
+            *self._config_args(), ctypes.byref(n_rec),
+            ctypes.byref(consumed), ctypes.byref(status))
+        self._adopt(handle, n_rec.value, status.value)
+        return consumed.value
+
+    def take(self) -> SlotRecordBlock:
+        """The records held, as a block; the chunk is empty again."""
+        handle, n = self._handle, self.n
+        if handle is None:
+            return SlotRecordBlock(n=0)
+        p, lib = self._parser, _load()
+        block = SlotRecordBlock(n=n)
+        for si, slot in enumerate(p.config.slots):
+            total = lib.pbox_slot_total(handle, si)
+            offsets = np.empty(n + 1, np.int64)
+            if slot.dtype == "float":
+                values = np.empty(total, np.float32)
+                lib.pbox_fill_slot_f32(handle, si,
+                                       values.ctypes.data,
+                                       offsets.ctypes.data)
+                block.float_slots[slot.name] = (values, offsets)
+            else:
+                values = np.empty(total, np.uint64)
+                lib.pbox_fill_slot_u64(handle, si,
+                                       values.ctypes.data,
+                                       offsets.ctypes.data)
+                block.uint64_slots[slot.name] = (values, offsets)
+        if p.parse_logkey:
+            sids = np.empty(n, np.uint64)
+            cm = np.empty(n, np.int32)
+            rk = np.empty(n, np.int32)
+            lib.pbox_fill_logkeys(handle, sids.ctypes.data,
+                                  cm.ctypes.data, rk.ctypes.data)
+            block.search_ids, block.cmatch, block.rank = sids, cm, rk
+        if p.parse_ins_id or p.parse_logkey:
+            nbytes = lib.pbox_insid_bytes(handle)
+            chars = ctypes.create_string_buffer(max(nbytes, 1))
+            offs = np.empty(n + 1, np.int64)
+            lib.pbox_fill_insids(handle, chars, offs.ctypes.data)
+            raw = chars.raw[:nbytes].decode()
+            block.ins_ids = [raw[offs[i]:offs[i + 1]] for i in range(n)]
+        stat_add("stat_total_feasign_num_in_mem", block.feasign_count)
+        lib.pbox_clear(handle)
+        self.n = 0
+        return block
+
+    def close(self) -> None:
+        if self._handle is not None:
+            _load().pbox_free(self._handle)
+        self._handle, self.n = None, 0
 
 
 class NativeHashShard:

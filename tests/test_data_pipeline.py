@@ -215,3 +215,297 @@ def test_pv_aligned_batches():
     ds.postprocess_instance()
     sizes2 = [bt.n for bt in ds.batches(B)]
     assert sizes2[:-1] == [B] * (len(sizes2) - 1)
+
+
+# -- the reader's two carriers: a file's bytes handed to the native parser
+# as they lie, against the text loop with the same parser ------------------
+
+CHUNK = 16
+BUFFER_SIZES = [64, 4096, 1 << 20]
+
+
+class LinesOnly:
+    """The same parser, offering ``parse_block(lines)`` alone: its feed
+    takes the text loop (the choice is made from what the parser offers)."""
+
+    def __init__(self, parser):
+        self.parse_block = parser.parse_block
+
+
+def record(rng, ins_id=False, logkey=False, wide=False):
+    parts = []
+    if ins_id:
+        parts.append(f"1 ins{rng.integers(0, 10**6)}")
+    if logkey:
+        parts.append("1 %x%02x%02x" % (rng.integers(1, 1 << 40),
+                                       rng.integers(0, 256),
+                                       rng.integers(0, 256)))
+    parts.append(f"1 {rng.integers(0, 2)}")
+    parts.append("3 " + " ".join(
+        rng.choice(["%.4f", "%.3e", "-%.2f", "%.0f"]) % abs(rng.normal())
+        for _ in range(3)))
+    for cap in (40 if wide else 4, 2):
+        k = rng.integers(1, cap + 1)
+        parts.append(f"{k} " + " ".join(
+            str(rng.integers(1, 1 << 62)) for _ in range(k)))
+    return " ".join(parts)
+
+
+def records(n, seed=0, **kw):
+    rng = np.random.default_rng(seed)
+    return [record(rng, **kw) for _ in range(n)]
+
+
+def _blank_lines():
+    out = ["", "   ", "\t"]
+    for k, r in enumerate(records(40, 1)):
+        out.append(r)
+        if k % 5 == 0:
+            out.extend(["", " \t "])
+    # blank lines where a chunk ends and where the file does
+    return "\n".join(out[:3] + out[3:] + ["", "", ""]) + "\n"
+
+
+def _blanks_and_tabs():
+    return "".join(
+        ["", "  ", "\t", " \t "][k % 4] + r.replace(" ", "  ", 2)
+        + ["", " ", "\t", " \t  "][(k // 4) % 4] + "\n"
+        for k, r in enumerate(records(50, 2)))
+
+
+# name -> (the file's text, DataFeed keywords)
+CASES = {
+    "plain": lambda: ("\n".join(records(50)) + "\n", {}),
+    "blank_lines": lambda: (_blank_lines(), {}),
+    "leading_trailing_blanks_and_tabs": lambda: (_blanks_and_tabs(), {}),
+    "crlf": lambda: ("\r\n".join(records(50, 3)) + "\r\n", {}),
+    "no_final_newline": lambda: ("\n".join(records(50, 4)), {}),
+    "no_final_newline_trailing_blank": lambda: (
+        "\n".join(records(21, 5)) + " \t", {}),
+    "exactly_chunk_lines": lambda: ("\n".join(records(CHUNK, 6)) + "\n", {}),
+    "chunk_lines_plus_one": lambda: (
+        "\n".join(records(CHUNK + 1, 7)) + "\n", {}),
+    "two_chunks_then_blank_lines": lambda: (
+        "\n".join(records(2 * CHUNK, 8)) + "\n\n  \n\n", {}),
+    "one_record": lambda: (records(1, 9)[0], {}),
+    "empty_file": lambda: ("", {}),
+    "blank_lines_only": lambda: ("\n  \n\t\n\r\n", {}),
+    "parse_ins_id": lambda: (
+        "\n".join(records(50, 10, ins_id=True)) + "\n",
+        {"parse_ins_id": True}),
+    "parse_logkey": lambda: (
+        "\n".join(records(50, 11, logkey=True)) + "\n",
+        {"parse_logkey": True}),
+    "parse_ins_id_and_logkey": lambda: (
+        "\n".join(records(50, 12, ins_id=True, logkey=True)) + "\n",
+        {"parse_ins_id": True, "parse_logkey": True}),
+    "float_slots": lambda: (
+        "".join(f"1 {k % 2} 3 {k}.5 -1e-3 {k * 1e7:.6e} 1 {k + 1} 1 7\n"
+                for k in range(40)), {}),
+    "multi_key_slots": lambda: (
+        "\n".join(records(50, 13, wide=True)) + "\n", {}),
+}
+
+
+def write_case(name, tmp_path):
+    text, kw = CASES[name]()
+    path = tmp_path / "part-00000"
+    path.write_bytes(text.encode())
+    return str(path), kw
+
+
+def feeds(cfg, buffer_bytes, **kw):
+    """(the feed on the bytes path, the same parser on the text path)."""
+    raw = DataFeed(cfg, chunk_lines=CHUNK, **kw)
+    raw.buffer_bytes = buffer_bytes
+    assert raw._parser.takes_bytes
+    text = DataFeed(cfg, chunk_lines=CHUNK, **kw)
+    text._parser = LinesOnly(raw._parser)
+    return raw, text
+
+
+def assert_blocks_equal(got, want):
+    assert [b.n for b in got] == [b.n for b in want]
+    for a, b in zip(got, want):
+        for slots_a, slots_b in ((a.uint64_slots, b.uint64_slots),
+                                 (a.float_slots, b.float_slots)):
+            assert slots_a.keys() == slots_b.keys()
+            for name in slots_a:
+                for x, y in zip(slots_a[name], slots_b[name]):
+                    assert x.dtype == y.dtype and np.array_equal(x, y), name
+        assert a.ins_ids == b.ins_ids
+        for field in ("search_ids", "cmatch", "rank"):
+            x, y = getattr(a, field), getattr(b, field)
+            assert (x is None) == (y is None)
+            assert x is None or np.array_equal(x, y), field
+
+
+def native_or_skip():
+    from paddlebox_tpu.native import slot_parser
+    if not slot_parser.available():
+        pytest.skip("native lib not built")
+
+
+@pytest.mark.parametrize("buffer_bytes", BUFFER_SIZES)
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_bytes_path_equals_text_path(case, buffer_bytes, tmp_path):
+    native_or_skip()
+    path, kw = write_case(case, tmp_path)
+    raw, text = feeds(make_config(), buffer_bytes, **kw)
+    want = list(text.read_file(path))
+    assert_blocks_equal(list(raw.read_file(path)), want)
+    assert all(b.n == CHUNK for b in want[:-1])
+
+
+@pytest.mark.parametrize("buffer_bytes", BUFFER_SIZES)
+@pytest.mark.parametrize("carrier", ["gz", "pipe_command"])
+def test_bytes_path_equals_text_path_through_a_pipe(carrier, buffer_bytes,
+                                                    tmp_path):
+    native_or_skip()
+    import dataclasses
+    import gzip
+    cfg = make_config()
+    text_of_file = "\n".join(records(70, 14)) + "\n"
+    if carrier == "gz":
+        path = str(tmp_path / "part-00000.gz")
+        with gzip.open(path, "wb") as f:
+            f.write(text_of_file.encode())
+        n = 70
+    else:
+        path = str(tmp_path / "part-00000")
+        with open(path, "w") as f:
+            f.write(text_of_file)
+        cfg = dataclasses.replace(cfg, pipe_command="sed -n '2~2p'")
+        n = 35
+    raw, text = feeds(cfg, buffer_bytes)
+    want = list(text.read_file(path))
+    assert sum(b.n for b in want) == n
+    assert_blocks_equal(list(raw.read_file(path)), want)
+
+
+@pytest.mark.parametrize("buffer_bytes", BUFFER_SIZES)
+def test_sample_rate_keeps_the_same_set_on_both_paths(buffer_bytes,
+                                                      tmp_path, monkeypatch):
+    native_or_skip()
+    import dataclasses
+    from paddlebox_tpu.native.slot_parser import NativeSlotParser
+    cfg = dataclasses.replace(make_config(), sample_rate=0.4, rand_seed=5)
+    paths = []
+    for k in range(3):
+        paths.append(str(tmp_path / f"part-{k:05d}"))
+        with open(paths[-1], "w") as f:
+            f.write("\n".join(records(9000, 20 + k)) + "\n")
+    monkeypatch.setattr(DataFeed, "buffer_bytes", buffer_bytes)
+
+    def kept():
+        ds = SlotDataset(cfg, read_threads=1)
+        ds.set_filelist(paths)
+        ds.load_into_memory()
+        return ds.get_blocks()
+
+    got = kept()
+    monkeypatch.setattr(NativeSlotParser, "takes_bytes", False)
+    want = kept()
+    assert 0 < sum(b.n for b in want) < 27000
+    assert_blocks_equal(got, want)
+
+
+@pytest.mark.parametrize("buffer_bytes", BUFFER_SIZES)
+@pytest.mark.parametrize("fault", ["slot_count_zero", "values_missing",
+                                   "ins_id_prefix"])
+def test_a_malformed_record_raises_the_same_error(fault, buffer_bytes,
+                                                  tmp_path):
+    native_or_skip()
+    good = records(2 * CHUNK + 3, 15, ins_id=fault == "ins_id_prefix")
+    bad = {"slot_count_zero": "1 1 3 0.1 0.2 0.3 0 1 5",
+           "values_missing": "1 1 3 0.1 0.2 0.3 2 8 9 1 \t",
+           "ins_id_prefix": "2 insX 1 1 3 0.1 0.2 0.3 1 4 1 5"}[fault]
+    path = tmp_path / "part-00000"
+    path.write_text("\n".join(good[:CHUNK + 5] + [bad] + good[CHUNK + 5:])
+                    + "\n")
+    raw, text = feeds(make_config(), buffer_bytes,
+                      parse_ins_id=fault == "ins_id_prefix")
+    seen, errors = [], []
+    for feed in (raw, text):
+        blocks = []
+        with pytest.raises(ValueError) as err:
+            for block in feed.read_file(str(path)):
+                blocks.append(block)
+        seen.append(blocks)
+        errors.append(str(err.value))
+    assert errors[0] == errors[1] and "native parse failed" in errors[0]
+    assert [b.n for b in seen[1]] == [CHUNK]    # the chunks before it
+    assert_blocks_equal(seen[0], seen[1])
+
+
+def _plugin_so_without_the_bytes_entry(tmp_path):
+    """A site's parser .so written against the block ABI alone: this
+    repo's parser source with the bytes entry's symbol renamed away."""
+    import subprocess
+    from paddlebox_tpu.native import build
+    src = os.path.join(os.path.dirname(build.lib_path()), "slot_parser.cc")
+    so = str(tmp_path / "libsite_parser.so")
+    subprocess.run(["g++", "-O1", "-shared", "-fPIC", "-std=c++17",
+                    "-Dpbox_parse_block_bytes=site_private_entry",
+                    "-o", so, src], check=True, capture_output=True)
+    return so
+
+
+def _string_slot_feed():
+    from paddlebox_tpu.ps.aux_tables import InputTable
+    cfg = DataFeedConfig(slots=(SlotConfig("s", capacity=2),
+                                SlotConfig("user", dtype="string",
+                                           capacity=1)))
+    return DataFeed(cfg, chunk_lines=CHUNK, input_table=InputTable()), \
+        "".join(f"1 {k + 1} 1 u{k % 7}\n" for k in range(40))
+
+
+def _plugin_feed(spec):
+    from paddlebox_tpu.data.data_feed import load_parser_plugin
+    cfg = DataFeedConfig(slots=(SlotConfig("s", capacity=2),))
+    feed = DataFeed(cfg, chunk_lines=CHUNK)
+    feed._parser = load_parser_plugin(spec, cfg)
+    return feed, "".join(f"1 {k + 1}\n" for k in range(40))
+
+
+@pytest.mark.parametrize("which", ["default", "so_plugin_with_the_entry",
+                                   "string_slots", "use_native_false",
+                                   "python_plugin",
+                                   "so_plugin_without_the_entry"])
+def test_the_carrier_is_chosen_from_what_the_parser_offers(which, tmp_path):
+    native_or_skip()
+    from paddlebox_tpu.native import build
+    from paddlebox_tpu.utils.monitor import StatRegistry, stat_snapshot
+    cfg = DataFeedConfig(slots=(SlotConfig("s", capacity=2),))
+    text = "".join(f"1 {k + 1}\n" for k in range(40))
+    if which == "default":
+        feed = DataFeed(cfg, chunk_lines=CHUNK)
+    elif which == "use_native_false":
+        feed = DataFeed(cfg, chunk_lines=CHUNK, use_native=False)
+    elif which == "string_slots":
+        feed, text = _string_slot_feed()
+    elif which == "python_plugin":
+        feed, text = _plugin_feed("tests.parser_plugin_fixture:create_parser")
+    elif which == "so_plugin_with_the_entry":
+        feed, text = _plugin_feed(f"{build.lib_path()}:pbox_parse_block")
+    else:
+        feed, text = _plugin_feed(
+            _plugin_so_without_the_bytes_entry(tmp_path)
+            + ":pbox_parse_block")
+    path = tmp_path / "part-00000"
+    path.write_text(text)
+    StatRegistry.instance().reset()
+    blocks = list(feed.read_file(str(path)))
+    stats = stat_snapshot("data.read")
+    takes_bytes = which in ("default", "so_plugin_with_the_entry")
+    if takes_bytes:
+        assert stats["data.read.raw_bytes"] == len(text)
+        assert "data.read.text_lines" not in stats
+    else:
+        assert stats["data.read.text_lines"] == 40
+        assert "data.read.raw_bytes" not in stats
+    if which != "python_plugin":    # the fixture makes one record a chunk
+        assert [b.n for b in blocks] == [16, 16, 8]
+    # one sample a chunk on either carrier, and the read that found the end
+    assert stats["data.read.parse_s.count"] == 3
+    assert stats["data.read.lines_s.count"] == 4
