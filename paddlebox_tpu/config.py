@@ -159,7 +159,11 @@ class EmbeddingTableConfig:
     feature_value.h:44-57)."""
 
     name: str = "embedding"
-    embedding_dim: int = 8           # mf_dim (embedx width, excl. show/clk/lr-w)
+    # mf_dim: the embedx width, excl. show/click/lr-w.  8 is PaddleBox's
+    # CTR default; the benchmark's configurations run 8, 32 and, for a
+    # sequence tower whose token embedding is the row, 2048 (the kernels
+    # cut a table wider than sorted_spmm.W_BLOCK rows into blocks)
+    embedding_dim: int = 8
     sgd: SparseSGDConfig = dataclasses.field(default_factory=SparseSGDConfig)
     accessor: AccessorConfig = dataclasses.field(default_factory=AccessorConfig)
     shard_num: int = 16              # host-table shards (≙ memory_sparse_table.h:46)

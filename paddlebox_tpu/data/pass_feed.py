@@ -132,7 +132,8 @@ def pack_pass(blocks: Sequence[SlotRecordBlock], feed_config: DataFeedConfig,
               key_mapper=None, prebatched: bool = False,
               batch_counts: Optional[Sequence[int]] = None,
               pack_threads: Optional[int] = None,
-              on_plane: Optional[Callable[[str, np.ndarray], None]] = None
+              on_plane: Optional[Callable[[str, np.ndarray], None]] = None,
+              seq_key_slot: Optional[str] = None
               ) -> HostPassArrays:
     """Vectorized whole-pass pack: one call per slot, one key translation
     for every occurrence in the pass (vs per-batch searchsorted loops).
@@ -155,6 +156,11 @@ def pack_pass(blocks: Sequence[SlotRecordBlock], feed_config: DataFeedConfig,
     on_plane: optional callable invoked on THIS thread as each finished
     SoA plane becomes final — upload_pass's per-plane H2D overlap hook
     (device dispatch stays on the pack coordinator thread).
+
+    seq_key_slot: name of a sparse slot — adds the extras plane
+    ``seq_keys`` [N*B, slot capacity] int32 of the slot's RAW keys (a
+    sequence model's next-token targets are vocabulary ids; ``indices``
+    holds working-set rows, which change every pass).  Keys must fit int32.
     """
     t_pack = time.perf_counter()
     m_pack = time.monotonic()
@@ -256,7 +262,7 @@ def pack_pass(blocks: Sequence[SlotRecordBlock], feed_config: DataFeedConfig,
         multi = np.zeros((nb, len(packer.label_slots)), np.float32)
         valid = np.zeros((nb,), dtype=bool)
         uid = np.zeros((nb,), np.uint64) if feed_config.uid_slot else None
-        aux = {} if feed_config.string_slots else None
+        aux = {} if feed_config.string_slots or seq_key_slot else None
 
         def pack_dense(slot, col: int) -> None:
             values, offsets = merged.float_slots[slot.name]
@@ -284,6 +290,17 @@ def pack_pass(blocks: Sequence[SlotRecordBlock], feed_config: DataFeedConfig,
             plane[pos] = padded.astype(np.int32)
             aux[slot.name] = plane
 
+        def pack_seq_keys(slot) -> None:
+            with trace.span("data.feed.seq_keys"):
+                vals, offs = merged.uint64_slots[slot.name]
+                if len(vals) and int(vals.max()) > np.iinfo(np.int32).max:
+                    raise ValueError(
+                        f"seq_keys: keys of slot {slot.name!r} exceed int32")
+                padded, _ = packer._pad_ragged(vals, offs, slot.capacity)
+                plane = np.zeros((nb, slot.capacity), np.int32)
+                plane[pos] = padded.astype(np.int32)
+                aux["seq_keys"] = plane
+
         tasks: List[Callable[[], None]] = []
         col = 0
         for slot in packer.dense_slots:
@@ -296,6 +313,10 @@ def pack_pass(blocks: Sequence[SlotRecordBlock], feed_config: DataFeedConfig,
         if aux is not None:
             for slot in feed_config.string_slots:
                 tasks.append(functools.partial(pack_aux, slot))
+            if seq_key_slot:
+                tasks.append(functools.partial(pack_seq_keys, next(
+                    s for s in packer.sparse_slots
+                    if s.name == seq_key_slot)))
         pool.map(lambda fn: fn(), tasks)
         valid[pos] = True
     finally:

@@ -29,11 +29,21 @@ key distribution.
 
 All offsets are chunk-aligned, so every DMA is a regular [W, C]/[W, TILE]
 block copy (no per-row DMAs — TPU DMA wants 128-lane-aligned slices).
+
+Width: up to W_BLOCK feature rows a table/payload block holds all of W (the
+CTR tables: W = 12, 36).  A wider table (a 2048-wide sequence-model row is
+W = 2052: one [W, TILE] float32 block would be 16.8 MB, past VMEM) is cut
+into W_BLOCK-row blocks along a leading grid axis; the one-hot does not
+depend on it, so each block of rows walks the same worklist and keeps the
+consecutive-revisit accumulation.  ``padded_width`` says at what height a
+caller builds such a table or payload (``ps/mxu_path.py`` does): whole
+blocks, no pad copy here.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 512     # occurrences per work-item (lane dim of payload blocks)
 TILE = 2048     # table rows per tile (lane dim of table blocks)
+W_BLOCK = 128   # feature rows per block once W outgrows one block
 # stable kernel names: the Mosaic custom calls carry them (kernel_name), so
 # a trace reduction or chip_smoke.py finds the kernels after a refactor
 GATHER_KERNEL = "sorted_spmm_gather"
@@ -50,6 +61,12 @@ SCATTER_KERNEL = "sorted_spmm_scatter"
 
 def _round_up(n: int, a: int) -> int:
     return (n + a - 1) // a * a
+
+
+def padded_width(w: int) -> int:
+    """Feature-major height the kernels run at: ``w`` itself while one
+    block holds it, else ``w`` rounded up to whole W_BLOCK blocks."""
+    return w if w <= W_BLOCK else _round_up(w, W_BLOCK)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -183,8 +200,9 @@ def build_plan(rows: jnp.ndarray, dims: SpmmDims, eff: SpmmDims = None):
 # kernels
 # ---------------------------------------------------------------------------
 
-def _gather_kernel(ch_ref, tl_ref, fst_ref, rows_ref, table_ref, out_ref):
-    i = pl.program_id(0)
+def _gather_kernel(ch_ref, tl_ref, fst_ref, rows_ref, table_ref, out_ref,
+                   work_axis: int = 0):
+    i = pl.program_id(work_axis)
     tile = tl_ref[i]
     t = table_ref.shape[1]
     c = rows_ref.shape[2]
@@ -212,8 +230,9 @@ def _gather_kernel(ch_ref, tl_ref, fst_ref, rows_ref, table_ref, out_ref):
         out_ref[...] += contrib
 
 
-def _scatter_kernel(ch_ref, tl_ref, fst_ref, rows_ref, pay_ref, out_ref):
-    i = pl.program_id(0)
+def _scatter_kernel(ch_ref, tl_ref, fst_ref, rows_ref, pay_ref, out_ref,
+                    work_axis: int = 0):
+    i = pl.program_id(work_axis)
     tile = tl_ref[i]
     t = out_ref.shape[1]
     c = rows_ref.shape[2]
@@ -239,6 +258,46 @@ def _scatter_kernel(ch_ref, tl_ref, fst_ref, rows_ref, pay_ref, out_ref):
         out_ref[...] += contrib
 
 
+def _call_blocked(kernel, name: str, scalars, rows2d, operand, dims,
+                  gather: bool, interpret: bool) -> jnp.ndarray:
+    """The wide form of both kernels: grid (W blocks, worklist).  The
+    worklist axis is the inner one, so an output block's revisits stay
+    consecutive within a block of rows.  The gather reads table tiles and
+    writes occurrence chunks, the scatter the reverse."""
+    w = operand.shape[0]
+    if w % W_BLOCK:
+        raise ValueError(
+            f"a table or payload of {w} feature rows is built at "
+            f"padded_width({w}) = {padded_width(w)}: whole blocks of rows")
+    c, t = dims.chunk, dims.tile
+
+    def by_tile(j, i, ch, tl, fs):
+        return (j, tl[i])
+
+    def by_chunk(j, i, ch, tl, fs):
+        return (j, ch[i])
+
+    tiles = pl.BlockSpec((W_BLOCK, t), by_tile)
+    chunks = pl.BlockSpec((W_BLOCK, c), by_chunk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(w // W_BLOCK, dims.n_work),
+        in_specs=[
+            pl.BlockSpec((1, 1, c), lambda j, i, ch, tl, fs: (ch[i], 0, 0)),
+            tiles if gather else chunks,
+        ],
+        out_specs=chunks if gather else tiles,
+    )
+    return pl.pallas_call(
+        functools.partial(kernel, work_axis=1),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(
+            (w, dims.p_pad if gather else dims.n_kernel), jnp.float32),
+        interpret=interpret,
+        name=name,
+    )(*scalars, rows2d, operand)
+
+
 def gather_sorted(table_fm: jnp.ndarray, rows2d: jnp.ndarray,
                   chunk_ids: jnp.ndarray, tile_ids: jnp.ndarray,
                   first_g: jnp.ndarray, dims: SpmmDims,
@@ -247,6 +306,10 @@ def gather_sorted(table_fm: jnp.ndarray, rows2d: jnp.ndarray,
     occurrence order (pad columns come from the zero sentinel tile)."""
     w = table_fm.shape[0]
     c, t = dims.chunk, dims.tile
+    if w > W_BLOCK:
+        return _call_blocked(_gather_kernel, GATHER_KERNEL,
+                             (chunk_ids, tile_ids, first_g), rows2d, table_fm,
+                             dims, True, interpret)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(dims.n_work,),
@@ -274,6 +337,10 @@ def scatter_add_sorted(payload_fm: jnp.ndarray, rows2d: jnp.ndarray,
     rows exactly zero; sentinel tile holds pad garbage — slice it off)."""
     w = payload_fm.shape[0]
     c, t = dims.chunk, dims.tile
+    if w > W_BLOCK:
+        return _call_blocked(_scatter_kernel, SCATTER_KERNEL,
+                             (chunk_ids, tile_ids, first_s), rows2d,
+                             payload_fm, dims, False, interpret)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(dims.n_work,),

@@ -21,7 +21,15 @@ gather each way, the only serial-ish ops left, ~2.6ms at 426k rows).  The
 pull table is feature-major [W, n_kernel] with W = 3 + D (+ Dex) + 1
 (rows: show, click, embed_w, mf×D, optional expand mf_ex×Dex, mf_size) so
 kernel blocks tile perfectly and the build is W row writes, not an
-[N, D] relayout.
+[N, D] relayout; past sorted_spmm.W_BLOCK rows (a 2048-wide sequence row)
+it is built at the kernels' padded height.
+
+Two consumers of the canonical values: the pooled CTR towers sum a slot's
+rows over its capacity (`pull_pool_cvm`, and `d_pooled` is broadcast back
+over L in the push); a model that takes its rows unpooled (a sequence
+tower, `models/looplm.py`) reads `pull_rows` [S, L, B, 3 + D] as they are
+and hands `push_and_update` a gradient per occurrence (`d_occ`), merged by
+key like any other.
 """
 
 from __future__ import annotations
@@ -59,19 +67,23 @@ def plan_eff_dims(plan, dims: sp.SpmmDims) -> Optional[sp.SpmmDims]:
 def _ex_dim(ws: Dict[str, jnp.ndarray]) -> int:
     """Expand ("NNCross") embedding width, 0 without one — the ex columns
     ride the same feature-major table/payload directly after mf, so the
-    kernels (width-agnostic) and the pooling (everything between col 3 and
-    the trailing mf_size is an embedding masked by created) need no
+    kernels (any width: one block of rows up to sorted_spmm.W_BLOCK,
+    W_BLOCK-row blocks beyond it) and the pooling (everything between col
+    3 and the trailing mf_size is an embedding masked by created) need no
     branches."""
     return ws["mf_ex"].shape[1] if "mf_ex" in ws else 0
 
 
 def _pull_table(ws: Dict[str, jnp.ndarray], dims: sp.SpmmDims) -> jnp.ndarray:
-    """Feature-major pull view [3 + D (+ Dex) + 1, n_kernel]."""
+    """Feature-major pull view [3 + D (+ Dex) + 1, n_kernel]; a table
+    wider than one kernel block is built at the kernels' padded height
+    (zero rows below mf_size), so that they make no pad copy."""
     from paddlebox_tpu.ps.embedding import mf_values
     n = ws["show"].shape[0]
     d = ws["mf"].shape[1]
     dx = _ex_dim(ws)
-    tab = jnp.zeros((3 + d + dx + 1, dims.n_kernel), jnp.float32)
+    tab = jnp.zeros((sp.padded_width(3 + d + dx + 1), dims.n_kernel),
+                    jnp.float32)
     tab = tab.at[0, :n].set(ws["show"])
     tab = tab.at[1, :n].set(ws["click"])
     tab = tab.at[2, :n].set(ws["embed_w"])
@@ -155,14 +167,14 @@ def acc_from_delta(delta: jnp.ndarray, n: int,
     return acc
 
 
-def pull_pool_cvm(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
-                  shape_slb: Tuple[int, int, int], use_cvm: bool = True,
-                  interpret: bool = False,
-                  crossing: str = "take") -> jnp.ndarray:
-    """Fused pull + seqpool + CVM → pooled [B, S, 3 + D].
+def pull_rows(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
+              shape_slb: Tuple[int, int, int], interpret: bool = False,
+              crossing: str = "take") -> jnp.ndarray:
+    """Per-occurrence pull values in canonical order, [S, L, B, 3 + D]:
+    show, click, embed_w and the mf columns times the row's created mask.
 
     Row 0 and the sentinel tile hold zeros, so padding occurrences and
-    unseen keys contribute nothing — no length mask needed on the pull side.
+    unseen keys read zeros — no length mask needed on the pull side.
     crossing: sorted→canonical lowering (ops/crossing.py) — "take" gathers
     by inv_perm, "sort" re-sorts keyed by perm (the destination index).
     """
@@ -198,8 +210,34 @@ def pull_pool_cvm(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
         # occurrences whose pull value is exactly zero — clamp + mask
         v = jnp.take(g.T, jnp.maximum(inv_perm, 0), axis=0)
         v = v * (inv_perm >= 0).astype(v.dtype)[:, None]
-    v = v.reshape(s, l, b, w).astype(jnp.float32)
+    return v.reshape(s, l, b, w).astype(jnp.float32)
+
+
+def pull_pool_cvm(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
+                  shape_slb: Tuple[int, int, int], use_cvm: bool = True,
+                  interpret: bool = False,
+                  crossing: str = "take") -> jnp.ndarray:
+    """Fused pull + seqpool + CVM → pooled [B, S, 3 + D]: ``pull_rows``
+    summed over each slot's capacity."""
+    v = pull_rows(ws, plan, dims, shape_slb, interpret, crossing)
     return pool_cvm_values(v, use_cvm, premasked=True)
+
+
+def occurrence_payload(d_occ: jnp.ndarray, ins_cvm: jnp.ndarray,
+                       slot_ids: jnp.ndarray) -> jnp.ndarray:
+    """Canonical push payload [S, L, B, D+4] from a gradient each
+    occurrence owns: ``d_occ`` [S, L, B, 1+D] is (g_embed, g_mf x D) of the
+    row pulled at that position (a sequence model's rows are not pooled, so
+    nothing is broadcast over L); g_show, g_click and slot are the
+    instance's, as in ``push_payload``."""
+    s, l, b = d_occ.shape[:3]
+    g_show = jnp.broadcast_to(ins_cvm[None, None, :, 0], (s, l, b))
+    g_click = jnp.broadcast_to(ins_cvm[None, None, :, 1], (s, l, b))
+    slot_col = jnp.broadcast_to(
+        slot_ids.astype(jnp.float32)[:, None, None], (s, l, b))
+    return jnp.concatenate(
+        [jnp.stack([g_show, g_click], axis=-1), d_occ, slot_col[..., None]],
+        axis=-1)
 
 
 def push_and_update(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
@@ -207,8 +245,13 @@ def push_and_update(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
                     ins_cvm: jnp.ndarray, slot_ids: jnp.ndarray,
                     cfg: SparseSGDConfig,
                     interpret: bool = False,
-                    crossing: str = "take") -> Dict[str, jnp.ndarray]:
+                    crossing: str = "take",
+                    d_occ: jnp.ndarray = None) -> Dict[str, jnp.ndarray]:
     """Merged push + sparse optimizer.
+
+    d_occ [S, L, B, 1+D], in place of d_pooled (then None): a gradient per
+    occurrence (``occurrence_payload``), for rows that were pulled
+    unpooled; it crosses by ``perm`` ("take" only), the rest is the same.
 
     d_pooled [B, S, 3+D] — cols 0,1 are ignored and replaced by the
     instance cvm (reference push semantics, box_wrapper_impl.h:373);
@@ -239,26 +282,43 @@ def push_and_update(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
     kd = eff or dims
     bf16 = bool(flags.get_flags("mxu_crossing_bf16"))
 
+    if d_occ is not None and crossing != "take":
+        raise ValueError("a per-occurrence push crosses by take only")
+
+    def kept_perm():
+        # the kept suffix of the full bijection — dropped row-0
+        # occurrences never scatter (row 0 is reserved, optimizer.py:17)
+        # and sentinel tail positions read canonical 0 but land in the
+        # discarded sentinel tile
+        return jnp.concatenate(
+            [perm, jnp.zeros((dims.p_pad - dims.p,), jnp.int32)]
+        )[dims.p_pad - kd.p_pad:]
+
     if len(plan) > 8:
         bs_ids, labelcol, slotcol = plan[8], plan[9], plan[10]
-        # dynamic columns only: [B*S, 1+D] (b-major, bs = b*S + s)
-        p2 = d_pooled[:, :, 2:].reshape(b * s, 1 + d)
-        if bf16:
-            p2 = p2.astype(jnp.bfloat16)
-        if crossing == "sort":
-            # canonical flat [(s,l,b), 1+D] — broadcast over L only here,
-            # in the narrow dynamic slice
-            can = jnp.broadcast_to(
-                jnp.transpose(p2.reshape(b, s, 1 + d), (1, 0, 2))[:, None],
-                (s, l, b, 1 + d)).reshape(dims.p, 1 + d)
-            dyn = cx.permute_by_dest(tuple(can.T), inv_perm)   # [1+D, p]
-            if eff is not None:
-                dyn = dyn[:, dims.p_pad - eff.p_pad:]
-            pad = kd.p_pad - dyn.shape[1]
-            dyn = jnp.concatenate(
-                [dyn, jnp.zeros((1 + d, pad), dyn.dtype)], axis=1)
+        if d_occ is not None:
+            dyn = jnp.take(d_occ.reshape(dims.p, 1 + d), kept_perm(),
+                           axis=0).T                           # [1+D, p_pad]
         else:
-            dyn = jnp.take(p2, bs_ids, axis=0).T               # [1+D, p_pad]
+            # dynamic columns only: [B*S, 1+D] (b-major, bs = b*S + s)
+            p2 = d_pooled[:, :, 2:].reshape(b * s, 1 + d)
+            if bf16:
+                p2 = p2.astype(jnp.bfloat16)
+            if crossing == "sort":
+                # canonical flat [(s,l,b), 1+D] — broadcast over L only
+                # here, in the narrow dynamic slice
+                can = jnp.broadcast_to(
+                    jnp.transpose(p2.reshape(b, s, 1 + d),
+                                  (1, 0, 2))[:, None],
+                    (s, l, b, 1 + d)).reshape(dims.p, 1 + d)
+                dyn = cx.permute_by_dest(tuple(can.T), inv_perm)  # [1+D, p]
+                if eff is not None:
+                    dyn = dyn[:, dims.p_pad - eff.p_pad:]
+                pad = kd.p_pad - dyn.shape[1]
+                dyn = jnp.concatenate(
+                    [dyn, jnp.zeros((1 + d, pad), dyn.dtype)], axis=1)
+            else:
+                dyn = jnp.take(p2, bs_ids, axis=0).T           # [1+D, p_pad]
         dyn = dyn.astype(jnp.float32)
         ones = jnp.ones((1, kd.p_pad), jnp.float32)
         srt_cm = jnp.concatenate(
@@ -270,7 +330,9 @@ def push_and_update(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
         # break the optimizer's exact slot matches: nodeid_slot,
         # slot_mf_dims), so the bandwidth lever only pays on the planes
         # path where slot rides a separate static f32 plane.
-        payload = push_payload(d_pooled, ins_cvm, slot_ids, (s, l, b))
+        payload = (push_payload(d_pooled, ins_cvm, slot_ids, (s, l, b))
+                   if d_occ is None
+                   else occurrence_payload(d_occ, ins_cvm, slot_ids))
         flat = payload.reshape(dims.p, w)
         if crossing == "sort":
             # destination = this element's sorted position (shifted
@@ -287,14 +349,7 @@ def push_and_update(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
             srt_cm = jnp.concatenate(
                 [srt, jnp.zeros((dims.p_pad - dims.p, w), srt.dtype)]).T
         else:
-            # trimmed plan: keep the suffix of the full bijection — dropped
-            # row-0 occurrences never scatter (row 0 is reserved,
-            # optimizer.py:17) and sentinel tail positions read canonical 0
-            # but land in the discarded sentinel tile
-            p0 = dims.p_pad - eff.p_pad
-            perm_k = jnp.concatenate(
-                [perm, jnp.zeros((dims.p_pad - dims.p,), jnp.int32)])[p0:]
-            srt_cm = jnp.take(flat, perm_k, axis=0).T
+            srt_cm = jnp.take(flat, kept_perm(), axis=0).T
         srt_cm = srt_cm.astype(jnp.float32)
         # slot column: keep only each row's FIRST occurrence (plan mask), so
         # the scatter-sum returns that occurrence's slot exactly — no
@@ -302,7 +357,13 @@ def push_and_update(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
         # deterministically (≙ the reference's per-key slot from its merge
         # position, box_wrapper.cu:417 PushMergeCopy)
         srt_cm = srt_cm.at[w - 1, :].mul(first_occ)
+    wp = sp.padded_width(w)
+    if wp != w:                     # whole kernel blocks of rows
+        srt_cm = jnp.concatenate(
+            [srt_cm, jnp.zeros((wp - w, kd.p_pad), jnp.float32)], axis=0)
     delta = sp.scatter_add_sorted(srt_cm, rows2d, ch, tl, fs, kd,
                                   interpret=interpret)     # [D+4, n_kernel]
+    if wp != w:
+        delta = delta[:w]
     acc = acc_from_delta(delta, n, d_main=ws["mf"].shape[1])
     return sparse_opt.apply_push(ws, acc, cfg)

@@ -92,6 +92,15 @@ class SparseTrainer:
         need = set(getattr(model, "extra_inputs", ()))
         have = ({"rank_offset", "ads_offset"}
                 | {s.name for s in feed_config.string_slots})
+        # a model that owns its loss over unpooled rows (models/looplm.py)
+        # says so on its class; the slot it names in seq_key_slot fills the
+        # seq_keys plane with its raw keys
+        self._row_model = bool(getattr(model, "row_inputs", False))
+        self._seq_key_slot = None
+        if getattr(model, "seq_key_slot", None) is not None:
+            self._seq_key_slot = feed_config.sparse_slots[
+                model.seq_key_slot].name
+            have.add("seq_keys")
         unknown = need - have
         if unknown:
             raise ValueError(
@@ -239,6 +248,17 @@ class SparseTrainer:
         instead of silently training wrong."""
         has_ex = "mf_ex" in self.engine.ws
         is_adagrad = self.engine.config.sgd.optimizer == "adagrad"
+        if self._row_model and (path != "mxu" or has_ex
+                                or self._dym_mask is not None
+                                or self.async_dense is not None
+                                or self.amp or self.wuauc is not None
+                                or self.trainer_config.dump_path):
+            raise ValueError(
+                "a model that takes unpooled rows (row_inputs) trains on "
+                "the single-device mxu path, without an expand embedding, "
+                "per-slot mf dims, amp, the async dense table, per-user AUC "
+                "or a prediction dump "
+                f"(resolved path {path!r})")
         if path == "mxu":
             if has_ex and self._dym_mask is not None:
                 raise ValueError(
@@ -312,11 +332,12 @@ class SparseTrainer:
         packer (transposed + planned in-step)."""
         path = self._resolve_path()
         self._validate_path(path)
-        if path == "ragged":
+        if path == "ragged" or self._row_model:
             raise ValueError(
-                "sparse_path='ragged' requires the pass-resident feed "
-                "(build_pass_feed / train_pass(feed)) — the streaming "
-                "per-batch path has no host CSR plan build")
+                "sparse_path='ragged' and models that take unpooled rows "
+                "require the pass-resident feed (build_pass_feed / "
+                "train_pass(feed)) — the streaming per-batch path has no "
+                "host CSR plan build and no key planes")
         crossing = ("take", "take")
         if path == "mxu":
             crossing = self._crossing_modes(
@@ -381,6 +402,32 @@ class SparseTrainer:
 
         return half
 
+    def _rows_dense_half(self):
+        """The dense half for a model that owns its loss (``row_inputs``):
+        it is handed the slots' unpooled rows, lengths and its extras,
+        returns ``(loss, aux)``; gradients flow to the parameters and to
+        the rows, the AUC accumulator is fed the model's own pairs, and
+        ``aux["stats"]`` takes the place of the per-example predictions
+        in the step's outputs."""
+        model = self.model
+        dense_tx = self.dense_tx
+
+        def half(params, opt_state, auc_state, rows, lengths, valid,
+                 extras):
+            kw = {k: extras[k] for k in getattr(model, "extra_inputs", ())}
+            (loss, aux), (d_params, d_rows) = jax.value_and_grad(
+                lambda p, x: model.loss(p, x, lengths, valid, **kw),
+                argnums=(0, 1), has_aux=True)(params, rows)
+            with jax.named_scope("dense.adam"):
+                updates, opt_state = dense_tx.update(d_params, opt_state,
+                                                     params)
+                params = optax.apply_updates(params, updates)
+            auc_state = accumulate_auc(auc_state, aux["auc_pred"],
+                                       aux["auc_label"], aux["auc_mask"])
+            return params, opt_state, auc_state, loss, aux["stats"], d_rows
+
+        return half
+
     def _make_core(self, path: str, crossing=("take", "take")):
         """Shared per-path step body, used by BOTH the per-batch and the
         pass-resident builders (single source of step semantics).
@@ -403,6 +450,40 @@ class SparseTrainer:
             # gather/scatter
             from paddlebox_tpu.ps import mxu_path
             interpret = jax.default_backend() == "cpu"
+            if self._row_model:
+                rows_half = self._rows_dense_half()
+
+                def core(ws, params, opt_state, auc_state, idx_slb, lengths,
+                         dense, labels, valid, plan, extras=None):
+                    s, l, b = idx_slb.shape
+                    dims = mxu_path.make_dims(s * l * b, ws["show"].shape[0])
+                    if plan is None:
+                        raise ValueError(
+                            "a model that takes unpooled rows trains from a "
+                            "feed with precomputed plans (build_pass_feed)")
+                    with jax.named_scope("seq.pull"):
+                        v = mxu_path.pull_rows(ws, plan, dims, (s, l, b),
+                                               interpret=interpret)
+                        rows = jax.lax.stop_gradient(
+                            jnp.transpose(v[..., 3:], (2, 0, 1, 3)))
+                    (params, opt_state, auc_state, loss, stats,
+                     d_rows) = rows_half(params, opt_state, auc_state, rows,
+                                         lengths.T, valid, extras)
+                    with jax.named_scope("seq.push"):
+                        # the tower's own columns: embed_w gets no gradient,
+                        # show/click the instance's counts as in every push
+                        d_mf = jnp.transpose(d_rows, (1, 2, 0, 3))
+                        d_occ = jnp.concatenate(
+                            [jnp.zeros(d_mf.shape[:3] + (1,), d_mf.dtype),
+                             d_mf], axis=-1)
+                        ins_cvm = jnp.stack([jnp.ones_like(labels), labels],
+                                            axis=1)
+                        ws = mxu_path.push_and_update(
+                            ws, plan, dims, idx_slb, None, ins_cvm,
+                            slot_ids, sgd_cfg, interpret=interpret,
+                            d_occ=d_occ)
+                    return ws, params, opt_state, auc_state, loss, stats
+                return core
             half = self._pooled_dense_half()
 
             def core(ws, params, opt_state, auc_state, idx_slb, lengths,
@@ -689,7 +770,8 @@ class SparseTrainer:
                               self.batch_size, label,
                               key_mapper=(self.engine.mapper if mapper is None
                                           else mapper),
-                              batch_counts=counts, on_plane=on_plane)
+                              batch_counts=counts, on_plane=on_plane,
+                              seq_key_slot=self._seq_key_slot)
         if self.sparse_path == "ragged":
             # lower the packed pass to CSR here so the PR 7 prefetcher's
             # worker thread hides the build under pass N's training ("auto"
@@ -857,7 +939,8 @@ class SparseTrainer:
                      if path == "mxu_sharded" else False)
         crossing = ("take", "take")
         planes = with_plans and "bs" in feed.plans
-        if path == "mxu":
+        if path == "mxu" and not self._row_model:
+            # (unpooled rows cross by take: a sort has one operand a column)
             eff_p_pad = None
             if with_plans:
                 r = feed.plans["rows2d"].shape      # [N, n_chunks, 1, c]
@@ -942,6 +1025,7 @@ class SparseTrainer:
         opt_state, auc_state = self.opt_state, self.auc_state
         plans = feed.plans if feed.plans is not None else {}
         losses = []
+        row_stats = []     # a row model's per-step stats (its "preds")
         n_batches = 0
         dump_file = None
         if self.trainer_config.dump_path:
@@ -1006,6 +1090,8 @@ class SparseTrainer:
                         self.wuauc.add_data(np.asarray(preds), lbl,
                                             feed.uid[sl], feed.host_valid[sl])
                     losses.append(loss)
+                    if self._row_model:
+                        row_stats.append(preds)
                     n_batches += 1
                     if progress is not None:
                         progress(n_batches)
@@ -1022,6 +1108,10 @@ class SparseTrainer:
             # one stacked device->host sync, not one RPC per batch scalar
             per_step = np.asarray(jnp.stack(losses)) if losses \
                 else np.zeros((0,), np.float32)
+            if row_stats:
+                self.model.record_stats(
+                    np.asarray(jnp.sum(jnp.stack(row_stats), axis=0)),
+                    n_batches)
         out["loss"] = float(per_step.mean()) if losses else float("nan")
         out["losses"] = [float(x) for x in per_step]
         return out

@@ -392,3 +392,77 @@ def test_crossing_bf16_close_to_f32():
                                   np.asarray(f32_ws["slot"])[touched])
     np.testing.assert_array_equal(np.asarray(legacy_bf_ws["slot"])[touched],
                                   np.asarray(f32_ws["slot"])[touched])
+
+
+# -- rows pulled and pushed per position (a sequence model's slot) ----------
+
+def _sequence_batch(n, L, B, seed=3):
+    """One sequence slot: [1, L, B] rows with a padded tail, row 0 beyond a
+    sequence's length, and a token repeated inside one sequence."""
+    rng = np.random.default_rng(seed)
+    lengths = rng.integers(2, L + 1, (1, B)).astype(np.int32)
+    idx = rng.integers(1, n, (1, L, B)).astype(np.int32)
+    idx[0, 1, :] = idx[0, 0, :]                  # the repeat
+    idx[0] = np.where(np.arange(L)[:, None] < lengths, idx[0], 0)
+    return idx, lengths
+
+
+def test_unpooled_pull_is_the_masked_row_of_every_position():
+    n, D, L, B = 300, 160, 6, 8                  # 164 rows: two W blocks
+    ws = _make_ws(n, D)
+    idx, _ = _sequence_batch(n, L, B)
+    dims = mxu_path.make_dims(L * B, n)
+    plan = mxu_path.build_plan(jnp.asarray(idx), dims)
+    v = mxu_path.pull_rows(ws, plan, dims, (1, L, B), interpret=True)
+    assert v.shape == (1, L, B, 3 + D)
+    created = (np.asarray(ws["mf_size"]) > 0)[idx]
+    want = np.asarray(ws["mf"])[idx] * created[..., None]
+    np.testing.assert_allclose(np.asarray(v[..., 3:]), want, atol=1e-5,
+                               rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(v[..., 2]),
+                               np.asarray(ws["embed_w"])[idx], atol=1e-5)
+    assert (np.asarray(v)[idx == 0] == 0).all()
+
+
+@pytest.mark.parametrize("planes", [False, True])
+@pytest.mark.parametrize("trim", [False, True])
+def test_unpooled_push_adds_every_occurrences_own_gradient(planes, trim):
+    """``d_occ``: the merged accumulators are ``.at[].add`` of the
+    per-occurrence gradients (a token repeated inside a sequence adds
+    twice), then the same sparse rule."""
+    from paddlebox_tpu.ops import sorted_spmm as sp
+    n, D, L, B = 300, 160, 6, 8
+    cfg = SparseSGDConfig(mf_create_thresholds=0.0)
+    ws = _make_ws(n, D)
+    idx, lengths = _sequence_batch(n, L, B)
+    rng = np.random.default_rng(5)
+    d_occ = rng.normal(0, 1, (1, L, B, 1 + D)).astype(np.float32)
+    d_occ[..., 0] = 0.0                          # embed_w: no gradient
+    labels = rng.integers(0, 2, B).astype(np.float32)
+    ins_cvm = jnp.asarray(np.stack([np.ones(B), labels], 1), jnp.float32)
+    slot_ids = jnp.asarray([100], jnp.int32)
+    dims = sp.spmm_dims(L * B, n, chunk=8, tile=32)
+    eff = sp.trimmed_dims(dims, int((idx != 0).sum())) if trim else None
+    plan = mxu_path.build_plan(jnp.asarray(idx), dims, eff)
+    if planes:
+        plan = _static_planes(plan, dims, eff, labels, slot_ids, 1, L, B)
+    got = mxu_path.push_and_update(ws, plan, dims, jnp.asarray(idx), None,
+                                   ins_cvm, slot_ids, cfg, interpret=True,
+                                   d_occ=jnp.asarray(d_occ))
+    flat = idx.reshape(-1)
+    real = (flat != 0).astype(np.float32)
+    acc = {"g_show": jnp.zeros(n).at[flat].add(real),
+           "g_click": jnp.zeros(n).at[flat].add(real * np.tile(labels, L)),
+           "g_embed": jnp.zeros(n),
+           "g_embedx": jnp.zeros((n, D)).at[flat].add(
+               d_occ.reshape(-1, 1 + D)[:, 1:] * real[:, None]),
+           "slot": jnp.where(jnp.zeros(n).at[flat].add(real) > 0, 100, 0)}
+    want = sparse_opt.apply_push(ws, acc, cfg)
+    for k in want:
+        np.testing.assert_allclose(np.asarray(got[k])[1:],
+                                   np.asarray(want[k])[1:], atol=2e-4,
+                                   rtol=1e-4, err_msg=k)
+    with pytest.raises(ValueError):
+        mxu_path.push_and_update(ws, plan, dims, jnp.asarray(idx), None,
+                                 ins_cvm, slot_ids, cfg, interpret=True,
+                                 crossing="sort", d_occ=jnp.asarray(d_occ))

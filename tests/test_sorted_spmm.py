@@ -189,3 +189,75 @@ def test_fuzz_random_geometries():
         rows[rng.random(p) < float(rng.random()) * 0.6] = 0
         _run(rows, n_rows, w=int(rng.integers(1, 9)), chunk=chunk,
              tile=tile, seed=trial, trim=bool(rng.random() < 0.5))
+
+
+# -- a width of several W blocks (a sequence model's 2052-wide row) ---------
+
+@pytest.mark.parametrize("w,trim", [(384, False), (384, True), (256, False)])
+def test_wide_rows_take_several_blocks_and_match_the_dense_forms(w, trim):
+    """Past W_BLOCK feature rows both kernels cut W into blocks along a
+    leading grid axis; the one-hot does not depend on it, so the result
+    is the narrow kernels': equal to the dense reference (``_run``) and to
+    the ``_xla`` forms."""
+    assert w > sp.W_BLOCK and sp.padded_width(w) == w
+    rng = np.random.default_rng(11)
+    rows = rng.integers(1, 200, 300).astype(np.int32)
+    if trim:
+        rows[:120] = 0
+    _run(rows, 200, w=w, trim=trim)
+    dims = sp.spmm_dims(len(rows), 200, chunk=8, tile=32)
+    plan = sp.build_plan(jnp.asarray(rows), dims)
+    rows2d, _, _, ch, tl, fg, fs, _ = plan
+    table = np.zeros((w, dims.n_kernel), np.float32)    # sentinel tile: 0
+    table[:, :200] = rng.normal(0, 1, (w, 200))
+    table = jnp.asarray(table)
+    pay = jnp.asarray(rng.normal(0, 1, (w, dims.p_pad)), jnp.float32)
+    np.testing.assert_allclose(
+        sp.gather_sorted(table, rows2d, ch, tl, fg, dims, interpret=True),
+        sp.gather_sorted_xla(table, rows2d, ch, tl, fg, dims),
+        atol=1e-3, rtol=1e-3)
+    got = sp.scatter_add_sorted(pay, rows2d, ch, tl, fs, dims,
+                                interpret=True)
+    want = sp.scatter_add_sorted_xla(pay, rows2d, ch, tl, fs, dims)
+    keep = slice(0, dims.n_kernel - dims.tile)     # not the sentinel tile
+    np.testing.assert_allclose(got[:, keep], want[:, keep], atol=1e-2,
+                               rtol=1e-3)
+
+
+def _grids(w):
+    """The grids of the two kernels' pallas_calls at table width ``w``."""
+    dims = sp.spmm_dims(64, 100, chunk=8, tile=32)
+    i32 = jnp.int32
+    plan = (jnp.zeros((dims.n_chunks, 1, 8), i32),) + tuple(
+        jnp.zeros((dims.n_work,), i32) for _ in range(3))
+    out = []
+    for fn, cols in ((sp.gather_sorted, dims.n_kernel),
+                     (sp.scatter_add_sorted, dims.p_pad)):
+        jaxpr = jax.make_jaxpr(lambda a, r, c, t, f: fn(
+            a, r, c, t, f, dims, interpret=True))(
+            jnp.zeros((w, cols), jnp.float32), *plan)
+        call = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        assert len(call) == 1
+        out.append(tuple(call[0].params["grid_mapping"].grid))
+    return dims, out
+
+
+@pytest.mark.parametrize("w", [12, 36])
+def test_ctr_widths_lower_as_before(w):
+    """The accepted configurations' tables (W = 12, 36) stay one block of
+    rows: a one-axis grid over the worklist, no pad, the programs they
+    had."""
+    dims, grids = _grids(w)
+    assert sp.padded_width(w) == w
+    assert grids == [(dims.n_work,), (dims.n_work,)]
+
+
+def test_a_2052_wide_table_runs_a_grid_over_its_rows():
+    """Built at ``padded_width`` (the caller's part, ``mxu_path``): 17
+    blocks of rows; a height that is no whole number of blocks is
+    refused, not padded by a copy."""
+    assert sp.padded_width(2052) == 2176 and sp.padded_width(300) == 384
+    dims, grids = _grids(2176)
+    assert grids == [(17, dims.n_work), (17, dims.n_work)]
+    with pytest.raises(ValueError, match="whole blocks"):
+        _grids(2052)

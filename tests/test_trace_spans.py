@@ -265,3 +265,53 @@ def test_no_second_annotation_site():
                         hits.append(os.path.relpath(
                             os.path.join(base, n), pkg))
     assert hits == [os.path.join("utils", "trace.py")]
+
+
+# -- a sequence model's spans, scopes and counters (models/looplm.py) -------
+
+def test_sequence_tower_spans_scopes_and_counters(tmp_path):
+    """What a pass of the looped language model leaves behind: the host
+    span of the key plane, the ``tower.*`` counters, and the named scopes
+    of the step that a device trace is read by."""
+    import jax
+    from looplm_fixture import BATCH, CAP, config, fleet_run
+    from paddlebox_tpu.trainer.trainer import SparseTrainer
+    cfg = config(layers=1, steps=2)
+    trainer, metrics, engine = fleet_run(tmp_path, cfg, passes=1,
+                                         trainer_cls=SparseTrainer)
+    stats = stat_snapshot()
+    assert stats["data.feed.seq_keys_s.count"] == 1
+    steps = metrics[0]["batches"]
+    assert stats["tower.recurrent_steps"] == 2 * steps
+    assert stats["tower.tokens_valid"] + stats["tower.tokens_padded"] == \
+        steps * BATCH * CAP
+    assert 0 < stats["tower.tokens_valid"] < steps * BATCH * CAP
+    assert 1.0 <= stats["tower.exit_expected_step"] <= 2.0
+    # the jitted function keeps its name, the scopes are in its lowering
+    assert trainer._packed_step_fn.__name__ == "step"
+    engine.begin_feed_pass()
+    engine.add_keys(np.arange(1, 9, dtype=np.uint64))
+    engine.end_feed_pass()
+    engine.begin_pass()
+    i32 = np.int32
+    data = {"indices": np.zeros((1, 1, CAP, BATCH), i32),
+            "lengths": np.full((1, 1, BATCH), 3, i32),
+            "dense": np.zeros((1, BATCH, 1), np.float32),
+            "labels": np.zeros((1, BATCH), np.float32),
+            "valid": np.ones((1, BATCH), bool),
+            "seq_keys": np.ones((1, BATCH, CAP), i32)}
+    from paddlebox_tpu.ps import mxu_path
+    core = trainer._make_core("mxu")
+    plan = mxu_path.build_plan(
+        data["indices"][0], mxu_path.make_dims(
+            CAP * BATCH, engine.ws["show"].shape[0]))
+    text = jax.jit(lambda ws, p, o, a: core(
+        ws, p, o, a, data["indices"][0], data["lengths"][0],
+        data["dense"][0], data["labels"][0], data["valid"][0], plan,
+        {"seq_keys": data["seq_keys"][0]})).lower(
+        engine.ws, trainer.params, trainer.opt_state, trainer.auc_state
+    ).as_text(debug_info=True)
+    for scope in ("seq.pull", "tower.ut", "tower.head_loss", "seq.push",
+                  "dense.adam"):
+        assert scope in text, scope
+    engine.end_pass()
