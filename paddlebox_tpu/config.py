@@ -19,7 +19,13 @@ class SlotConfig:
 
     ``capacity`` is the static per-instance feasign capacity used to pad
     variable-length slots for XLA (the reference carries true var-len LoD;
-    under jit we need fixed shapes — SURVEY.md §7 hard part (5)).
+    under jit we need fixed shapes — SURVEY.md §7 hard part (5)).  It is
+    this slot's own and enforced at pack: both packers clip a record at
+    its slot's capacity (BatchPacker.pad_sparse; a key beyond it is neither
+    pulled nor pushed, and counted in ``data.pack.clipped_keys``).  The
+    feed stores every slot at the widest one's capacity ([S, L, B]); the
+    pooled pull crossing walks only ``sum(capacity) * B`` of it
+    (ps/mxu_path.pull_pool_cvm), so declare what the slot holds.
     """
 
     name: str

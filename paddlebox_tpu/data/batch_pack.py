@@ -22,6 +22,7 @@ import numpy as np
 
 from paddlebox_tpu.config import DataFeedConfig, SlotConfig
 from paddlebox_tpu.data.slot_record import SlotRecordBlock
+from paddlebox_tpu.utils.monitor import stat_add
 
 
 @dataclasses.dataclass
@@ -61,20 +62,38 @@ class BatchPacker:
         self.dense_dim = sum(s.dim for s in self.dense_slots)
 
     def _pad_ragged(self, values: np.ndarray, offsets: np.ndarray,
-                    cap: int):
-        """ragged (values, offsets[n+1]) → padded [n, cap] + lengths [n]."""
+                    cap: int, width: Optional[int] = None):
+        """ragged (values, offsets[n+1]) → padded [n, width] + lengths [n]
+        clipped at ``cap`` (width defaults to cap; a sparse slot is clipped
+        at its own capacity and stored at the widest slot's)."""
         lens = np.diff(offsets)
         clipped = np.minimum(lens, cap).astype(np.int32)
         n = len(lens)
-        col = np.arange(cap, dtype=np.int64)[None, :]
+        width = cap if width is None else width
+        col = np.arange(width, dtype=np.int64)[None, :]
         gather = offsets[:-1, None] + col
         mask = col < clipped[:, None]
         gather = np.where(mask, gather, 0)
         if len(values) == 0:
-            padded = np.zeros((n, cap), dtype=values.dtype)
+            padded = np.zeros((n, width), dtype=values.dtype)
         else:
             padded = np.where(mask, values[gather], values.dtype.type(0))
         return padded, clipped
+
+    def pad_sparse(self, slot: SlotConfig, values: np.ndarray,
+                   offsets: np.ndarray):
+        """One sparse slot's records → [n, self.capacity] + lengths [n].
+        A record is clipped at ITS slot's capacity (SlotConfig.capacity),
+        so positions at or beyond it hold padding in every plane: the
+        invariant the pooled pull crossing (ps/mxu_path.pull_pool_cvm)
+        relies on to skip them.  A clipped key is neither pulled nor
+        pushed, and is counted (``data.pack.clipped_keys``)."""
+        padded, lens = self._pad_ragged(values, offsets, slot.capacity,
+                                        self.capacity)
+        clipped = int(offsets[-1] - offsets[0]) - int(lens.sum())
+        if clipped:
+            stat_add("data.pack.clipped_keys", float(clipped))
+        return padded, lens
 
     def pack(self, block: SlotRecordBlock,
              key_mapper: Optional[Callable[[np.ndarray], np.ndarray]] = None
@@ -88,7 +107,7 @@ class BatchPacker:
         lengths = np.zeros((S, B), dtype=np.int32)
         for si, slot in enumerate(self.sparse_slots):
             values, offsets = block.uint64_slots[slot.name]
-            padded, lens = self._pad_ragged(values, offsets, L)
+            padded, lens = self.pad_sparse(slot, values, offsets)
             keys[si, :n] = padded
             lengths[si, :n] = lens
 

@@ -234,9 +234,10 @@ def pack_pass(blocks: Sequence[SlotRecordBlock], feed_config: DataFeedConfig,
                 "pack_pass without a key_mapper stores raw feasigns in the "
                 "int32 index plane; keys exceed int32 — pass the engine's "
                 "PassKeyMapper (engine.mapper)")
-        # _pad_ragged zero-fills positions beyond each record's length, so
-        # padding already lands on the reserved zero row — no re-mask pass
-        padded, lens = packer._pad_ragged(v, o, L)
+        # zero-filled beyond each record's length (clipped at the slot's
+        # own capacity), so padding already lands on the reserved zero
+        # row — no re-mask pass
+        padded, lens = packer.pad_sparse(slot, v, o)
         rows = rows_of(r0, r1)
         indices[si, rows] = padded
         lengths[si, rows] = lens

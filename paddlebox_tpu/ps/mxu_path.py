@@ -26,7 +26,9 @@ it is built at the kernels' padded height.
 
 Two consumers of the canonical values: the pooled CTR towers sum a slot's
 rows over its capacity (`pull_pool_cvm`, and `d_pooled` is broadcast back
-over L in the push); a model that takes its rows unpooled (a sequence
+over L in the push; [S, L, B] is storage at the widest slot's capacity, and
+the pull crossing takes only the positions each slot's own declared
+capacity can hold, one take a group of slots of equal capacity); a model that takes its rows unpooled (a sequence
 tower, `models/looplm.py`) reads `pull_rows` [S, L, B, 3 + D] as they are
 and hands `push_and_update` a gradient per occurrence (`d_occ`), merged by
 key like any other.
@@ -34,7 +36,8 @@ key like any other.
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+import itertools
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -167,6 +170,41 @@ def acc_from_delta(delta: jnp.ndarray, n: int,
     return acc
 
 
+def _pull_sorted(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
+                 interpret: bool) -> jnp.ndarray:
+    """The sorted-domain half of a pull, [3 + D, p_pad]: show, click,
+    embed_w and the mf columns times the row's created mask, one column a
+    kept sorted position."""
+    from paddlebox_tpu import flags
+    d = ws["mf"].shape[1] + _ex_dim(ws)
+    rows2d, ch, tl, fg = plan[0], plan[3], plan[4], plan[5]
+    tab = _pull_table(ws, dims)
+    g = sp.gather_sorted(tab, rows2d, ch, tl, fg,
+                         plan_eff_dims(plan, dims) or dims,
+                         interpret=interpret)              # [3+D+1, p_pad]
+    # created-mask the mf rows in the SORTED domain: the mf_size column is
+    # consumed here and never rides the crossing (w shrinks by 1, and the
+    # canonical-domain mask multiply disappears)
+    created = (g[3 + d:4 + d] > 0).astype(g.dtype)         # [1, p_pad]
+    g = jnp.concatenate([g[:3], g[3:3 + d] * created], axis=0)
+    if flags.get_flags("mxu_crossing_bf16"):
+        g = g.astype(jnp.bfloat16)
+    return g
+
+
+def _take_canonical(g: jnp.ndarray, inv_perm: jnp.ndarray,
+                    dims: sp.SpmmDims, trimmed: bool) -> jnp.ndarray:
+    """The "take" pull crossing: sorted columns ``g`` [W, p_pad] → one row
+    [W] a canonical position of ``inv_perm`` (all of the plan's, or any
+    subset of them)."""
+    if not trimmed:
+        return jnp.take(g.T[:dims.p], inv_perm, axis=0)    # canonical [p,W]
+    # trimmed plan: dropped positions (inv_perm < 0) were row-0
+    # occurrences whose pull value is exactly zero — clamp + mask
+    v = jnp.take(g.T, jnp.maximum(inv_perm, 0), axis=0)
+    return v * (inv_perm >= 0).astype(v.dtype)[:, None]
+
+
 def pull_rows(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
               shape_slb: Tuple[int, int, int], interpret: bool = False,
               crossing: str = "take") -> jnp.ndarray:
@@ -178,24 +216,13 @@ def pull_rows(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
     crossing: sorted→canonical lowering (ops/crossing.py) — "take" gathers
     by inv_perm, "sort" re-sorts keyed by perm (the destination index).
     """
-    from paddlebox_tpu import flags
     from paddlebox_tpu.ops import crossing as cx
     assert crossing in ("take", "sort"), crossing
     s, l, b = shape_slb
-    d = ws["mf"].shape[1] + _ex_dim(ws)
-    rows2d, perm, inv_perm, ch, tl, fg, fs, first_occ = plan[:8]
+    perm, inv_perm = plan[1], plan[2]
     eff = plan_eff_dims(plan, dims)
-    tab = _pull_table(ws, dims)
-    g = sp.gather_sorted(tab, rows2d, ch, tl, fg, eff or dims,
-                         interpret=interpret)              # [3+D+1, p_pad]
-    # created-mask the mf rows in the SORTED domain: the mf_size column is
-    # consumed here and never rides the crossing (w shrinks by 1, and the
-    # canonical-domain mask multiply disappears)
-    created = (g[3 + d:4 + d] > 0).astype(g.dtype)         # [1, p_pad]
-    g = jnp.concatenate([g[:3], g[3:3 + d] * created], axis=0)
-    w = 3 + d
-    if flags.get_flags("mxu_crossing_bf16"):
-        g = g.astype(jnp.bfloat16)
+    g = _pull_sorted(ws, plan, dims, interpret)
+    w = g.shape[0]
     if crossing == "sort":
         if eff is not None:
             # dropped (row-0) positions re-enter as leading zero columns —
@@ -203,24 +230,82 @@ def pull_rows(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
             p0 = dims.p_pad - eff.p_pad
             g = jnp.concatenate([jnp.zeros((w, p0), g.dtype), g], axis=1)
         v = cx.permute_by_dest(tuple(g[:, :dims.p]), perm).T  # [p, W]
-    elif eff is None:
-        v = jnp.take(g.T[:dims.p], inv_perm, axis=0)       # canonical [p,W]
     else:
-        # trimmed plan: dropped positions (inv_perm < 0) were row-0
-        # occurrences whose pull value is exactly zero — clamp + mask
-        v = jnp.take(g.T, jnp.maximum(inv_perm, 0), axis=0)
-        v = v * (inv_perm >= 0).astype(v.dtype)[:, None]
+        v = _take_canonical(g, inv_perm, dims, eff is not None)
     return v.reshape(s, l, b, w).astype(jnp.float32)
+
+
+def capacity_groups(capacities: Optional[Sequence[int]], s: int,
+                    l: int) -> Tuple[Tuple[int, Tuple[int, ...]], ...]:
+    """Slots of equal declared capacity, ``((c, slots), ...)`` by rising
+    ``c``; None = every slot holds ``l``.  The canonical rectangle
+    [S, L, B] is storage at the widest slot's capacity; a slot's positions
+    at or beyond its own hold padding in every batch (the packers clip
+    there: BatchPacker.pad_sparse)."""
+    if capacities is None:
+        return ((l, tuple(range(s))),)
+    if len(capacities) != s or not all(1 <= c <= l for c in capacities):
+        raise ValueError(
+            f"capacities {tuple(capacities)} do not fit {s} slots of at "
+            f"most {l} positions")
+    return tuple((c, tuple(i for i in range(s) if capacities[i] == c))
+                 for c in sorted(set(capacities)))
+
+
+def pull_cross_rows(capacities: Optional[Sequence[int]],
+                    shape_slb: Tuple[int, int, int],
+                    crossing: str = "take") -> int:
+    """Rows the pooled pull crossing emits a step (the gauge
+    ``ps.mxu.pull_cross_rows``): the positions the slots can hold under
+    "take", the whole rectangle under "sort"."""
+    s, l, b = shape_slb
+    if crossing != "take":
+        return s * l * b
+    return b * sum(c * len(slots)
+                   for c, slots in capacity_groups(capacities, s, l))
+
+
+def _runs(slots: Sequence[int]):
+    """Consecutive runs of an ascending slot list, as (first position in
+    the list, first slot, length)."""
+    for _, run in itertools.groupby(enumerate(slots), lambda t: t[1] - t[0]):
+        run = list(run)
+        yield run[0] + (len(run),)
 
 
 def pull_pool_cvm(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
                   shape_slb: Tuple[int, int, int], use_cvm: bool = True,
-                  interpret: bool = False,
-                  crossing: str = "take") -> jnp.ndarray:
+                  interpret: bool = False, crossing: str = "take",
+                  capacities: Optional[Sequence[int]] = None
+                  ) -> jnp.ndarray:
     """Fused pull + seqpool + CVM → pooled [B, S, 3 + D]: ``pull_rows``
-    summed over each slot's capacity."""
-    v = pull_rows(ws, plan, dims, shape_slb, interpret, crossing)
-    return pool_cvm_values(v, use_cvm, premasked=True)
+    summed over each slot's capacity.
+
+    capacities: the slots' declared capacities (static).  The "take"
+    crossing then runs once a capacity group and emits only the positions
+    a slot can hold, ``sum(capacities) * B`` rows and not ``S * L * B``:
+    the ones left out held exact zeros, so the sums are the same.  One
+    group (every slot declares the same, or None) is the full-rectangle
+    path, op for op."""
+    s, l, b = shape_slb
+    groups = capacity_groups(capacities, s, l)
+    if len(groups) == 1 or crossing != "take":
+        v = pull_rows(ws, plan, dims, shape_slb, interpret, crossing)
+        return pool_cvm_values(v, use_cvm, premasked=True)
+    g = _pull_sorted(ws, plan, dims, interpret)
+    trimmed = plan_eff_dims(plan, dims) is not None
+    ip = plan[2].reshape(s, l, b)
+    pieces = []                 # (first slot, pooled [B, run, 3 + D])
+    for c, slots in groups:
+        runs = list(_runs(slots))
+        ip_c = jnp.concatenate([ip[s0:s0 + n, :c] for _, s0, n in runs])
+        v = _take_canonical(g, ip_c.reshape(-1), dims, trimmed)
+        v = v.reshape(len(slots), c, b, -1).astype(jnp.float32)
+        pooled = pool_cvm_values(v, use_cvm, premasked=True)
+        pieces += [(s0, pooled[:, j:j + n]) for j, s0, n in runs]
+    # back into slot order: the runs' static slices, by first slot
+    return jnp.concatenate([x for _, x in sorted(pieces, key=lambda t: t[0])],
+                           axis=1)
 
 
 def occurrence_payload(d_occ: jnp.ndarray, ins_cvm: jnp.ndarray,

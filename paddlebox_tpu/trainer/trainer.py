@@ -39,7 +39,8 @@ from paddlebox_tpu.ps import embedding, optimizer as sparse_opt
 from paddlebox_tpu.ps.pass_manager import BoxPSEngine
 from paddlebox_tpu.utils import compile_cache, intervals, trace
 from paddlebox_tpu.utils.channel import Channel, ChannelClosed
-from paddlebox_tpu.utils.monitor import stat_observe, stat_snapshot
+from paddlebox_tpu.utils.monitor import (stat_observe, stat_set,
+                                         stat_snapshot)
 from paddlebox_tpu.utils.timer import TimerRegistry
 from paddlebox_tpu import flags
 
@@ -450,6 +451,16 @@ class SparseTrainer:
             # gather/scatter
             from paddlebox_tpu.ps import mxu_path
             interpret = jax.default_backend() == "cpu"
+            # a pooled slot's declared capacity bounds the positions the
+            # pull crossing takes; unpooled rows cross the whole rectangle
+            capacities = None if self._row_model else tuple(
+                sl.capacity for sl in self.packer.sparse_slots)
+            shape_slb = (len(self.packer.sparse_slots), self.packer.capacity,
+                         self.batch_size)
+            stat_set("ps.mxu.pull_cross_rows", float(
+                mxu_path.pull_cross_rows(capacities, shape_slb, crossing[0])))
+            stat_set("ps.mxu.pull_cross_rows_canonical",
+                     float(np.prod(shape_slb)))
             if self._row_model:
                 rows_half = self._rows_dense_half()
 
@@ -502,7 +513,7 @@ class SparseTrainer:
                     plan = mxu_path.build_plan(idx_slb, dims)
                 pooled = jax.lax.stop_gradient(mxu_path.pull_pool_cvm(
                     ws, plan, dims, (s, l, b), use_cvm, interpret=interpret,
-                    crossing=crossing[0]))
+                    crossing=crossing[0], capacities=capacities))
                 (params, opt_state, auc_state, loss, preds, d_pooled,
                  d_params) = half(params, opt_state, auc_state, pooled,
                                   dense, labels, valid, extras)

@@ -466,3 +466,106 @@ def test_unpooled_push_adds_every_occurrences_own_gradient(planes, trim):
         mxu_path.push_and_update(ws, plan, dims, jnp.asarray(idx), None,
                                  ins_cvm, slot_ids, cfg, interpret=True,
                                  crossing="sort", d_occ=jnp.asarray(d_occ))
+
+
+# -- the pooled pull crossing takes only the positions a slot can hold ------
+
+def _capacity_batch(n_rows, caps, B, seed=1):
+    """``_batch`` over slots that hold at most their declared capacity:
+    [S, max(caps), B] rows, padding at and beyond a slot's own capacity
+    (what the packers guarantee: BatchPacker.pad_sparse)."""
+    S, L = len(caps), max(caps)
+    idx, lengths, _, ins_cvm, slot_ids = _batch(n_rows, S, L, B, seed)
+    idx = np.array(idx)
+    for s_, c in enumerate(caps):
+        idx[s_, c:] = 0
+    return jnp.asarray(idx), ins_cvm, slot_ids
+
+
+def _pull_tower_push(pull, ws, plan, dims, idx, ins_cvm, slot_ids, cfg):
+    """One step: ``pull`` -> a small tower's gradient -> push_and_update;
+    returns (pooled, the working set after it)."""
+    pooled = pull(ws, plan, dims, idx.shape)
+    b = pooled.shape[0]
+    tower = jnp.asarray(np.random.default_rng(9).normal(
+        0, 0.3, (pooled.shape[1] * pooled.shape[2], 5)).astype(np.float32))
+    d_pooled = jax.grad(
+        lambda x: jnp.sum(jnp.tanh(x.reshape(b, -1) @ tower)))(pooled)
+    return pooled, mxu_path.push_and_update(
+        ws, plan, dims, idx, d_pooled, ins_cvm, slot_ids, cfg,
+        interpret=True)
+
+
+@pytest.mark.parametrize("use_cvm", [True, False])
+@pytest.mark.parametrize("trim", [False, True])
+@pytest.mark.parametrize("caps", [(1, 1, 1), (1, 16, 1, 16), (3, 1, 16)])
+def test_grouped_pull_equals_full_rectangle(caps, trim, use_cvm):
+    """The crossing run once a capacity group (only the positions a slot
+    can hold) gives the pooled values of the full [S, L, B] crossing bit
+    for bit — the positions left out held exact zeros — and the step
+    behind it leaves the same working set."""
+    from paddlebox_tpu.ops import sorted_spmm as sp
+    n, D, B = 300, 4, 16
+    S, L = len(caps), max(caps)
+    cfg = SparseSGDConfig(mf_create_thresholds=5.0)
+    ws = _make_ws(n, D)
+    idx, ins_cvm, slot_ids = _capacity_batch(n, caps, B)
+    dims = sp.spmm_dims(S * L * B, n, chunk=8, tile=32)
+    eff = None
+    if trim:
+        eff = sp.trimmed_dims(dims, int((np.asarray(idx) != 0).sum()))
+        assert L == 1 or eff.p_pad < dims.p_pad, "batch must actually trim"
+    plan = mxu_path.build_plan(idx, dims, eff)
+
+    def pull(capacities):
+        return lambda ws, plan, dims, shape: mxu_path.pull_pool_cvm(
+            ws, plan, dims, shape, use_cvm, interpret=True,
+            capacities=capacities)
+
+    got = _pull_tower_push(pull(caps), ws, plan, dims, idx, ins_cvm,
+                           slot_ids, cfg)
+    want = _pull_tower_push(pull(None), ws, plan, dims, idx, ins_cvm,
+                            slot_ids, cfg)
+    assert got[0].shape == (B, S, 3 + D)
+    for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    assert mxu_path.pull_cross_rows(caps, (S, L, B)) == sum(caps) * B
+    assert mxu_path.pull_cross_rows(caps, (S, L, B), "sort") == S * L * B
+
+
+def test_equal_capacities_lower_to_the_full_rectangle_step():
+    """One capacity group (DeepFM: every slot holds one key; any model
+    with one capacity) takes the full-rectangle path unchanged: the step's
+    StableHLO text is that of ``pull_rows`` + ``pool_cvm_values``."""
+    n, D, S, L, B = 300, 4, 5, 3, 16
+    cfg = SparseSGDConfig(mf_create_thresholds=5.0)
+    ws = _make_ws(n, D)
+    idx, _, _, ins_cvm, slot_ids = _batch(n, S, L, B)
+    dims = mxu_path.make_dims(S * L * B, n)
+    plan = mxu_path.build_plan(idx, dims)
+
+    def grouped(ws, plan, dims, shape):
+        return mxu_path.pull_pool_cvm(ws, plan, dims, shape, True,
+                                      interpret=True, capacities=(L,) * S)
+
+    def full(ws, plan, dims, shape):
+        return mxu_path.pool_cvm_values(
+            mxu_path.pull_rows(ws, plan, dims, shape, interpret=True),
+            True, premasked=True)
+
+    texts = [jax.jit(lambda ws, plan, pull=pull: _pull_tower_push(
+        pull, ws, plan, dims, idx, ins_cvm, slot_ids, cfg)
+    ).lower(ws, plan).as_text() for pull in (grouped, full)]
+    assert texts[0] == texts[1]
+    # and a model with two capacities does not
+    mixed = jax.jit(lambda ws, plan: mxu_path.pull_pool_cvm(
+        ws, plan, dims, (S, L, B), True, interpret=True,
+        capacities=(1, L, 1, L, L))).lower(ws, plan).as_text()
+    assert mixed.count("gather") > jax.jit(lambda ws, plan: full(
+        ws, plan, dims, (S, L, B))).lower(ws, plan).as_text().count("gather")
+
+
+@pytest.mark.parametrize("caps", [(1, 2), (1, 2, 4, 3), (0, 3, 3)])
+def test_capacities_must_fit_the_rectangle(caps):
+    with pytest.raises(ValueError, match="capacities"):
+        mxu_path.capacity_groups(caps, 3, 3)

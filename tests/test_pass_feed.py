@@ -20,10 +20,12 @@ N_SLOTS, DENSE_DIM, MF, CAP = 4, 3, 4, 3
 
 
 def _feed_config(n_slots=N_SLOTS, cap=CAP, dense_dim=DENSE_DIM):
+    """cap: one capacity for every slot, or one a slot."""
+    caps = [cap] * n_slots if isinstance(cap, int) else list(cap)
     return DataFeedConfig(slots=tuple(
         [SlotConfig("label", dtype="float", is_dense=True, dim=1),
          SlotConfig("dense0", dtype="float", is_dense=True, dim=dense_dim)]
-        + [SlotConfig(f"s{i}", slot_id=100 + i, capacity=cap)
+        + [SlotConfig(f"s{i}", slot_id=100 + i, capacity=caps[i])
            for i in range(n_slots)]))
 
 
@@ -45,8 +47,8 @@ def _make_block(rng, n, n_slots=N_SLOTS, cap=CAP, dense_dim=DENSE_DIM,
     return blk
 
 
-def _build(blocks, sparse_path="auto", batch_size=64):
-    cfg = _feed_config()
+def _build(blocks, sparse_path="auto", batch_size=64, cap=CAP):
+    cfg = _feed_config(cap=cap)
     ds = SlotDataset(cfg)
     ds._blocks = blocks
     eng = BoxPSEngine(EmbeddingTableConfig(
@@ -222,3 +224,73 @@ def test_first_occ_slot_exact_under_multi_slot_key():
     # duplicates of row 5: only the first sorted position is marked
     dup_pos = np.nonzero(srt == 5)[0]
     assert first_occ[dup_pos[0]] == 1.0 and first_occ[dup_pos[1]] == 0.0
+
+
+# -- a slot's declared capacity bounds what is packed, pulled and pushed ----
+
+MIXED_CAPS = (1, 3, 2, 3)
+
+
+@pytest.mark.parametrize("packer", ["batch", "pass"])
+def test_record_is_clipped_at_its_slots_capacity(packer):
+    """Both packers clip a record's keys at ITS slot's capacity (not the
+    widest slot's): ``lengths`` says so, the positions beyond hold
+    padding, and ``data.pack.clipped_keys`` counts what was left out."""
+    from paddlebox_tpu.data.batch_pack import BatchPacker
+    from paddlebox_tpu.utils.monitor import stat_get
+    rng = np.random.default_rng(21)
+    n, b = 40, 64
+    blk = _make_block(rng, n)           # every slot holds 1..CAP keys
+    cfg = _feed_config(cap=MIXED_CAPS)
+    before = stat_get("data.pack.clipped_keys")
+    if packer == "batch":
+        out = BatchPacker(cfg, b).pack(
+            blk, key_mapper=lambda ks: ks.astype(np.int64))
+        indices, lengths = out.indices, out.lengths     # [S, B, L]
+    else:
+        out = pack_pass([blk], cfg, b)
+        indices, lengths = out.indices, out.lengths     # [S, N*B, L]
+    assert indices.shape == (N_SLOTS, b, CAP)
+    clipped = 0
+    for si, cap in enumerate(MIXED_CAPS):
+        vals, offs = blk.uint64_slots[f"s{si}"]
+        lens = np.diff(offs)
+        np.testing.assert_array_equal(lengths[si, :n], np.minimum(lens, cap))
+        assert (indices[si, :, cap:] == 0).all()
+        for r in range(n):
+            k = min(int(lens[r]), cap)
+            np.testing.assert_array_equal(indices[si, r, :k],
+                                          vals[offs[r]:offs[r] + k])
+            assert (indices[si, r, k:] == 0).all()
+        clipped += int(np.maximum(lens - cap, 0).sum())
+    assert clipped > 0
+    assert stat_get("data.pack.clipped_keys") - before == clipped
+
+
+@pytest.mark.parametrize("resident", [True, False])
+def test_key_beyond_its_slots_capacity_is_neither_pulled_nor_pushed(resident):
+    """A key beyond its slot's declared capacity trains nothing: its row
+    receives no push (show stays as pulled), on the pass-resident feed and
+    on the per-batch path; and the step's gauges say how many rows the
+    pull crossing emits against the canonical rectangle."""
+    from paddlebox_tpu.utils.monitor import stat_get
+    rng = np.random.default_rng(22)
+    n, b = 128, 64
+    blk = _make_block(rng, n)
+    # slot s0 declares one key; every record carries a second, unique one
+    vals, offs = blk.uint64_slots["s0"]
+    first = vals[offs[:-1]]
+    extra = (10_000 + np.arange(n)).astype(np.uint64)
+    blk.uint64_slots["s0"] = (
+        np.stack([first, extra], axis=1).reshape(-1),
+        2 * np.arange(n + 1, dtype=np.int64))
+    ds, eng, tr = _build([blk], "mxu", batch_size=b, cap=(1, 3, 1, 3))
+    rows_extra, rows_first = eng.mapper(extra), eng.mapper(first)
+    assert (rows_extra > 0).all()       # in the working set, never trained
+    show0 = np.asarray(eng.ws["show"]).copy()
+    tr.train_pass(tr.build_pass_feed(ds) if resident else ds)
+    show1 = np.asarray(eng.ws["show"])
+    np.testing.assert_array_equal(show1[rows_extra], show0[rows_extra])
+    assert (show1[rows_first] > show0[rows_first]).all()
+    assert stat_get("ps.mxu.pull_cross_rows") == (1 + 3 + 1 + 3) * b
+    assert stat_get("ps.mxu.pull_cross_rows_canonical") == N_SLOTS * CAP * b
