@@ -21,10 +21,9 @@ TrainFilesWithProfiler, boxps_worker.cc:1358):
    name of the wedged phase — never a bare 0.0;
  * each phase has its own budget; a wedged phase fails fast;
  * `step_ms` breaks the device step into pull/dense/push phases for the
-   SELECTED sparse step path (BENCH_SPARSE_PATH, default ragged) and
-   profiles the padded-dense fast path side by side: `sparse_share` =
-   sparse / (sparse + dense) device time, `ragged_speedup` = fast-path
-   sparse time / selected-path sparse time.
+   SELECTED sparse step path (BENCH_SPARSE_PATH, default auto: the
+   trainer's own choice) and profiles the padded-dense fast path side by
+   side: `sparse_share` = sparse / (sparse + dense) device time.
 
 Geometry (full): 26 sparse slots with variable lengths 1..3 (capacity 3),
 13 dense features, mf_dim=8, 2M-key working set, B=16384.
@@ -360,13 +359,11 @@ def _profile_step_phases(trainer, feed, k=8):
     the no-op floor is subtracted.
 
     Profiles the SELECTED step path's pull/dense/push phases AND the
-    padded-dense fast path's pull/push side by side, so every record
-    carries the comparison the ragged path exists to win:
-    `sparse_share` = sparse / (sparse + dense), `ragged_speedup` =
-    fast sparse time / selected sparse time."""
+    padded-dense fast path's pull/push side by side:
+    `sparse_share` = sparse / (sparse + dense)."""
     import jax
     import jax.numpy as jnp
-    from paddlebox_tpu.ps import fast_path, mxu_path, ragged_path
+    from paddlebox_tpu.ps import fast_path, mxu_path
     from paddlebox_tpu.data.pass_feed import plan_tuple
 
     path = trainer._resolve_path()
@@ -429,14 +426,6 @@ def _profile_step_phases(trainer, feed, k=8):
     if path == "fast":
         pooled0 = fast_pooled0
         t_pull, t_push = t_fast_pull, t_fast_push
-    elif path == "ragged":
-        plan = plan_tuple(jax.tree.map(lambda a: a[0], feed.plans))
-        pooled0 = jax.jit(lambda w: ragged_path.pull_pool_cvm(
-            w, plan, (s, l, b), trainer.use_cvm))(ws)
-        t_pull = timed(lambda c: c + ragged_path.pull_pool_cvm(
-            vary(c), plan, (s, l, b), trainer.use_cvm).sum())
-        t_push = timed_ws(lambda w: ragged_path.push_and_update(
-            w, plan, pooled0, ins_cvm, (s, l, b), sgd_cfg))
     else:  # mxu
         dims = mxu_path.make_dims(s * l * b, n_rows)
         plan = plan_tuple(jax.tree.map(lambda a: a[0], feed.plans))
@@ -469,9 +458,6 @@ def _profile_step_phases(trainer, feed, k=8):
     sparse = out["pull_pool"] + out["push_optimizer"]
     total = sparse + out["dense_fwd_bwd"]
     out["sparse_share"] = round(sparse / total, 4) if total > 0 else 0.0
-    fast_sparse = out["fast_pull_pool"] + out["fast_push_optimizer"]
-    out["ragged_speedup"] = (round(fast_sparse / sparse, 2)
-                             if sparse > 0 else 0.0)
     return out
 
 
@@ -1772,11 +1758,8 @@ def run_config(tag, batch_size, n_batches, n_keys, pack_threads):
     # meta-optimizer ≙) — MXU-native precision for the MLP
     amp = os.environ.get("BENCH_AMP", "1") == "1"
     legacy = os.environ.get("BENCH_LEGACY_FEED") == "1"
-    # sparse step path: ragged (CSR [U]-domain kernels, ROADMAP item 1) is
-    # the default for the pass-resident feed; the legacy streaming feed
-    # can't carry a CSR plan, so it stays on the auto (mxu) resolution
-    sparse_path = os.environ.get("BENCH_SPARSE_PATH",
-                                 "auto" if legacy else "ragged")
+    # sparse step path: auto is the trainer's own choice (mxu on one chip)
+    sparse_path = os.environ.get("BENCH_SPARSE_PATH", "auto")
     trainer = SparseTrainer(engine, model, dataset.feed_config,
                             batch_size=batch_size, auc_table_size=100_000,
                             amp=amp, sparse_path=sparse_path)
@@ -1801,7 +1784,6 @@ def run_config(tag, batch_size, n_batches, n_keys, pack_threads):
             # kept fraction of the sorted domain after padding-trim
             # (sorted_spmm.trimmed_dims) — the kernel/push-crossing work
             # scales with this; plan_dims holds the untrimmed geometry
-            # (mxu plans only; ragged CSR plans have no trimmed domain)
             trim_frac = (feed.plans["rows2d"].shape[1]
                          / feed.plan_dims.n_chunks)
         record(**{f"{tag}_pass_pack_s": round(pack_s, 1),

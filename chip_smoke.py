@@ -9,12 +9,13 @@ One process (it holds the chip) drives the path a user drives: slot-format
 text files written from a seed, ``fleet.init`` -> ``BoxPSDataset`` ->
 ``fleet.train_passes`` (serial loop: load_into_memory / begin_pass /
 build_pass_feed / train_pass / end_pass) for two passes of a few batches,
-once per sparse-step lowering.  Each stage must resolve to the lowering it
-names, take every batch with a finite loss, report an AUC in [0, 1], and
-leave its rows written back to the host table; the stages train identical
-data from identical seeds, so their per-step losses must also agree with
-the plain-XLA ``reference`` stage.  On a TPU the ``mxu`` / ``mxu_sharded``
-step must carry both Mosaic kernels.
+once on the lowering ``auto`` resolves to and once on ``reference``.  Each
+stage must resolve to the lowering it names, take every batch with a
+finite loss, report an AUC in [0, 1], and leave its rows written back to
+the host table; the stages train identical data from identical seeds, so
+their per-step losses must also agree with the plain-XLA ``reference``
+stage.  On a TPU the ``mxu`` / ``mxu_sharded`` step must carry both Mosaic
+kernels.
 
 Fails (non-zero, no result line) when JAX finds no TPU, when the native
 library does not build and load, or when any stage check fails: nothing
@@ -364,7 +365,7 @@ def main() -> int:
     from paddlebox_tpu import flags
     flags.set_flags({"check_nan_inf": True})
     topology = None
-    stages = [("mxu", "auto", "mxu"), ("ragged", "ragged", "ragged"),
+    stages = [("mxu", "auto", "mxu"),
               ("reference", "reference", "reference")]
     if args.chips > 1:
         from paddlebox_tpu.config import MeshConfig
@@ -372,10 +373,9 @@ def main() -> int:
         topology = HybridTopology(MeshConfig(dp=args.chips),
                                   devices[:args.chips])
         # the reference for the sharded exchange is the same data on ONE
-        # device through a lowering that shares no kernel with it (the
-        # one-chip run ties ragged to the plain-XLA reference step)
+        # device through the plain-XLA step, which shares no kernel with it
         stages = [("mxu_sharded", "auto", "mxu_sharded"),
-                  ("reference", "ragged", "ragged")]
+                  ("reference", "reference", "reference")]
 
     results = {}
     with tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp:

@@ -65,10 +65,6 @@ class HostPassArrays:
     aux: Optional[Dict[str, np.ndarray]] = None
     uid: Optional[np.ndarray] = None    # [N*B] uint64 (uid_slot, HOST-side:
     #   uids never ship to device — wuauc accumulates on host)
-    # ragged-path CSR step plans ({seg, inv, occ_w, u_rows, u_slot}, each
-    # [N, ...]) — built host-side (build_csr_plans) so the prefetch worker
-    # hides the cost under pass N's training; None until/unless built
-    csr: Optional[Dict[str, np.ndarray]] = None
 
     def extra_planes(self) -> Dict[str, np.ndarray]:
         """Every optional per-record plane (rank_offset + aux index
@@ -603,87 +599,6 @@ def precompute_plans(feed: PackedPassFeed, dims, eff=None,
             (s, l, b)))
 
 
-def _round8(n: int) -> int:
-    """Pad a plan extent up to a multiple of 8 (lane-friendly, and a
-    shared max keeps the stacked per-batch plan arrays homogeneous)."""
-    return max(8, -(-int(n) // 8) * 8)
-
-
-def build_csr_plans(indices: np.ndarray, slot_ids: Sequence[int],
-                    n_batches: int, batch_size: int) -> Dict[str, np.ndarray]:
-    """Per-batch CSR step plans for the ragged sparse path (host, numpy).
-
-    Lowers each batch's padded [S, B, L] index plane to its valid-
-    occurrence frontier ONCE per pass, so the jitted step never touches
-    the [S, L, B] padded domain or the full-[N] working set (≙ the
-    reference's pass-scope DedupKeysAndFillIdx, box_wrapper_impl.h:129 —
-    dedup/index once, reuse every kernel; COGNATE's stay-in-the-nonzero-
-    domain argument).  Occurrences are enumerated in the fast path's
-    canonical flat order (s-major, then l, then b — exactly
-    ``[S, L, B].reshape(-1)``), so per-row scatter-add summand order
-    matches fast_path's and segment sums are order-reproducible.
-
-    Returns stacked planes, one leading batch axis each:
-
-      seg     [N, P_pad] int32 — pooled-output segment ``s*B + b`` of each
-              valid occurrence (pad → 0; its payload is zeroed by occ_w)
-      inv     [N, P_pad] int32 — occurrence → [U]-domain row position;
-              position 0 is reserved for working-set row 0 (the all-zero
-              padding row), real unique rows sit at 1.. in sorted order
-      occ_w   [N, P_pad] f32  — 1.0 valid / 0.0 pad payload weight
-      u_rows  [N, U_pad] int32 — sorted-unique working-set row of each
-              [U]-position (u_rows[:, 0] == 0 always; pad → 0, so every
-              duplicate scatter of row 0 writes identical pass-through
-              values — deterministic by construction)
-      u_slot  [N, U_pad] int32 — per-[U]-row merged slot id (max over the
-              row's occurrence slots, matching fast_path's ``.at[].max``)
-
-    Padding occurrences (index 0) are DROPPED, not masked: working-set
-    row 0 is the reserved all-zero row in every path, so its pull
-    contribution is zero and its push is suppressed (optimizer
-    push_touched excludes row 0) — bit-identical to carrying them.
-    """
-    t0 = time.perf_counter()
-    m0 = time.monotonic()
-    S, NB, L = indices.shape
-    B = int(batch_size)
-    N = int(n_batches)
-    slot_arr = np.asarray(slot_ids, dtype=np.int32)
-    per = []
-    p_max = u_max = 0
-    for i in range(N):
-        # [S, B, L] -> [S, L, B]: the fast path's flat order
-        slb = np.ascontiguousarray(
-            indices[:, i * B:(i + 1) * B, :].transpose(0, 2, 1))
-        flatv = slb.reshape(-1)
-        pos = np.flatnonzero(flatv)
-        rows = flatv[pos]
-        s_of = (pos // (L * B)).astype(np.int32)
-        b_of = (pos % B).astype(np.int32)
-        uniq = np.unique(rows).astype(np.int32)       # sorted, excludes 0
-        inv = (np.searchsorted(uniq, rows) + 1).astype(np.int32)
-        per.append((s_of * B + b_of, inv, uniq, s_of))
-        p_max = max(p_max, pos.size)
-        u_max = max(u_max, uniq.size + 1)
-    P_pad, U_pad = _round8(p_max), _round8(u_max)
-    seg = np.zeros((N, P_pad), np.int32)
-    invp = np.zeros((N, P_pad), np.int32)
-    occ_w = np.zeros((N, P_pad), np.float32)
-    u_rows = np.zeros((N, U_pad), np.int32)
-    u_slot = np.zeros((N, U_pad), np.int32)
-    for i, (sg, inv, uniq, s_of) in enumerate(per):
-        p, u = sg.size, uniq.size
-        seg[i, :p] = sg
-        invp[i, :p] = inv
-        occ_w[i, :p] = 1.0
-        u_rows[i, 1:1 + u] = uniq                      # [0] stays row 0
-        np.maximum.at(u_slot[i], inv, slot_arr[s_of])
-    intervals.record("csr", m0, time.monotonic())
-    stat_observe("data.pass_feed.csr_build_s", time.perf_counter() - t0)
-    return {"seg": seg, "inv": invp, "occ_w": occ_w,
-            "u_rows": u_rows, "u_slot": u_slot}
-
-
 def slice_batch(tree, i):
     """Batch i of a stacked pytree (XLA dynamic-slice inside jit)."""
     return jax.tree.map(
@@ -696,8 +611,6 @@ def plan_tuple(p: Dict[str, jnp.ndarray]):
     payload planes are present (precompute_plans with slot_ids) the tuple
     extends to 11 fields; mxu_path keys the narrow-crossing push on the
     length."""
-    if "u_rows" in p:      # ragged-path CSR plan (build_csr_plans)
-        return (p["seg"], p["inv"], p["occ_w"], p["u_rows"], p["u_slot"])
     base = (p["rows2d"], p["perm"], p["inv_perm"], p["ch"], p["tl"],
             p["fg"], p["fs"], p["first_occ"])
     if "bs" in p:

@@ -23,13 +23,8 @@ worker thread while the trainer runs the current pass:
 
 Division of labour is deliberate:
 
-* Host-only work (file read, key dedup, table pull, numpy pack, and —
-  under ``sparse_step_path=ragged`` — the per-pass CSR plan lowering
-  (pass_feed.build_csr_plans, run inside trainer.pack_pass_host)) runs on
+* Host-only work (file read, key dedup, table pull, numpy pack) runs on
   background threads — it releases the GIL and the device never sees it.
-  The CSR build is the ragged path's only per-pass host cost; hiding it
-  here is what makes the [U]-domain step effectively free to feed
-  (intervals report it as ``csr_hidden_s``).
 * EVERY device dispatch (working-set upload, feed H2D, plan builds) stays
   on the main thread — concurrent device dispatch from two python threads
   can deadlock single-stream runtimes (ps/pass_manager.py's async_build

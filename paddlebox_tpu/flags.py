@@ -117,13 +117,6 @@ define_flag("ps_device_cache_rows", 262_144,
             "row capacity of the device-resident hot-row cache "
             "(ps/device_cache.py); admission/eviction ranks by the "
             "day-scale delta_score stats plus pass recency")
-define_flag("sparse_step_path", "auto",
-            "jitted sparse step lowering: auto | fast | mxu | ragged "
-            "(trainer/trainer.py).  'ragged' keeps per-step sparse math in "
-            "the [P_valid]/[U] nonzero domain via host-built CSR plans "
-            "(ps/ragged_path.py); 'fast'/'mxu' are the padded-dense paths; "
-            "'auto' defers to the trainer's topology/optimizer-driven "
-            "resolution.  Bit-identity across paths is the contract")
 define_flag("mxu_crossing_bf16", False,
             "move the mxu path's sorted<->canonical crossings in bfloat16 "
             "— halves the bytes of the dominant step cost (BENCH_r03: two "

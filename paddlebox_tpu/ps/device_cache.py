@@ -114,9 +114,9 @@ class DeviceRowCache:
 
     Step-path agnostic: the cache operates on whole working-set rows
     (gather at adoption, fold-back at end_pass), never on the step's
-    intermediate layout — so fast ([S,L,B] padded), mxu (sorted-chunk),
-    and ragged (CSR [U]-domain) steps compose with it unchanged, and the
-    cache on/off bit-identity tests hold per path.
+    intermediate layout — so fast ([S,L,B] padded) and mxu (sorted-chunk)
+    steps compose with it unchanged, and the cache on/off bit-identity
+    tests hold per path.
     """
 
     def __init__(self, capacity: int, nonclk_coeff: float = 0.1,

@@ -111,10 +111,7 @@ def build_working_set(host_soa: Dict[str, np.ndarray], mf_dim: int,
     with the given NamedSharding (row-sharded over the mesh).
 
     The reserved all-zero row 0 is load-bearing for every step path:
-    fast/mxu point padding occurrences at it so they pool as exact 0.0,
-    and the ragged CSR plan (ps/ragged_path.py) additionally pins row 0
-    as [U]-position 0 — its pad/unknown sink whose gathered values and
-    scattered-back updates are both provably zero.
+    fast/mxu point padding occurrences at it so they pool as exact 0.0.
 
     ≙ BuildGPUTask's HBM pool fill (ps_gpu_wrapper.cc:684-760) — a single
     chunked H2D per field instead of 500k-key memcpy loops.

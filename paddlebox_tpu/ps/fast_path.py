@@ -121,7 +121,7 @@ def push_and_update(ws: Dict[str, jnp.ndarray], idx: jnp.ndarray,
     # -- scalar state: full-table [N] ops (8MB/pass — cheap) --------------
     # PB301 suppressions below: these 1-D [N] scalar sweeps are this
     # path's documented contract (module docstring — "per-feature scalars
-    # stay [N] 1-D"); the [U]-domain alternative is ps/ragged_path.py.
+    # stay [N] 1-D").
     from paddlebox_tpu.ps.optimizer import push_touched
     touched = push_touched(ws, {"g_show": g_show})
     # pboxlint: disable-next=PB301 -- documented-cheap [N] scalar pass

@@ -404,11 +404,8 @@ def apply_push(ws, acc, cfg: SparseSGDConfig, dims_row=None):
     """dims_row: optional per-row [N] mf dims (dynamic-dim accessor,
     ≙ CtrDymfAccessor) — rules divide/mask by the row's true width.
 
-    Row-count generic: every rule is elementwise over axis 0, so callers
-    may pass the full [N] working set (fast/mxu paths) OR a gathered
-    [U]-row sub-SoA with matching [U] accumulators (ps/ragged_path.py) —
-    the rules run verbatim on the smaller domain and the caller scatters
-    the result back.  Nothing here may assume ws spans the whole pass."""
+    Row-count generic: every rule is elementwise over axis 0, so nothing
+    here may assume ws spans the whole pass."""
     out = OPTIMIZERS[cfg.optimizer](ws, acc, cfg, dims_row)
     # ctr_double accessor support: exact pass-delta counters ride along —
     # small magnitudes, so the f32 adds are exact even when the absolute

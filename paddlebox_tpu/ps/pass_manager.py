@@ -251,10 +251,9 @@ class BoxPSEngine:
 
     def _upload(self, host_rows) -> Dict[str, jnp.ndarray]:
         # The ws built here is the one contract every step path consumes
-        # — fast's padded [S,L,B] gathers, mxu's sorted chunks, and
-        # ragged's CSR [U]-row gather/scatter all index the same [N]-row
-        # SoA (row 0 reserved zero), so path selection never changes what
-        # begin_pass/end_pass upload or write back.
+        # — fast's padded [S,L,B] gathers and mxu's sorted chunks index
+        # the same [N]-row SoA (row 0 reserved zero), so path selection
+        # never changes what begin_pass/end_pass upload or write back.
         #
         # ctr_double accessor: the host keeps f64 show/click; the device
         # trains in f32, so end_pass writes back host + (device delta) in

@@ -5,8 +5,7 @@ is that per-step math scales with the BATCH (the [P] valid occurrences /
 [U] unique rows it actually touches), not with the WORKING SET ([N] pass
 rows, 2M at bench geometry).  A single innocuous-looking
 ``jnp.where(touched, ws["show"] + g, ws["show"])`` inside a jitted step
-is a full-[N] sweep per step — exactly the regression class
-ps/ragged_path.py exists to eliminate, and one that creeps back silently
+is a full-[N] sweep per step — a regression that creeps back silently
 because the op is *correct*, just O(N) instead of O(U).
 
   PB301  a step-path function uses the full working-set array ``ws[...]``
@@ -14,8 +13,8 @@ because the op is *correct*, just O(N) instead of O(U).
          argument, or a non-structural attribute like ``.T``/``.astype``)
          instead of gathering rows first.
 
-Scope is deliberately narrow — the three step-lowering modules
-(``fast_path.py``, ``mxu_path.py``, ``ragged_path.py``), functions that
+Scope is deliberately narrow — the two step-lowering modules
+(``fast_path.py``, ``mxu_path.py``), functions that
 take the working set as a ``ws`` parameter — so the rule never fires on
 host-side table code, which legitimately sweeps [N].
 
@@ -48,7 +47,7 @@ from typing import Dict, List
 from paddlebox_tpu.tools.pboxlint.core import (Finding, Module,
                                                PackageContext)
 
-_STEP_MODULES = frozenset({"fast_path.py", "mxu_path.py", "ragged_path.py"})
+_STEP_MODULES = frozenset({"fast_path.py", "mxu_path.py"})
 # metadata / scatter-builder attributes on ws[...] that touch no elements
 _STRUCTURAL_ATTRS = frozenset({"at", "shape", "dtype", "ndim", "size"})
 # gather/scatter method calls a bare ws[...] may feed (relayout, not math)
@@ -122,7 +121,6 @@ def check(mod: Module, ctx: PackageContext) -> List[Finding]:
                 f"per-step function {fn.name}() uses full working-set "
                 f"array ws[{key!r}] as an elementwise operand — a per-step "
                 f"O(N) sweep over the whole pass working set; gather the "
-                f"touched rows first and do the math in the [U]/[P] domain "
-                f"(ps/ragged_path.py), or document the cost with a "
-                f"disable-next suppression"))
+                f"touched rows first and do the math in the [U]/[P] domain, "
+                f"or document the cost with a disable-next suppression"))
     return findings

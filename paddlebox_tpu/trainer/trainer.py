@@ -52,8 +52,8 @@ class SparseTrainer:
                  topology: Optional[HybridTopology] = None,
                  auc_table_size: int = 100_000,
                  trainer_config: Optional[TrainerConfig] = None,
-                 amp: bool = False, fast_path: bool = True,
-                 sparse_path: str = "auto", seed: int = 0):
+                 amp: bool = False, sparse_path: str = "auto",
+                 seed: int = 0):
         self.engine = engine
         self.model = model
         self.packer = BatchPacker(feed_config, batch_size, label_slot)
@@ -62,14 +62,8 @@ class SparseTrainer:
         self.topology = topology
         self.trainer_config = trainer_config or TrainerConfig()
         self.amp = amp  # bf16 MXU compute for the dense net (master f32)
-        self.fast_path = fast_path  # tiling-aware pipeline (ps/fast_path.py)
-        # "mxu" (sorted-SpMM kernels), "ragged" (CSR [U]-domain step),
-        # "fast", "reference", or "auto"; FLAGS_sparse_step_path overrides
-        # an "auto" construction (flag stays inert when the caller picked
-        # a concrete path explicitly)
-        if sparse_path == "auto" \
-                and flags.get_flags("sparse_step_path") != "auto":
-            sparse_path = flags.get_flags("sparse_step_path")
+        # "auto" (_resolve_path picks from what the trainer can see), or a
+        # lowering by name: "mxu", "mxu_sharded", "fast", "reference"
         self.sparse_path = sparse_path
         self.timers = TimerRegistry()
         self.slot_ids = np.array(
@@ -188,11 +182,7 @@ class SparseTrainer:
         has_ex = "mf_ex" in self.engine.ws
         is_adagrad = self.engine.config.sgd.optimizer == "adagrad"
         if path == "auto":
-            if not self.fast_path:
-                # fast_path=False is the documented escape hatch to the
-                # numerically-exact reference step — honor it
-                path = "reference"
-            elif has_ex and self._dym_mask is not None:
+            if has_ex and self._dym_mask is not None:
                 # no path trains mf_ex under per-slot dynamic dims (fast/
                 # reference pull only 3+D columns) — fail with the clear
                 # error instead of an in-jit shape mismatch downstream
@@ -282,17 +272,6 @@ class SparseTrainer:
                 raise ValueError(
                     "sparse_path='fast' implements the adagrad rule only "
                     f"(got {self.engine.config.sgd.optimizer!r})")
-        elif path == "ragged":
-            if has_ex:
-                raise ValueError(
-                    "sparse_path='ragged' pulls only the 3+D pooled "
-                    "columns — extended (mf_ex) tables need the mxu or "
-                    "mxu_sharded path")
-            if self.topology is not None:
-                raise ValueError(
-                    "sparse_path='ragged' builds its CSR step plans "
-                    "host-side against a single-host working set — use "
-                    "mxu_sharded under a topology")
         elif path == "reference":
             if self.async_dense is not None:
                 raise ValueError(
@@ -333,12 +312,11 @@ class SparseTrainer:
         packer (transposed + planned in-step)."""
         path = self._resolve_path()
         self._validate_path(path)
-        if path == "ragged" or self._row_model:
+        if self._row_model:
             raise ValueError(
-                "sparse_path='ragged' and models that take unpooled rows "
-                "require the pass-resident feed (build_pass_feed / "
-                "train_pass(feed)) — the streaming per-batch path has no "
-                "host CSR plan build and no key planes")
+                "models that take unpooled rows require the pass-resident "
+                "feed (build_pass_feed / train_pass(feed)) — the streaming "
+                "per-batch path has no key planes")
         crossing = ("take", "take")
         if path == "mxu":
             crossing = self._crossing_modes(
@@ -642,35 +620,6 @@ class SparseTrainer:
                 return out + ((d_params,) if async_dense else ())
             return core
 
-        if path == "ragged":
-            # CSR [U]-domain step (ps/ragged_path.py): the pass was
-            # lowered to per-batch CSR plans at feed build; the step
-            # touches only the valid-occurrence frontier and the batch's
-            # unique rows — never the padded [S, L, B] domain, never a
-            # full-[N] sweep
-            from paddlebox_tpu.ps import ragged_path
-            half = self._pooled_dense_half()
-
-            def core(ws, params, opt_state, auc_state, idx_slb, lengths,
-                     dense, labels, valid, plan, extras=None):
-                if plan is None:
-                    raise ValueError(
-                        "sparse_path='ragged' needs the pass-resident "
-                        "feed's CSR plans (build_pass_feed) — they cannot "
-                        "be built in-trace")
-                s, l, b = idx_slb.shape
-                pooled = jax.lax.stop_gradient(ragged_path.pull_pool_cvm(
-                    ws, plan, (s, l, b), use_cvm))
-                (params, opt_state, auc_state, loss, preds, d_pooled,
-                 d_params) = half(params, opt_state, auc_state, pooled,
-                                  dense, labels, valid, extras)
-                ins_cvm = jnp.stack([jnp.ones_like(labels), labels], axis=1)
-                ws = ragged_path.push_and_update(ws, plan, d_pooled,
-                                                 ins_cvm, (s, l, b), sgd_cfg)
-                out = (ws, params, opt_state, auc_state, loss, preds)
-                return out + ((d_params,) if async_dense else ())
-            return core
-
         if path == "fast":
             # tiling-aware step (ps/fast_path.py docstring); numerically
             # identical to the reference step — tests/test_fast_path.py
@@ -783,13 +732,6 @@ class SparseTrainer:
                                           else mapper),
                               batch_counts=counts, on_plane=on_plane,
                               seq_key_slot=self._seq_key_slot)
-        if self.sparse_path == "ragged":
-            # lower the packed pass to CSR here so the PR 7 prefetcher's
-            # worker thread hides the build under pass N's training ("auto"
-            # never resolves to ragged, so the attribute check is exact)
-            arrays.csr = pf.build_csr_plans(arrays.indices, self.slot_ids,
-                                            arrays.n_batches,
-                                            arrays.batch_size)
         return arrays
 
     def pass_shardings(self, arrays) -> Optional[dict]:
@@ -841,19 +783,6 @@ class SparseTrainer:
                 pf.precompute_plans(feed, dims, eff, slot_ids=self.slot_ids)
             elif path == "mxu_sharded":
                 self._precompute_sharded_plans(feed)
-            elif path == "ragged":
-                # fail at feed-build time, not first train step: an invalid
-                # config (mf_ex / topology) should not cost a CSR build first
-                self._validate_path(path)
-                csr = arrays.csr
-                if csr is None:
-                    # serial path (no prefetch worker ran pack_pass_host with
-                    # the ragged path selected) — build now, same plans
-                    csr = pf.build_csr_plans(arrays.indices, self.slot_ids,
-                                             arrays.n_batches,
-                                             arrays.batch_size)
-                feed.plans = {k: jnp.asarray(v) for k, v in csr.items()}
-                feed.plan_dims = self._ragged_plan_key(feed)
         return feed
 
     def build_pass_feed(self, dataset: SlotDataset,
@@ -915,14 +844,6 @@ class SparseTrainer:
         _, tbl_axes, n_tbl, _, _ = self._sharded_layout()
         return ("mxu_sharded", tuple(feed.data["indices"].shape),
                 self.engine.ws["show"].shape[0], tbl_axes, n_tbl)
-
-    def _ragged_plan_key(self, feed: PackedPassFeed):
-        """Identity of the geometry a feed's CSR plans were built for
-        (feed shape + table height): u_rows are pass-local working-set
-        rows, so a table resize makes resident plans silently corrupting
-        — the packed loop compares this before every pass."""
-        return ("ragged", tuple(feed.data["indices"].shape),
-                self.engine.ws["show"].shape[0])
 
     def _require_pv_for_rank(self, dataset) -> None:
         """rank_offset is only meaningful when every batch holds WHOLE page
@@ -1015,14 +936,6 @@ class SparseTrainer:
                     f"{feed.plan_dims}, but the exchange now needs {cur} — "
                     "rebuild the feed (build_pass_feed) after a table or "
                     "mesh change")
-        elif feed.plans is not None and path == "ragged":
-            cur = self._ragged_plan_key(feed)
-            if cur != feed.plan_dims:
-                raise ValueError(
-                    "PackedPassFeed CSR plans were built for "
-                    f"{feed.plan_dims}, but the pass now needs {cur} — "
-                    "rebuild the feed (build_pass_feed) after a table "
-                    "resize")
         if self._packed_step_fn is None \
                 or self._packed_sig != self._packed_signature(feed):
             self._build_packed_step(feed)

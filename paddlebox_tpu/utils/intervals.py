@@ -13,9 +13,6 @@ kind and computes union/overlap-aware utilization:
 * ``pack``   — host-side batch packing (data/pass_feed.py, stream pack)
 * ``upload`` — host→device uploads (working-set build, packed batches)
 * ``write``  — working-set write-back to the DRAM tier at pass end
-* ``csr``    — host-side CSR step-plan build for the ragged sparse path
-  (data/pass_feed.py build_csr_plans; hidden under training when the
-  PR 7 prefetcher runs it on the worker thread)
 
 ``report(since)`` merges each kind's intervals (union seconds, clipped
 to the window), yielding:
@@ -46,8 +43,8 @@ from paddlebox_tpu.utils.monitor import stat_add
 
 # Closed set of activity kinds (PB204-style bounded cardinality: the
 # per-kind cumulative stat below interpolates `kind` into a metric name).
-KINDS = ("device", "pull", "pack", "upload", "write", "csr")
-_HOST_KINDS = ("pull", "pack", "upload", "write", "csr")
+KINDS = ("device", "pull", "pack", "upload", "write")
+_HOST_KINDS = ("pull", "pack", "upload", "write")
 
 
 def union_seconds(iv: List[Tuple[float, float]],

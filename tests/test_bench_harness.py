@@ -397,7 +397,7 @@ def test_compare_feed_gap_gate_skipped_when_device_idle(bench, monkeypatch,
 
 def test_compare_flags_sparse_share_regression(bench, monkeypatch, tmp_path):
     """step_ms.sparse_share creeping back up is the padded-dense
-    regression class the ragged path eliminated — compare gates it."""
+    regression class — compare gates it."""
     def rf(path, share):
         path.write_text(json.dumps(
             {"metric": "m", "value": 1000.0, "final": True,

@@ -37,7 +37,7 @@ def test_tiny_runs_every_stage_on_cpu():
     out = json.loads(lines[-2][len(tag):])
     assert out["tiny"] is True
     assert {k: v["resolved_path"] for k, v in out["stages"].items()} == {
-        "mxu": "mxu", "ragged": "ragged", "reference": "reference"}
+        "mxu": "mxu", "reference": "reference"}
     assert out["native"]["ok"] is True
     assert out["claim"] is None
     # the two relaxations of --tiny are announced, not silent

@@ -56,7 +56,7 @@ def run_one(fast: bool, steps=3):
     eng = make_engine()
     model = CtrDnn(num_slots=S, emb_width=3 + MF, dense_dim=DD,
                    hidden=(16,))
-    tr = SparseTrainer(eng, model, cfg, batch_size=B, fast_path=fast,
+    tr = SparseTrainer(eng, model, cfg, batch_size=B,
                        sparse_path="fast" if fast else "reference",
                        auc_table_size=1000, seed=11)
     tr._build_step()

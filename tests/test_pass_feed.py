@@ -137,7 +137,7 @@ def test_auto_resolves_to_mxu_at_bench_geometry():
     rng = np.random.default_rng(4)
     ds, eng, tr = _build([_make_block(rng, 64)], "auto")
     assert tr._resolve_path() == "mxu"
-    tr.fast_path = False
+    tr.sparse_path = "reference"
     assert tr._resolve_path() == "reference"
 
 

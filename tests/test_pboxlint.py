@@ -1751,9 +1751,9 @@ def test_pb301_prefix_push_and_update_full_n_sweeps():
 
 
 def test_pb301_ragged_gather_update_scatter_clean():
-    """The [U]-domain shape (ps/ragged_path.py): gather the touched rows,
-    do the math on the gathered sub-array, scatter once — plus the
-    structural uses (.shape/.dtype/.at) and bare aliasing.  All allowed."""
+    """The [U]-domain shape: gather the touched rows, do the math on the
+    gathered sub-array, scatter once — plus the structural uses
+    (.shape/.dtype/.at) and bare aliasing.  All allowed."""
     src = """
     import jax.numpy as jnp
 
@@ -1767,7 +1767,7 @@ def test_pb301_ragged_gather_update_scatter_clean():
         created = (ws["mf_size"][u_rows] > 0).astype(ws["show"].dtype)
         return out, mf, created
     """
-    assert codes(src, path="paddlebox_tpu/ps/ragged_path.py") == []
+    assert codes(src, path="paddlebox_tpu/ps/mxu_path.py") == []
 
 
 def test_pb301_relayout_set_arg_allowed_wrapped_call_not():
@@ -1786,7 +1786,7 @@ def test_pb301_relayout_set_arg_allowed_wrapped_call_not():
 
 def test_pb301_out_of_scope_silent():
     """Host-side table code legitimately sweeps [N]; the rule only scopes
-    the three step-lowering modules and functions taking ``ws``."""
+    the two step-lowering modules and functions taking ``ws``."""
     sweep = """
     import jax.numpy as jnp
 
