@@ -84,6 +84,11 @@ def _measure(take_rows: int, sort_n: int, w: int, backend: str,
     idx = jnp.asarray(
         rng.integers(0, sort_n, take_rows).astype(np.int32))
     dest = jnp.asarray(rng.permutation(sort_n).astype(np.int32))
+    # NOTE (PR 31): this times a row-major `take` of a [sort_n, w] source.
+    # The step's pull crossing gathers feature-major, or row-major at lane
+    # width where mxu_path.cross_lane_width says so, and its cost hangs on
+    # whether the source fits fast memory, so this is not the take a wide
+    # pull runs (ROADMAP queue 3 `crossing-autotune`; the cells pin take).
     # both lowerings must compile on the live backend: a failure here is
     # a finding about the device, so it propagates (a silent "take" would
     # hide it behind the slower crossing)

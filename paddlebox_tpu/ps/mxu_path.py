@@ -17,12 +17,18 @@ the kernels' hi/lo bf16 split carries ~1e-5 relative error).
 
 Layout notes: occurrence order is canonical [S, L, B] flattened; the plan's
 `perm`/`inv_perm` move between canonical and sorted domains (one XLA row
-gather each way, the only serial-ish ops left, ~2.6ms at 426k rows).  The
-pull table is feature-major [W, n_kernel] with W = 3 + D (+ Dex) + 1
-(rows: show, click, embed_w, mf×D, optional expand mf_ex×Dex, mf_size) so
-kernel blocks tile perfectly and the build is W row writes, not an
-[N, D] relayout; past sorted_spmm.W_BLOCK rows (a 2048-wide sequence row)
-it is built at the kernels' padded height.
+gather each way, the only serial-ish ops left; on a v5e the pull's is 1.9 ms
+at DeepFM's 426k 11-wide rows and, since PR 31, ~14 ms at Wide&Deep's 950k
+35-wide ones, PERF.md §5).  The pull table is feature-major [W, n_kernel]
+with W = 3 + D (+ Dex) + 1 (rows: show, click, embed_w, mf×D, optional
+expand mf_ex×Dex, mf_size) so kernel blocks tile perfectly and the build is
+W row writes, not an [N, D] relayout; past sorted_spmm.W_BLOCK rows (a
+2048-wide sequence row) it is built at the kernels' padded height.  The
+kernels' output stays feature-major too; the "take" pull crossing alone may
+leave that layout: where its feature-major source would not fit the chip's
+fast memory it relayouts the sorted columns once a step into row-major rows
+padded to the lane width and gathers those (`cross_lane_width`, the rule and
+its chip readings below).
 
 Two consumers of the canonical values: the pooled CTR towers sum a slot's
 rows over its capacity (`pull_pool_cvm`, and `d_pooled` is broadcast back
@@ -192,17 +198,99 @@ def _pull_sorted(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
     return g
 
 
+# -- the layout the "take" pull crossing gathers in -------------------------
+# XLA's TPU gather writes a row of up to 56 columns feature-major ({0,1}:
+# one access a sublane tile of 8 columns) and is quick at that only while
+# its source [round_up(W, 8), p_pad] stays in the chip's fast memory; out
+# of it a row costs ~11 ns a tile instead of ~2.7.  A row-major source
+# whose rows are padded to the lane width is gathered whole tiles at a
+# time, ~20 ns a row whatever the width, relayout included.  Readings on a
+# v5e (PR 31, PERF.md §6: take + pooling sum, 950,272 rows out in Wide&Deep's
+# two capacity groups; ms feature-major / row-major):
+#   W   sorted source     feature-major source   ms fm / rm
+#   11  425,984 rows      26 MiB                 2.08 / 9.77   (DeepFM: 425,984 rows out)
+#   11  851,968           52 MiB                 4.67 / 19.11
+#   19  851,968           78 MiB                 8.09 / 19.17
+#   27  851,968          104 MiB                10.75 / 19.25
+#   35  638,976           97.5 MiB              12.86 / 18.97
+#   35  786,432          120 MiB                50.88 / 19.14
+#   35  851,968          130 MiB                53.30 / 19.30  (Wide&Deep)
+#   43  638,976          117 MiB                61.27 / 19.02
+#   19  1,277,952        117 MiB                34.93 / 20.24
+#   11  1,703,936        104 MiB                 6.47 / 21.21
+#   11  2,097,152        128 MiB                28.51 / 22.21
+#   56  851,968          182 MiB                65.22 / 19.45
+#   59  851,968          (row-major by itself)  17.41 / 19.72
+#   67  851,968          (row-major by itself)  17.53 / 19.83
+#  131  851,968          (row-major by itself)  23.31 / 27.20  (256 lanes)
+CROSS_LANES = 128                       # lanes of a tile: the padded row
+CROSS_FAST_SOURCE_BYTES = 110 << 20     # 104 MiB still gathers fast, 117 not
+CROSS_LANES_MIN_WIDTH = 17  # two tiles or fewer: 1.14-1.28x, an 8x+ source
+CROSS_LANES_MAX_WIDTH = 56  # wider, the compiler lays rows row-major itself
+
+
+def cross_lane_width(w: int, p_pad: int, itemsize: int = 4,
+                     crossing: str = "take") -> int:
+    """Padded row width the pull crossing gathers at, from the static
+    shape of the sorted columns [w, p_pad] alone (the gauge
+    ``ps.mxu.pull_cross_lane_width``): CROSS_LANES where the feature-major
+    gather would read a source too large for fast memory, 0 = the
+    feature-major gather (and under "sort", which gathers nothing)."""
+    if crossing != "take" or not (
+            CROSS_LANES_MIN_WIDTH <= w <= CROSS_LANES_MAX_WIDTH):
+        return 0
+    sublanes = 32 // itemsize           # rows of a tile: 8 float32, 16 bf16
+    fm_bytes = -(-w // sublanes) * sublanes * p_pad * itemsize
+    return CROSS_LANES if fm_bytes > CROSS_FAST_SOURCE_BYTES else 0
+
+
+def pull_cross_lane_width(ws: Dict[str, jnp.ndarray], plan,
+                          dims: sp.SpmmDims, crossing: str = "take") -> int:
+    """``cross_lane_width`` of the step that pulls ``ws`` by ``plan``."""
+    from paddlebox_tpu import flags
+    return cross_lane_width(
+        3 + ws["mf"].shape[1] + _ex_dim(ws),
+        (plan_eff_dims(plan, dims) or dims).p_pad,
+        2 if flags.get_flags("mxu_crossing_bf16") else 4, crossing)
+
+
+def _lane_rows(g: jnp.ndarray) -> Optional[jnp.ndarray]:
+    """Sorted columns ``g`` [W, p_pad] as the row-major, lane-wide source
+    of the crossing, [p_pad, CROSS_LANES] (the W columns, zero lanes
+    beyond); None where ``cross_lane_width`` leaves the row feature-major.
+    One relayout a step: the barrier keeps the compiler from folding it
+    back into every gather that reads it."""
+    w = g.shape[0]
+    lanes = cross_lane_width(w, g.shape[1], g.dtype.itemsize)
+    if not lanes:
+        return None
+    return jax.lax.optimization_barrier(
+        jnp.pad(g.T, ((0, 0), (0, lanes - w))))
+
+
 def _take_canonical(g: jnp.ndarray, inv_perm: jnp.ndarray,
-                    dims: sp.SpmmDims, trimmed: bool) -> jnp.ndarray:
+                    dims: sp.SpmmDims, trimmed: bool,
+                    src: Optional[jnp.ndarray]) -> jnp.ndarray:
     """The "take" pull crossing: sorted columns ``g`` [W, p_pad] → one row
     [W] a canonical position of ``inv_perm`` (all of the plan's, or any
-    subset of them)."""
-    if not trimmed:
-        return jnp.take(g.T[:dims.p], inv_perm, axis=0)    # canonical [p,W]
-    # trimmed plan: dropped positions (inv_perm < 0) were row-0
-    # occurrences whose pull value is exactly zero — clamp + mask
-    v = jnp.take(g.T, jnp.maximum(inv_perm, 0), axis=0)
-    return v * (inv_perm >= 0).astype(v.dtype)[:, None]
+    subset of them).  ``src``: ``_lane_rows(g)``, made once by a caller
+    that takes more than once from the same columns."""
+    if src is None:
+        if not trimmed:
+            return jnp.take(g.T[:dims.p], inv_perm, axis=0)  # canonical [p,W]
+        # trimmed plan: dropped positions (inv_perm < 0) were row-0
+        # occurrences whose pull value is exactly zero — clamp + mask
+        v = jnp.take(g.T, jnp.maximum(inv_perm, 0), axis=0)
+        return v * (inv_perm >= 0).astype(v.dtype)[:, None]
+    # row-major at lane width: the same rows (inv_perm < p, so no slice
+    # to p), and the W lanes that hold them handed back.  The compiler
+    # keeps the gather's [rows, CROSS_LANES] output row-major by itself,
+    # also with the pooling sum behind it (PR 31: no second barrier)
+    v = jnp.take(src, jnp.maximum(inv_perm, 0) if trimmed else inv_perm,
+                 axis=0)[:, :g.shape[0]]
+    if trimmed:
+        v = v * (inv_perm >= 0).astype(v.dtype)[:, None]
+    return v
 
 
 def pull_rows(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
@@ -231,7 +319,8 @@ def pull_rows(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
             g = jnp.concatenate([jnp.zeros((w, p0), g.dtype), g], axis=1)
         v = cx.permute_by_dest(tuple(g[:, :dims.p]), perm).T  # [p, W]
     else:
-        v = _take_canonical(g, inv_perm, dims, eff is not None)
+        v = _take_canonical(g, inv_perm, dims, eff is not None,
+                            _lane_rows(g))
     return v.reshape(s, l, b, w).astype(jnp.float32)
 
 
@@ -293,13 +382,14 @@ def pull_pool_cvm(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
         v = pull_rows(ws, plan, dims, shape_slb, interpret, crossing)
         return pool_cvm_values(v, use_cvm, premasked=True)
     g = _pull_sorted(ws, plan, dims, interpret)
+    src = _lane_rows(g)         # once, for every capacity group
     trimmed = plan_eff_dims(plan, dims) is not None
     ip = plan[2].reshape(s, l, b)
     pieces = []                 # (first slot, pooled [B, run, 3 + D])
     for c, slots in groups:
         runs = list(_runs(slots))
         ip_c = jnp.concatenate([ip[s0:s0 + n, :c] for _, s0, n in runs])
-        v = _take_canonical(g, ip_c.reshape(-1), dims, trimmed)
+        v = _take_canonical(g, ip_c.reshape(-1), dims, trimmed, src)
         v = v.reshape(len(slots), c, b, -1).astype(jnp.float32)
         pooled = pool_cvm_values(v, use_cvm, premasked=True)
         pieces += [(s0, pooled[:, j:j + n]) for j, s0, n in runs]

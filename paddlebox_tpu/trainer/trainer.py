@@ -439,6 +439,13 @@ class SparseTrainer:
                 mxu_path.pull_cross_rows(capacities, shape_slb, crossing[0])))
             stat_set("ps.mxu.pull_cross_rows_canonical",
                      float(np.prod(shape_slb)))
+
+            def lane_gauge(ws, plan, dims, mode):
+                # set while the step is traced: the layout rule reads the
+                # plan's static width beside the row's
+                stat_set("ps.mxu.pull_cross_lane_width", float(
+                    mxu_path.pull_cross_lane_width(ws, plan, dims, mode)))
+
             if self._row_model:
                 rows_half = self._rows_dense_half()
 
@@ -450,6 +457,7 @@ class SparseTrainer:
                         raise ValueError(
                             "a model that takes unpooled rows trains from a "
                             "feed with precomputed plans (build_pass_feed)")
+                    lane_gauge(ws, plan, dims, "take")
                     with jax.named_scope("seq.pull"):
                         v = mxu_path.pull_rows(ws, plan, dims, (s, l, b),
                                                interpret=interpret)
@@ -489,6 +497,7 @@ class SparseTrainer:
                     idx_slb = jnp.where(jnp.arange(l)[None, :, None]
                                         < lengths[:, None, :], idx_slb, 0)
                     plan = mxu_path.build_plan(idx_slb, dims)
+                lane_gauge(ws, plan, dims, crossing[0])
                 pooled = jax.lax.stop_gradient(mxu_path.pull_pool_cvm(
                     ws, plan, dims, (s, l, b), use_cvm, interpret=interpret,
                     crossing=crossing[0], capacities=capacities))

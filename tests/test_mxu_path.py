@@ -239,29 +239,22 @@ def test_extended_table_pull_push_matches_reference(crossing):
             rtol=2e-4, err_msg=f"field {k}")
 
 
-def test_extended_table_trains_through_trainer():
-    """An expand-embedding engine auto-resolves to the mxu path and trains
-    a pass end-to-end (previously extended tables fell back to the slower
-    reference path)."""
-    from paddlebox_tpu.config import (DataFeedConfig, EmbeddingTableConfig,
-                                      SlotConfig)
+def _slot_dataset(caps, n, seed=8):
+    """A feed config and a one-block dataset of ``n`` records over sparse
+    slots of capacities ``caps`` (keys 1..299), a label and two dense
+    features."""
+    from paddlebox_tpu.config import DataFeedConfig, SlotConfig
     from paddlebox_tpu.data.dataset import SlotDataset
     from paddlebox_tpu.data.slot_record import SlotRecordBlock
-    from paddlebox_tpu.models.ctr_dnn import CtrDnn
-    from paddlebox_tpu.ps.pass_manager import BoxPSEngine
-    from paddlebox_tpu.trainer.trainer import SparseTrainer
-
-    D, DX, S, CAP, B = 4, 3, 3, 2, 64
     cfg = DataFeedConfig(slots=tuple(
         [SlotConfig("label", dtype="float", is_dense=True, dim=1),
          SlotConfig("dense0", dtype="float", is_dense=True, dim=2)]
-        + [SlotConfig(f"s{i}", slot_id=100 + i, capacity=CAP)
-           for i in range(S)]))
-    rng = np.random.default_rng(8)
-    n = 4 * B
+        + [SlotConfig(f"s{i}", slot_id=100 + i, capacity=c)
+           for i, c in enumerate(caps)]))
+    rng = np.random.default_rng(seed)
     blk = SlotRecordBlock(n=n)
-    for i in range(S):
-        lens = rng.integers(1, CAP + 1, size=n)
+    for i, c in enumerate(caps):
+        lens = rng.integers(1, c + 1, size=n)
         off = np.zeros((n + 1,), np.int64)
         np.cumsum(lens, out=off[1:])
         blk.uint64_slots[f"s{i}"] = (
@@ -273,6 +266,20 @@ def test_extended_table_trains_through_trainer():
         np.arange(n + 1, dtype=np.int64) * 2)
     ds = SlotDataset(cfg)
     ds._blocks = [blk]
+    return cfg, ds
+
+
+def test_extended_table_trains_through_trainer():
+    """An expand-embedding engine auto-resolves to the mxu path and trains
+    a pass end-to-end (previously extended tables fell back to the slower
+    reference path)."""
+    from paddlebox_tpu.config import EmbeddingTableConfig
+    from paddlebox_tpu.models.ctr_dnn import CtrDnn
+    from paddlebox_tpu.ps.pass_manager import BoxPSEngine
+    from paddlebox_tpu.trainer.trainer import SparseTrainer
+
+    D, DX, S, CAP, B = 4, 3, 3, 2, 64
+    cfg, ds = _slot_dataset((CAP,) * S, 4 * B)
 
     eng = BoxPSEngine(EmbeddingTableConfig(
         embedding_dim=D, expand_dim=DX, shard_num=4,
@@ -569,3 +576,217 @@ def test_equal_capacities_lower_to_the_full_rectangle_step():
 def test_capacities_must_fit_the_rectangle(caps):
     with pytest.raises(ValueError, match="capacities"):
         mxu_path.capacity_groups(caps, 3, 3)
+
+
+# -- a wide row's "take" crossing gathers row-major at lane width -----------
+
+def _parent_take(g, inv_perm, dims, trimmed, src=None):
+    """``_take_canonical`` as it was before the layout rule (PR 30)."""
+    if not trimmed:
+        return jnp.take(g.T[:dims.p], inv_perm, axis=0)
+    v = jnp.take(g.T, jnp.maximum(inv_perm, 0), axis=0)
+    return v * (inv_perm >= 0).astype(v.dtype)[:, None]
+
+
+def _row_major(monkeypatch):
+    """The other side of the rule at a test's size: no source is small
+    enough for fast memory."""
+    monkeypatch.setattr(mxu_path, "CROSS_FAST_SOURCE_BYTES", 0)
+
+
+def _wide_case(caps, trim, D=32, n=300, B=16):
+    from paddlebox_tpu.ops import sorted_spmm as sp
+    S, L = len(caps), max(caps)
+    ws = _make_ws(n, D)
+    idx, ins_cvm, slot_ids = _capacity_batch(n, caps, B)
+    dims = sp.spmm_dims(S * L * B, n, chunk=8, tile=32)
+    eff = None
+    if trim:
+        eff = sp.trimmed_dims(dims, int((np.asarray(idx) != 0).sum()))
+        assert eff.p_pad < dims.p_pad, "batch must actually trim"
+    return ws, idx, ins_cvm, slot_ids, dims, mxu_path.build_plan(idx, dims,
+                                                                 eff)
+
+
+@pytest.mark.parametrize("use_cvm", [True, False])
+@pytest.mark.parametrize("trim", [False, True])
+@pytest.mark.parametrize("caps", [(1, 16, 1, 16), (3, 3, 3)])
+def test_lane_wide_pull_equals_feature_major(caps, trim, use_cvm,
+                                             monkeypatch):
+    """A 35-wide row gathered row-major at lane width: the pooled pull,
+    grouped and in one group, and the step behind it are bit for bit what
+    the feature-major gather gives: the same rows, the same sums in the
+    same order."""
+    cfg = SparseSGDConfig(mf_create_thresholds=5.0)
+    ws, idx, ins_cvm, slot_ids, dims, plan = _wide_case(caps, trim)
+
+    def step():
+        return _pull_tower_push(
+            lambda ws, plan, dims, shape: mxu_path.pull_pool_cvm(
+                ws, plan, dims, shape, use_cvm, interpret=True,
+                capacities=caps),
+            ws, plan, dims, idx, ins_cvm, slot_ids, cfg)
+
+    assert mxu_path.pull_cross_lane_width(ws, plan, dims) == 0
+    want = step()
+    _row_major(monkeypatch)
+    assert mxu_path.pull_cross_lane_width(ws, plan, dims) == 128
+    got = step()
+    assert got[0].shape == (16, len(caps), 35)
+    assert float(jnp.abs(got[0][..., 3:]).sum()) > 0
+    for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _lowered_pull(fn, ws, plan):
+    return jax.jit(fn).lower(ws, plan).as_text()
+
+
+@pytest.mark.parametrize("shape", ["pooled-11-groups", "pooled-11-one-group",
+                                   "pooled-11-trimmed", "pooled-35-fits",
+                                   "rows-2051"])
+def test_rows_the_rule_leaves_alone_lower_to_the_parent_text(shape,
+                                                             monkeypatch):
+    """A row of two sublane tiles or fewer (DeepFM's 11 wide), a row past
+    the band (Ouro's 2,051-wide ``pull_rows``), both whatever the source's
+    size, and a wide row whose source fits fast memory: the lowered
+    StableHLO text is the parent's, op for op."""
+    if shape != "pooled-35-fits":
+        _row_major(monkeypatch)
+    if shape == "rows-2051":
+        n, D, L, B = 300, 2048, 6, 8
+        ws = _make_ws(n, D)
+        idx, _ = _sequence_batch(n, L, B)
+        dims = mxu_path.make_dims(L * B, n)
+        plan = mxu_path.build_plan(jnp.asarray(idx), dims)
+
+        def pull(ws, plan):
+            return mxu_path.pull_rows(ws, plan, dims, (1, L, B),
+                                      interpret=True)
+    else:
+        caps = (3, 3, 3) if shape == "pooled-11-one-group" else (1, 16, 1, 16)
+        ws, _, _, _, dims, plan = _wide_case(
+            caps, shape == "pooled-11-trimmed",
+            D=32 if shape == "pooled-35-fits" else 8)
+
+        def pull(ws, plan):
+            return mxu_path.pull_pool_cvm(
+                ws, plan, dims, (len(caps), max(caps), 16), True,
+                interpret=True, capacities=caps)
+    text = _lowered_pull(lambda ws, plan: pull(ws, plan), ws, plan)
+    assert "optimization_barrier" not in text
+    monkeypatch.setattr(mxu_path, "_take_canonical", _parent_take)
+    assert text == _lowered_pull(lambda ws, plan: pull(ws, plan), ws, plan)
+
+
+@pytest.mark.parametrize("caps", [(1, 16, 1, 16), (1, 3, 16, 3), (4, 4)])
+def test_one_relayout_serves_every_capacity_group(caps, monkeypatch):
+    """The sorted columns are laid out row-major once a step, whatever the
+    number of capacity groups, and every group gathers its rows from that
+    one source at lane width."""
+    import re
+    _row_major(monkeypatch)
+    ws, _, _, _, dims, plan = _wide_case(caps, True)
+    text = _lowered_pull(lambda ws, plan: mxu_path.pull_pool_cvm(
+        ws, plan, dims, (len(caps), max(caps), 16), True, interpret=True,
+        capacities=caps), ws, plan)
+    lanes = mxu_path.CROSS_LANES
+    assert text.count("optimization_barrier") == 1
+    assert len(re.findall(rf"stablehlo\.pad.*-> tensor<\d+x{lanes}xf32>",
+                          text)) == 1
+    assert len(re.findall(
+        rf'"stablehlo\.gather".*\n?.*-> tensor<\d+x{lanes}xf32>',
+        text)) == len(set(caps))
+
+
+def _wide_trainer():
+    """A 35-wide pooled model with two capacities, one trained pass."""
+    from paddlebox_tpu.config import EmbeddingTableConfig
+    from paddlebox_tpu.models.ctr_dnn import CtrDnn
+    from paddlebox_tpu.ps.pass_manager import BoxPSEngine
+    from paddlebox_tpu.trainer.trainer import SparseTrainer
+
+    D, caps, B = 32, (1, 3, 1, 3), 64
+    cfg, ds = _slot_dataset(caps, 3 * B)
+    eng = BoxPSEngine(EmbeddingTableConfig(
+        embedding_dim=D, shard_num=4,
+        sgd=SparseSGDConfig(mf_create_thresholds=0.0)))
+    eng.begin_feed_pass()
+    for b in ds.get_blocks():
+        eng.add_keys(b.all_keys())
+    eng.end_feed_pass()
+    eng.begin_pass()
+    eng.ws["mf_size"] = jnp.full_like(eng.ws["mf_size"], D)
+    tr = SparseTrainer(eng, CtrDnn(num_slots=len(caps), emb_width=3 + D,
+                                   dense_dim=2, hidden=(16,)),
+                       cfg, batch_size=B, seed=0)
+    stats = tr.train_pass(tr.build_pass_feed(ds))
+    return stats, {k: np.asarray(v) for k, v in eng.ws.items()}
+
+
+@pytest.mark.parametrize("row_major", [True, False])
+def test_gauge_says_which_layout_the_pull_crossing_took(row_major,
+                                                        monkeypatch):
+    """``ps.mxu.pull_cross_lane_width`` is set when the step is built:
+    128 where the row-major crossing is taken, 0 where the feature-major
+    one is; and the trained pass is the same on both sides of the rule."""
+    from paddlebox_tpu.utils.monitor import stat_get
+    if row_major:
+        _row_major(monkeypatch)
+    stats, ws = _wide_trainer()
+    assert stat_get("ps.mxu.pull_cross_lane_width") == (
+        mxu_path.CROSS_LANES if row_major else 0)
+    assert stats["batches"] == 3 and np.isfinite(stats["loss"])
+    monkeypatch.undo()
+    if not row_major:
+        _row_major(monkeypatch)
+    other, ws_other = _wide_trainer()
+    assert stat_get("ps.mxu.pull_cross_lane_width") == (
+        0 if row_major else mxu_path.CROSS_LANES)
+    assert stats["loss"] == other["loss"]
+    for k in ws:
+        np.testing.assert_array_equal(ws[k], ws_other[k], err_msg=k)
+
+
+@pytest.mark.parametrize("w,p_pad,itemsize,lanes", [
+    (11, 425_984, 4, 0),        # deepfm_criteo, both cells: 26 MiB
+    (35, 851_968, 4, 128),      # widedeep_seq.epochs: 130 MiB
+    (35, 851_968, 2, 0),        # the same under mxu_crossing_bf16: 78 MiB
+    (2051, 4096, 4, 0),         # ouro_2p6b.seq_epochs: past the band
+    (35, 638_976, 4, 0),        # 97.5 MiB fits (12.9 against 19.0 ms)
+    (43, 638_976, 4, 128),      # 117 MiB does not (61.3 against 19.0 ms)
+    (27, 851_968, 4, 0),        # 104 MiB fits (10.7 against 19.3 ms)
+    (19, 1_277_952, 4, 128),    # 117 MiB does not (34.9 against 20.2 ms)
+    (11, 3_407_872, 4, 0),      # two tiles: left alone whatever the size
+    (56, 851_968, 4, 128), (59, 851_968, 4, 0), (131, 851_968, 4, 0)])
+def test_lane_rule_reads_static_shapes_alone(w, p_pad, itemsize, lanes):
+    assert mxu_path.cross_lane_width(w, p_pad, itemsize) == lanes
+    assert mxu_path.cross_lane_width(w, p_pad, itemsize, "sort") == 0
+
+
+@pytest.mark.parametrize("row_major", [False, True])
+def test_bf16_crossing_takes_either_layout(row_major, monkeypatch):
+    """FLAGS_mxu_crossing_bf16 under the layout rule: the 35-wide pooled
+    pull in bfloat16 stays within bf16 tolerance of float32 whichever
+    layout the rule gives it, and is the same bits in both."""
+    from paddlebox_tpu import flags
+    caps = (1, 16, 1, 16)
+    ws, _, _, _, dims, plan = _wide_case(caps, True)
+
+    def pull():
+        return np.asarray(mxu_path.pull_pool_cvm(
+            ws, plan, dims, (4, 16, 16), True, interpret=True,
+            capacities=caps))
+
+    f32 = pull()
+    flags.set_flags({"mxu_crossing_bf16": True})
+    try:
+        fm = pull()
+        if row_major:
+            _row_major(monkeypatch)
+            assert mxu_path.pull_cross_lane_width(ws, plan, dims) == 128
+        got = pull()
+    finally:
+        flags.set_flags({"mxu_crossing_bf16": False})
+    np.testing.assert_allclose(got, f32, atol=0.3, rtol=2e-2)
+    np.testing.assert_array_equal(got, fm)
