@@ -61,6 +61,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from paddlebox_tpu.models import rowlm
+from paddlebox_tpu.models.rowlm import rms_norm, sampled_negatives  # noqa: F401
 from paddlebox_tpu.utils.monitor import stat_add, stat_set
 
 STATS = ("targets", "exit_expected_step_sum", "tokens_valid",
@@ -68,18 +70,6 @@ STATS = ("targets", "exit_expected_step_sum", "tokens_valid",
 _NEG = -1e30          # finite "minus infinity": a fully masked row stays finite
 ATTN_CHUNK = 2        # sequences a block of attention (scores [c, heads, n, n])
 HEAD_BLOCK = 1024     # tokens a block of the head (logits [block, vocabulary])
-
-
-def rms_norm(z, g, eps):
-    """RMS(z; g).  An all-zero vector (a row the table has not created
-    yet, a position that saw nothing but such rows) passes no gradient:
-    the norm's Jacobian there is g / sqrt(eps), and a chain of them, one
-    a norm down a position that stays zero, overflows float32 into NaN
-    parameter gradients, where the true contribution (0 x finite) is 0."""
-    dead = jnp.all(z == 0, axis=-1, keepdims=True)
-    z = jnp.where(dead, jax.lax.stop_gradient(z), z)
-    return g * z * jax.lax.rsqrt(jnp.mean(z * z, axis=-1, keepdims=True)
-                                 + eps)
 
 
 def rope_tables(n: int, head_dim: int, theta: float):
@@ -96,26 +86,6 @@ def rope(x, cos, sin):
     half = x.shape[-1] // 2
     rot = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
     return x * cos[:, None, :] + rot * sin[:, None, :]
-
-
-def sampled_negatives(seed: int, first_key, lengths, n: int, vocab: int):
-    """One vocabulary id a (example, position), uniform, from a counter
-    hash of (seed, example, position); an example is named by its place
-    in the batch, its first key and its length, so batches differ.
-    first_key, lengths [B] int32 -> [B, n] int32."""
-    def mix(h):
-        h = (h ^ (h >> 16)) * jnp.uint32(0x7FEB352D)
-        h = (h ^ (h >> 15)) * jnp.uint32(0x846CA68B)
-        return h ^ (h >> 16)
-
-    b = first_key.shape[0]
-    ex = mix(jnp.uint32(seed & 0xFFFFFFFF)
-             + jnp.arange(b, dtype=jnp.uint32) * jnp.uint32(0x9E3779B1)
-             + first_key.astype(jnp.uint32) * jnp.uint32(0x85EBCA77)
-             + lengths.astype(jnp.uint32) * jnp.uint32(0xC2B2AE3D))
-    h = mix(ex[:, None] + jnp.arange(n, dtype=jnp.uint32)[None, :]
-            * jnp.uint32(0x27D4EB2F))
-    return (h % jnp.uint32(vocab)).astype(jnp.int32)
 
 
 class LoopLM:
@@ -202,21 +172,10 @@ class LoopLM:
         cross-entropy [M], gate logit [M], log p of target and of
         negative [M].  Token blocks under a checkpoint: the backward
         recomputes a block's logits instead of keeping [M, vocabulary]."""
-        m, hd = h.shape
-        blk = min(HEAD_BLOCK, m)
-        pad = -m % blk
-        if pad:
-            h = jnp.pad(h, ((0, pad), (0, 0)))
-            targets = jnp.pad(targets, (0, pad))
-            negatives = jnp.pad(negatives, (0, pad))
-
         @jax.checkpoint
         def block(args):
             hb, yb, nb = args                      # [blk, H], [blk], [blk]
-            z = hb @ params["head"]                # [blk, V]
-            lse = jax.nn.logsumexp(z, axis=-1)
-            zy = jnp.take_along_axis(z, yb[:, None], axis=-1)[:, 0]
-            zn = jnp.take_along_axis(z, nb[:, None], axis=-1)[:, 0]
+            lse, zy, zn = rowlm.head_logits(params["head"], hb, yb, nb)
             # one column: kept exact, so that it does not depend on
             # whether the compiler takes it to the MXU
             gate = jnp.dot(hb, params["gate_w"],
@@ -224,11 +183,8 @@ class LoopLM:
                 + params["gate_b"]
             return lse - zy, gate, zy - lse, zn - lse
 
-        with jax.named_scope("tower.head_loss"):
-            out = jax.lax.map(block, (h.reshape(-1, blk, hd),
-                                      targets.reshape(-1, blk),
-                                      negatives.reshape(-1, blk)))
-        return tuple(a.reshape(-1)[:m] for a in out)
+        return rowlm.map_token_blocks(block, HEAD_BLOCK, h, targets,
+                                      negatives)
 
     def tower_terms(self, params, x, lengths, targets, negatives):
         """x [B, n, H] -> ce [T, M], gate logits [T, M] and the last
@@ -292,14 +248,8 @@ class LoopLM:
         x = rows[:, self.seq_key_slot]                        # [B, n, H]
         ln = lengths[:, self.seq_key_slot]
         b, n, h = x.shape
-        tokens = jnp.clip(seq_keys[:, :n] - self.key_base, 0, self.vocab - 1)
-        pos = jnp.arange(n)
-        has_target = ((pos[None, :] < ln[:, None] - 1)
-                      & valid[:, None]).reshape(-1)        # [M]
-        targets = jnp.concatenate(
-            [tokens[:, 1:], jnp.zeros((b, 1), tokens.dtype)], axis=1)
-        negatives = sampled_negatives(self.neg_seed, seq_keys[:, 0], ln, n,
-                                      self.vocab)
+        targets, has_target, negatives = rowlm.next_token_plan(
+            seq_keys, ln, valid, n, self.key_base, self.vocab, self.neg_seed)
         ce, gate, (lp_pos, lp_neg) = self.tower_terms(
             params, x, ln, targets.reshape(-1), negatives.reshape(-1))
         with jax.named_scope("tower.head_loss"):
@@ -312,15 +262,9 @@ class LoopLM:
             loss = jnp.sum(per * w) / jnp.maximum(count, 1.0)
             steps = jnp.arange(1, self.ut_steps + 1, dtype=jnp.float32)
             expected = jnp.sum(jnp.sum(p * steps[:, None], axis=0) * w)
-            tokens = jnp.sum(jnp.where(valid, jnp.minimum(ln, n), 0)
-                             ).astype(jnp.float32)
-            ln_v = math.log(self.vocab)
+            tokens = rowlm.token_counts(ln, valid, n)
             aux = {
-                "auc_pred": jax.nn.sigmoid(
-                    jnp.concatenate([lp_pos, lp_neg]) + ln_v),
-                "auc_label": jnp.concatenate(
-                    [jnp.ones_like(lp_pos), jnp.zeros_like(lp_neg)]),
-                "auc_mask": jnp.concatenate([has_target, has_target]),
+                **rowlm.auc_pairs(lp_pos, lp_neg, has_target, self.vocab),
                 "stats": jnp.stack([count, expected, tokens,
                                     b * n - tokens]),
             }
@@ -330,7 +274,6 @@ class LoopLM:
         """Counters of a pass: ``total`` is ``stats`` summed over its
         ``steps`` steps (the trainer reads it back once a pass)."""
         targets, expected, valid, padded = (float(v) for v in total)
-        stat_add("tower.tokens_valid", valid)
-        stat_add("tower.tokens_padded", padded)
+        rowlm.record_padding(valid, padded)
         stat_add("tower.recurrent_steps", float(self.ut_steps * steps))
         stat_set("tower.exit_expected_step", expected / max(targets, 1.0))

@@ -15,6 +15,7 @@ sorting, no dynamic shapes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -174,3 +175,154 @@ class MoELayer:
             + params["b2"][:, None, :]
         y = jnp.einsum("ecd,tec->td", out, combine)
         return y, aux
+
+
+# -- a chip's share of a routed layer: sort, keep, one expert a block --------
+
+EXPERT_BLOCK = 1024   # rows of the sorted assignments a block (one expert's)
+
+
+def route_top_k(x, router, bias, top_k: int, scale: float):
+    """Sigmoid routing over ALL experts: s = sigmoid(x W_r) (the one
+    product kept in float32: which expert comes eighth must not hang on
+    how the device rounds), chosen = top-k of s + bias, weights ``scale *
+    s_e / sum over the chosen``.  x [T, H] -> ids [T, k], weights [T, k]."""
+    s = jax.nn.sigmoid(jnp.dot(x, router,
+                               precision=jax.lax.Precision.HIGHEST))
+    _, idx = lax.top_k(s + bias, top_k)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+
+
+def _block(plan, i, size: int, top_k: int):
+    """Block i of the sorted held assignments: its expert, the
+    assignments in it (ids into [T * k]), their tokens, and which of its
+    rows hold one (an expert's last block is filled up with rows that
+    hold none).  ``plan`` = (order, load, first_block)."""
+    order, load, first_block = plan
+    with jax.named_scope("dispatch"):
+        e = jnp.sum(first_block <= i) - 1            # the block's expert
+        inside = (i - first_block[e]) * size + jnp.arange(size)
+        keep = inside < load[e]
+        at = jnp.cumsum(load)[e] - load[e] + inside  # place in the sort
+        rows = order[jnp.clip(at, 0, order.shape[0] - 1)]
+    return e, rows, rows // top_k, keep
+
+
+def _expert_rows(xs, wt, wg, wu, wd):
+    """One expert's SwiGLU on its rows, times their routing weights."""
+    with jax.named_scope("experts"):
+        return ((jax.nn.silu(xs @ wg) * (xs @ wu)) @ wd) * wt[:, None]
+
+
+def _block_inputs(x, weights, experts, plan, i, size, top_k):
+    e, rows, token, keep = _block(plan, i, size, top_k)
+    with jax.named_scope("dispatch"):
+        xs = jnp.where(keep[:, None], x[token], 0.0)
+        wt = jnp.where(keep, weights[rows], 0.0)
+        w_e = tuple(lax.dynamic_index_in_dim(w, e, keepdims=False)
+                    for w in experts)
+    return e, rows, token, keep, xs, wt, w_e
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _expert_blocks(x, weights, wg, wu, wd, plan, size: int, top_k: int):
+    """The held assignments, sorted by expert and cut into blocks of
+    ``size`` rows none of which holds two experts, multiplied block by
+    block: as many blocks as the data fill (a ``while``, so the backward
+    is written out below: the same blocks again, each recomputed and
+    differentiated alone, an expert's weight gradient added in place).
+    Returns the output [T, H] and the assignments the blocks took."""
+    blocks = plan[2][-1]
+
+    def one(i, carry):
+        out, taken = carry
+        _, _, token, keep, xs, wt, w_e = _block_inputs(
+            x, weights, (wg, wu, wd), plan, i, size, top_k)
+        ys = _expert_rows(xs, wt, *w_e)
+        with jax.named_scope("combine"):
+            out = out.at[token].add(jnp.where(keep[:, None], ys, 0.0))
+        return out, taken + jnp.sum(keep).astype(jnp.float32)
+
+    return lax.fori_loop(0, blocks, one,
+                         (jnp.zeros_like(x), jnp.zeros((), jnp.float32)))
+
+
+def _expert_blocks_fwd(x, weights, wg, wu, wd, plan, size, top_k):
+    return (_expert_blocks(x, weights, wg, wu, wd, plan, size, top_k),
+            (x, weights, wg, wu, wd, plan))
+
+
+def _expert_blocks_bwd(size, top_k, saved, g):
+    x, weights, wg, wu, wd, plan = saved
+
+    def one(i, grads):
+        dx, dwt, dw = grads
+        e, rows, token, keep, xs, wt, w_e = _block_inputs(
+            x, weights, (wg, wu, wd), plan, i, size, top_k)
+        _, vjp = jax.vjp(_expert_rows, xs, wt, *w_e)
+        dxs, dwt_rows, *dw_e = vjp(jnp.where(keep[:, None], g[0][token], 0.0))
+        with jax.named_scope("combine"):
+            dx = dx.at[token].add(jnp.where(keep[:, None], dxs, 0.0))
+            dwt = dwt.at[rows].add(jnp.where(keep, dwt_rows, 0.0))
+            dw = tuple(a.at[e].add(b) for a, b in zip(dw, dw_e))
+        return dx, dwt, dw
+
+    dx, dwt, dw = lax.fori_loop(
+        0, plan[2][-1], one,
+        (jnp.zeros_like(x), jnp.zeros_like(weights),
+         tuple(jnp.zeros_like(w) for w in (wg, wu, wd))))
+    return (dx, dwt, *dw, None)
+
+
+_expert_blocks.defvjp(_expert_blocks_fwd, _expert_blocks_bwd)
+
+
+def routed_experts(x, live, router, bias, experts, held, top_k: int,
+                   scale: float):
+    """The part of a routed SwiGLU layer that the experts held here give:
+    sum over e chosen AND in ``held`` of w_e E_e(x), for x [T, H].
+
+    The router scores every expert (``router`` [H, E]); ``held`` names the
+    ones whose weights ``experts`` = (wg, wu [n_held, H, F], wd [n_held,
+    F, H]) are, in that order.  The step's assignments are sorted by
+    expert; those of held experts are cut into blocks of ``EXPERT_BLOCK``
+    rows, an expert's last block filled up so that no block holds two,
+    and each block is one expert's three plain products.  The blocks in
+    use are as many as the data fill (the mean need is ``k * n_held / E``
+    assignments a position, the worst case ``min(k, n_held)``: a router
+    may send every token here, and while a pass's rows are new it does):
+    **no assignment is dropped**, no row is multiplied for an expert it
+    did not choose, and a step pays for the blocks its data need.  An
+    assignment to an expert that lies elsewhere costs nothing here and
+    nothing stands in for it (on several chips the exchange would carry
+    it away).  A position outside ``live`` [T], or whose x is all zero
+    (every expert returns 0 for it, and its scores tie), is not
+    dispatched.
+
+    Returns the output [T, H] and counts: ``held`` assignments to held
+    experts, ``dropped`` of them that no block took (``held`` less the
+    rows the blocks counted as they ran: 0), ``load`` [n_held] tokens
+    each expert received."""
+    n_held = len(held)
+    size = min(EXPERT_BLOCK, x.shape[0] * top_k)
+    with jax.named_scope("router"):
+        idx, w = route_top_k(x, router, bias, top_k, scale)
+    with jax.named_scope("dispatch"):
+        # a held expert's place in ``experts``; n_held: it lies elsewhere
+        local = jnp.full((router.shape[1],), n_held, jnp.int32).at[
+            jnp.asarray(held)].set(jnp.arange(n_held, dtype=jnp.int32))
+        live = live & jnp.any(x != 0, axis=-1)
+        key = jnp.where(live[:, None], local[idx], n_held).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        load = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :], axis=0
+                       ).astype(jnp.int32)
+        # the first block of each expert, and past the last: all in use
+        first_block = jnp.concatenate([
+            jnp.zeros((1,), jnp.int32), jnp.cumsum(-(-load // size))])
+    out, taken = _expert_blocks(x, w.reshape(-1), *experts,
+                                (order, load, first_block), size, top_k)
+    held_n = jnp.sum(load).astype(jnp.float32)
+    counts = {"held": held_n, "dropped": held_n - taken,
+              "load": load.astype(jnp.float32)}
+    return out, counts

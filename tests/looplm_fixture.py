@@ -83,10 +83,13 @@ def jax_copy(tree):
     return jax.tree.map(lambda a: np.array(a), tree)
 
 
-def fleet_run(tmp_path, cfg, passes=2, lines=4, seed=0, trainer_cls=Snapshots):
+def fleet_run(tmp_path, cfg, passes=2, lines=4, seed=0, trainer_cls=Snapshots,
+              model=None):
     """``passes`` tiny passes through fleet.init -> BoxPSDataset ->
     SparseTrainer -> fleet.train_passes; returns (trainer, metrics,
-    engine)."""
+    engine).  ``model``: another row model over the same feed (its rows
+    as wide as ``cfg``'s table)."""
+    hidden = cfg["table"]["embedx_dim"]
     files = []
     for p in range(passes):
         path = str(tmp_path / f"seq-{p}.txt")
@@ -94,11 +97,11 @@ def fleet_run(tmp_path, cfg, passes=2, lines=4, seed=0, trainer_cls=Snapshots):
                         cfg["vocab_size"])
         files.append([path])
     fl = fleet.init(DistributedStrategy(table=EmbeddingTableConfig(
-        embedding_dim=HIDDEN, shard_num=4, sgd=SparseSGDConfig(**SGD))))
+        embedding_dim=hidden, shard_num=4, sgd=SparseSGDConfig(**SGD))))
     engine = fl.init_engine(seed=seed)
     ds = fleet.DatasetFactory().create_dataset("BoxPSDataset",
                                                feed_config=feed_config())
-    trainer = trainer_cls(engine, model_of(cfg), feed_config(),
+    trainer = trainer_cls(engine, model or model_of(cfg), feed_config(),
                           batch_size=BATCH, seed=seed)
     metrics = fleet.train_passes(trainer, ds, files, date="20260930",
                                  prefetch=False)
