@@ -32,7 +32,7 @@ from typing import Iterable, Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from paddlebox_tpu.config import DataFeedConfig, SlotConfig
-from paddlebox_tpu.data.slot_record import SlotRecordBlock
+from paddlebox_tpu.data.slot_record import BlockStore, SlotRecordBlock
 from paddlebox_tpu.utils import trace
 from paddlebox_tpu.utils.monitor import stat_add
 
@@ -182,9 +182,13 @@ class DataFeed:
 
     def __init__(self, config: DataFeedConfig, parse_ins_id: bool = False,
                  parse_logkey: bool = False, chunk_lines: int = 4096,
-                 use_native: bool = True, input_table=None):
+                 use_native: bool = True, input_table=None,
+                 block_store: Optional[BlockStore] = None):
         self.config = config
         self.chunk_lines = chunk_lines
+        # where the bytes path's blocks get their memory (the reading
+        # dataset's store); the text path's blocks own ordinary arrays
+        self._block_store = block_store
         self._parser = make_parser(config, parse_ins_id, parse_logkey,
                                    use_native=use_native,
                                    input_table=input_table)
@@ -215,7 +219,7 @@ class DataFeed:
                 yield block
 
     def _read_bytes(self, path: str) -> Iterator[SlotRecordBlock]:
-        chunk = self._parser.new_chunk()
+        chunk = self._parser.new_chunk(self._block_store)
         with open_bytes(path, self.config.pipe_command) as f:
             window = _ReadWindow(f, self.buffer_bytes)
             try:

@@ -6,6 +6,19 @@ memory by reader threads, optionally shuffled locally and across hosts, then
 iterated as device batches while the next pass preloads
 (≙ PreLoadIntoMemory data_set.cc:2219, BoxHelper overlap box_wrapper.h:1141).
 
+A pass's parsed blocks lie in slabs of the dataset's ``BlockStore`` (the
+rebuild's ``SlotObjPool``, data_feed.h:305; data/slot_record.py), and the
+slabs go back to it at the moment the blocks are dead: at the entry of
+``load_into_memory`` (for the blocks it is about to replace), on
+``release_memory``, on ``wait_preload_done`` and when a shuffle or
+``preprocess_instance`` replaces them with one merged copy.  So the next pass
+is parsed into the memory the last one held, and one pass of blocks is
+resident where two were.  **A block from ``get_blocks()`` is valid until the
+dataset next replaces its blocks**; whoever keeps data longer copies it
+(``SlotRecordBlock.concat``, also of one block, copies).
+``preload_into_memory`` reads beside the live blocks, so it draws only on what
+an earlier ``release_memory`` returned.
+
 The inter-host global shuffle (≙ PaddleShuffler MPI transport,
 data_set.cc:2440-2648) goes through a pluggable ``ShuffleTransport``; the
 in-process LoopbackTransport covers single-host and tests, a gRPC/proxy
@@ -22,7 +35,7 @@ import numpy as np
 
 from paddlebox_tpu.config import DataFeedConfig
 from paddlebox_tpu.data.data_feed import DataFeed
-from paddlebox_tpu.data.slot_record import SlotRecordBlock
+from paddlebox_tpu.data.slot_record import BlockStore, SlotRecordBlock
 from paddlebox_tpu.utils import lockdep, trace
 from paddlebox_tpu.utils.channel import Channel
 from paddlebox_tpu.utils.monitor import stat_add
@@ -123,6 +136,7 @@ class SlotDataset:
         self.transport = transport or LoopbackTransport()
         self.filelist: List[str] = []
         self._blocks: List[SlotRecordBlock] = []
+        self._store = BlockStore()      # the memory of the parsed blocks
         self._preload_future = None
         self._lock = lockdep.lock("data.dataset.SlotDataset._lock")
         self._rng = np.random.default_rng(feed_config.rand_seed or None)
@@ -147,7 +161,8 @@ class SlotDataset:
         def read_one(path: str) -> None:
             feed = DataFeed(self.feed_config, self.parse_ins_id,
                             self.parse_logkey,
-                            input_table=self.input_table)
+                            input_table=self.input_table,
+                            block_store=self._store)
             # per-file rng seeded by (rand_seed, path): the kept instance
             # SET is deterministic regardless of reader-thread interleaving
             import zlib
@@ -159,7 +174,9 @@ class SlotDataset:
                     # feed-level instance downsampling
                     # (≙ DataFeedDesc.sample_rate)
                     keep = np.nonzero(rng_f.random(block.n) < rate)[0]
-                    block = block.select(keep)
+                    parsed, block = block, block.select(keep)
+                    if parsed.storage is not None:  # the copy is what stays
+                        self._store.give_back([parsed.retire()])
                     if block.n == 0:
                         continue
                 with trace.span("data.read.key_tap"):
@@ -174,9 +191,17 @@ class SlotDataset:
             list(pool.map(read_one, files))
         return blocks
 
+    def _set_blocks(self, blocks: List[SlotRecordBlock]) -> None:
+        """Replace the pass's blocks; the replaced ones are dead from here
+        (the lifetime contract above) and their slabs go back."""
+        dead, self._blocks = self._blocks, blocks
+        self._store.release_pass(
+            [b.retire() for b in dead if b.storage is not None])
+
     def load_into_memory(self) -> None:
         with trace.span("data.load_into_memory", files=len(self.filelist)):
-            self._blocks = self._read_all()
+            self._set_blocks([])    # the read lands where they lay
+            self._set_blocks(self._read_all())
         self._pv_grouped = False   # fresh records: re-run preprocess_instance
         stat_add("stat_dataset_instances", self.instance_num())
 
@@ -190,12 +215,12 @@ class SlotDataset:
 
     def wait_preload_done(self) -> None:
         if self._preload_future is not None:
-            self._blocks = self._preload_future.result()
+            self._set_blocks(self._preload_future.result())
             self._preload_future = None
             self._pv_grouped = False
 
     def release_memory(self) -> None:
-        self._blocks = []
+        self._set_blocks([])
 
     # -- shuffle -------------------------------------------------------------
     def local_shuffle(self) -> None:
@@ -203,7 +228,7 @@ class SlotDataset:
         block = SlotRecordBlock.concat(self._blocks)
         if block.n:
             block = block.permute(self._rng.permutation(block.n))
-        self._blocks = [block] if block.n else []
+        self._set_blocks([block] if block.n else [])
 
     def global_shuffle(self, by_ins_id: bool = False) -> None:
         """Redistribute records across hosts: hash(ins_id) or random % world
@@ -243,7 +268,7 @@ class SlotDataset:
         block = SlotRecordBlock.concat(keep + received)
         if block.n:
             block = block.permute(self._rng.permutation(block.n))
-        self._blocks = [block] if block.n else []
+        self._set_blocks([block] if block.n else [])
 
     # -- PV / ins merge (AucRunner) -----------------------------------------
     def preprocess_instance(self) -> None:
@@ -257,7 +282,7 @@ class SlotDataset:
         if merged.n == 0 or merged.search_ids is None:
             return
         order = np.argsort(merged.search_ids, kind="stable")
-        self._blocks = [merged.permute(order)]
+        self._set_blocks([merged.permute(order)])
         self._pv_grouped = True
 
     def postprocess_instance(self) -> None:

@@ -1,4 +1,5 @@
-"""SlotRecord storage: struct-of-arrays blocks of instances.
+"""SlotRecord storage: struct-of-arrays blocks of instances, and the pool
+their memory comes from.
 
 TPU-first redesign of the reference's per-record SlotRecordObject + arena pool
 (data_feed.h:97-440: SlotValues, SlotRecordObject, SlotObjPool).  Instead of
@@ -7,14 +8,33 @@ millions of tiny heap records recycled through a pool, instances travel in
 records.  This keeps host memory flat and copies vectorized — the role the
 arena played for C++ — and is exactly the layout the device batch-pack wants
 (SURVEY.md §7 step 2).
+
+``BlockStore`` is the rebuild's ``SlotObjPool`` (data_feed.h:305: bulk
+get/put of records): a block parsed by the native reader lies in ONE slab,
+its arrays typed views of it, and a pass's slabs go back to the store when
+the pass's blocks are dead, so the next pass is parsed into memory that has
+been touched before (a fresh page costs a fault, and a pass is ~230k of them).
+
+**Block lifetime.**  A ``SlotRecordBlock`` handed out by a ``SlotDataset``
+(``get_blocks``) is valid until that dataset next replaces its blocks:
+``load_into_memory``, ``release_memory``, ``wait_preload_done``, a shuffle or
+``preprocess_instance``.  Its storage is then given back and will be
+overwritten by a later read, and the block itself is retired: reading its
+slots raises.  Whoever keeps data longer copies it; ``concat`` (one block or
+many), ``select`` / ``permute`` / ``slice`` and ``all_keys`` all return arrays
+of their own.
 """
 
 from __future__ import annotations
 
+import collections.abc
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+import threading
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from paddlebox_tpu.utils.monitor import stat_add
 
 Ragged = Tuple[np.ndarray, np.ndarray]  # (values [total], offsets [n+1])
 
@@ -50,6 +70,86 @@ def _select_ragged(r: Ragged, idx: np.ndarray) -> Ragged:
     return values[flat_idx], new_off
 
 
+PAGE = 4096
+ALIGN = 64      # every array carved from a slab starts on a cache line
+
+
+def size_class(nbytes: int) -> int:
+    """``nbytes`` rounded up to a page, then to eight classes a power of
+    two (at most an eighth over): blocks whose slots hold a varying number
+    of keys still find each other's slabs."""
+    nbytes = max(PAGE, -(-nbytes // PAGE) * PAGE)
+    step = max(PAGE, 1 << max(0, (nbytes - 1).bit_length() - 4))
+    return -(-nbytes // step) * step
+
+
+class BlockStore:
+    """Storage of parsed blocks, recycled from pass to pass (≙ SlotObjPool,
+    data_feed.h:305).  ``take`` answers with a page-aligned ``uint8`` slab of
+    the request's size class: the smallest one given back that can hold it,
+    else a new one.  ``release_pass`` takes the slabs of a pass whose blocks
+    are dead and lets go of whatever else it held, so the store never holds
+    more bytes than the last released pass did; ``give_back`` returns single
+    slabs (a block dropped while its pass is still being read).  Thread-safe;
+    it learns nothing from its callers but sizes, and a store that has
+    nothing large enough simply allocates."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._free: Dict[int, List[np.ndarray]] = {}    # class -> slabs
+        self._free_bytes = 0
+
+    @property
+    def free_bytes(self) -> int:
+        return self._free_bytes
+
+    def take(self, nbytes: int) -> np.ndarray:
+        want = size_class(nbytes)
+        slab = None
+        with self._lock:
+            fit = min((c for c, slabs in self._free.items()
+                       if c >= want and slabs), default=None)
+            if fit is not None:
+                slab = self._free[fit].pop()
+                self._free_bytes -= slab.nbytes
+        if slab is not None:
+            stat_add("data.read.block_bytes_reused", nbytes)
+            return slab
+        stat_add("data.read.block_bytes_fresh", nbytes)
+        raw = np.empty(want + PAGE, np.uint8)
+        lo = -raw.ctypes.data % PAGE
+        return raw[lo:lo + want]
+
+    def give_back(self, slabs: Iterable[np.ndarray]) -> None:
+        with self._lock:
+            for slab in slabs:
+                self._free.setdefault(slab.nbytes, []).append(slab)
+                self._free_bytes += slab.nbytes
+
+    def release_pass(self, slabs: Sequence[np.ndarray]) -> None:
+        """The slabs of a whole pass: they become all the store holds."""
+        if not slabs:
+            return
+        with self._lock:
+            self._free, self._free_bytes = {}, 0
+        self.give_back(slabs)
+
+
+class _RetiredSlots(collections.abc.Mapping):
+    """What a retired block has in place of its slots: any read raises."""
+
+    def _raise(self, *_):
+        raise RuntimeError(
+            "SlotRecordBlock used after its dataset replaced it "
+            "(load_into_memory / release_memory / a shuffle): a block is "
+            "valid until then, copy what must outlive it")
+
+    __getitem__ = __iter__ = __len__ = _raise
+
+
+_RETIRED = _RetiredSlots()
+
+
 @dataclasses.dataclass
 class SlotRecordBlock:
     """A batch of instances in struct-of-arrays layout."""
@@ -64,11 +164,21 @@ class SlotRecordBlock:
     search_ids: Optional[np.ndarray] = None   # uint64, PV/AucRunner merge key
     cmatch: Optional[np.ndarray] = None       # int32
     rank: Optional[np.ndarray] = None         # int32
+    # the BlockStore slab the arrays above are views of (native reader);
+    # None for a block that owns ordinary arrays
+    storage: Optional[np.ndarray] = None
 
     # ------------------------------------------------------------------
     @property
     def feasign_count(self) -> int:
         return sum(int(v[1][-1]) for v in self.uint64_slots.values())
+
+    def retire(self) -> Optional[np.ndarray]:
+        """Detach the slab for its store; the block reads as dead after."""
+        slab, self.storage = self.storage, None
+        self.uint64_slots = self.float_slots = self.aux_slots = _RETIRED
+        self.ins_ids = self.search_ids = self.cmatch = self.rank = None
+        return slab
 
     def select(self, idx: np.ndarray) -> "SlotRecordBlock":
         idx = np.asarray(idx, dtype=np.int64)

@@ -1,4 +1,14 @@
-"""ctypes wrapper over the native parser/hash library."""
+"""ctypes wrapper over the native parser/hash library.
+
+A block that ``NativeChunk.take`` makes lies in one buffer: every
+``(offsets, values)`` pair is a typed view of it, a cache line apart.  A
+chunk that was given a ``BlockStore`` (``new_chunk(store)``: the reading
+``SlotDataset``'s, the rebuild's ``SlotObjPool``, data_feed.h:305) asks
+it for that buffer, so a pass is parsed into the slabs the last pass's
+blocks gave back, and leaves the slab on ``block.storage`` for the
+dataset to give back in turn; the block is valid until then
+(data/slot_record.py has the lifetime contract).
+"""
 
 from __future__ import annotations
 
@@ -9,7 +19,8 @@ from typing import Optional
 import numpy as np
 
 from paddlebox_tpu.config import DataFeedConfig
-from paddlebox_tpu.data.slot_record import SlotRecordBlock
+from paddlebox_tpu.data.slot_record import (ALIGN, BlockStore,
+                                             SlotRecordBlock)
 from paddlebox_tpu.native import build
 from paddlebox_tpu.utils.monitor import stat_add
 
@@ -105,8 +116,8 @@ class NativeSlotParser:
             return False
         return True
 
-    def new_chunk(self) -> "NativeChunk":
-        return NativeChunk(self)
+    def new_chunk(self, store: Optional[BlockStore] = None) -> "NativeChunk":
+        return NativeChunk(self, store)
 
     def parse_block(self, lines) -> SlotRecordBlock:
         chunk = NativeChunk(self)
@@ -122,11 +133,14 @@ class NativeChunk:
     from lines or from successive byte ranges of a file, and that ``take``
     turns into a SlotRecordBlock.  The handle lives until ``close`` and is
     emptied, not freed, between blocks: its columns keep their memory, so
-    a file's later chunks grow nothing and fault no fresh page.  One
-    thread at a time."""
+    a file's later chunks grow nothing and fault no fresh page.  With a
+    ``store`` the blocks' own memory is recycled too (``take``).  One thread
+    at a time."""
 
-    def __init__(self, parser: NativeSlotParser):
+    def __init__(self, parser: NativeSlotParser,
+                 store: Optional[BlockStore] = None):
         self._parser = parser
+        self._store = store
         self._bytes_entry = None
         self._handle = None
         self.n = 0                  # records held
@@ -177,31 +191,46 @@ class NativeChunk:
         return consumed.value
 
     def take(self) -> SlotRecordBlock:
-        """The records held, as a block; the chunk is empty again."""
+        """The records held, as a block; the chunk is empty again.  The
+        block's arrays are typed views, a cache line apart, of one buffer:
+        a slab of the chunk's ``BlockStore`` (``block.storage``, which the
+        block's dataset gives back when the block is dead), or with no
+        store an array of its own."""
         handle, n = self._handle, self.n
         if handle is None:
             return SlotRecordBlock(n=0)
         p, lib = self._parser, _load()
-        block = SlotRecordBlock(n=n)
+        # (dtype, count) of every array, in carving order
+        wanted = []
         for si, slot in enumerate(p.config.slots):
-            total = lib.pbox_slot_total(handle, si)
-            offsets = np.empty(n + 1, np.int64)
+            wanted += [(np.int64, n + 1),
+                       (np.float32 if slot.dtype == "float" else np.uint64,
+                        lib.pbox_slot_total(handle, si))]
+        if p.parse_logkey:
+            wanted += [(np.uint64, n), (np.int32, n), (np.int32, n)]
+        starts, end = [], 0
+        for dtype, count in wanted:
+            starts.append(end)
+            end += -(-count * np.dtype(dtype).itemsize // ALIGN) * ALIGN
+        block = SlotRecordBlock(n=n)
+        if self._store is not None:
+            buf = block.storage = self._store.take(end)
+        else:
+            buf = np.empty(end, np.uint8)
+        arrays = iter([np.ndarray((count,), dtype, buffer=buf, offset=lo)
+                       for lo, (dtype, count) in zip(starts, wanted)])
+        for si, slot in enumerate(p.config.slots):
+            offsets, values = next(arrays), next(arrays)
             if slot.dtype == "float":
-                values = np.empty(total, np.float32)
-                lib.pbox_fill_slot_f32(handle, si,
-                                       values.ctypes.data,
+                lib.pbox_fill_slot_f32(handle, si, values.ctypes.data,
                                        offsets.ctypes.data)
                 block.float_slots[slot.name] = (values, offsets)
             else:
-                values = np.empty(total, np.uint64)
-                lib.pbox_fill_slot_u64(handle, si,
-                                       values.ctypes.data,
+                lib.pbox_fill_slot_u64(handle, si, values.ctypes.data,
                                        offsets.ctypes.data)
                 block.uint64_slots[slot.name] = (values, offsets)
         if p.parse_logkey:
-            sids = np.empty(n, np.uint64)
-            cm = np.empty(n, np.int32)
-            rk = np.empty(n, np.int32)
+            sids, cm, rk = arrays
             lib.pbox_fill_logkeys(handle, sids.ctypes.data,
                                   cm.ctypes.data, rk.ctypes.data)
             block.search_ids, block.cmatch, block.rank = sids, cm, rk

@@ -7,7 +7,8 @@ from paddlebox_tpu.config import DataFeedConfig, SlotConfig
 from paddlebox_tpu.data.data_feed import DataFeed, SlotParser, parse_logkey
 from paddlebox_tpu.data.batch_pack import BatchPacker
 from paddlebox_tpu.data.dataset import SlotDataset, LoopbackTransport
-from paddlebox_tpu.data.slot_record import SlotRecordBlock
+from paddlebox_tpu.data.slot_record import BlockStore, SlotRecordBlock
+from paddlebox_tpu.utils.monitor import StatRegistry, stat_snapshot
 
 
 def make_config():
@@ -475,7 +476,6 @@ def _plugin_feed(spec):
 def test_the_carrier_is_chosen_from_what_the_parser_offers(which, tmp_path):
     native_or_skip()
     from paddlebox_tpu.native import build
-    from paddlebox_tpu.utils.monitor import StatRegistry, stat_snapshot
     cfg = DataFeedConfig(slots=(SlotConfig("s", capacity=2),))
     text = "".join(f"1 {k + 1}\n" for k in range(40))
     if which == "default":
@@ -509,3 +509,259 @@ def test_the_carrier_is_chosen_from_what_the_parser_offers(which, tmp_path):
     # one sample a chunk on either carrier, and the read that found the end
     assert stats["data.read.parse_s.count"] == 3
     assert stats["data.read.lines_s.count"] == 4
+
+
+# -- the block store: a pass's parsed blocks land in the memory the last
+# pass's blocks held (the rebuild's SlotObjPool) ---------------------------
+
+class ScribblingStore(BlockStore):
+    """Every slab that comes back is overwritten before it can be handed
+    out again: a block that still read it, or a fill that wrote short of
+    its array, shows as 0xFF."""
+
+    def give_back(self, slabs):
+        slabs = list(slabs)
+        for slab in slabs:
+            slab[:] = 0xFF
+        super().give_back(slabs)
+
+
+def small_chunks(monkeypatch, buffer_bytes=DataFeed.buffer_bytes,
+                 swap_parser=None):
+    """Datasets made from here on read in chunks of CHUNK records through
+    a ``buffer_bytes`` buffer, with ``swap_parser(feed)``'s parser if
+    given."""
+    from paddlebox_tpu.data import dataset as dataset_mod
+
+    def make_feed(*a, **kw):
+        feed = DataFeed(*a, chunk_lines=CHUNK, **kw)
+        feed.buffer_bytes = buffer_bytes
+        if swap_parser is not None:
+            feed._parser = swap_parser(feed)
+        return feed
+    monkeypatch.setattr(dataset_mod, "DataFeed", make_feed)
+
+
+def one_reader(cfg, store=None, **kw):
+    ds = SlotDataset(cfg, read_threads=1, **kw)
+    if store is not None:
+        ds._store = store
+    return ds
+
+
+def load(ds, files):
+    ds.set_filelist(files)
+    ds.load_into_memory()
+    return ds.get_blocks()
+
+
+def write_passes(tmp_path, case, other_seed=99):
+    """(files of pass A: the case's file; files of pass B: another count
+    of other records in the same format; DataFeed keywords)."""
+    path_a, kw = write_case(case, tmp_path)
+    path_b = tmp_path / "part-00001"
+    path_b.write_text("\n".join(records(
+        37, other_seed, ins_id=kw.get("parse_ins_id", False),
+        logkey=kw.get("parse_logkey", False))) + "\n")
+    return [path_a], [str(path_b)], kw
+
+
+def assert_alternating_loads_equal_fresh_ones(cfg, passes, kw, loads=4):
+    want = [load(one_reader(cfg, **kw), files) for files in passes]
+    ds = one_reader(cfg, ScribblingStore(), **kw)
+    for k in range(loads * len(passes)):
+        which = k % len(passes)
+        assert_blocks_equal(load(ds, passes[which]), want[which])
+    return ds
+
+
+@pytest.mark.parametrize("buffer_bytes", BUFFER_SIZES)
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_recycled_storage_holds_the_blocks_the_files_hold(
+        case, buffer_bytes, tmp_path, monkeypatch):
+    native_or_skip()
+    small_chunks(monkeypatch, buffer_bytes)
+    files_a, files_b, kw = write_passes(tmp_path, case)
+    StatRegistry.instance().reset()
+    assert_alternating_loads_equal_fresh_ones(
+        make_config(), [files_a, files_b], kw)
+    if case not in ("empty_file", "blank_lines_only"):
+        assert stat_snapshot("data.read")["data.read.block_bytes_reused"] > 0
+
+
+def test_recycled_storage_with_blocks_of_many_sizes(tmp_path, monkeypatch):
+    """Slots of 1-40 keys, files of other lengths, passes of other file
+    counts: a request finds a slab of another block's size class, or none."""
+    native_or_skip()
+    small_chunks(monkeypatch)
+    passes = []
+    for p, counts in enumerate([(70, 5, 33), (16, 90), (3,), (41, 41, 41, 2)]):
+        passes.append([])
+        for k, n in enumerate(counts):
+            path = tmp_path / f"pass{p}-part-{k:05d}"
+            path.write_text(
+                "\n".join(records(n, 100 * p + k, wide=True)) + "\n")
+            passes[-1].append(str(path))
+    ds = assert_alternating_loads_equal_fresh_ones(make_config(), passes, {})
+    sizes = {b.storage.nbytes for b in ds.get_blocks()}
+    assert len(sizes) > 1
+
+
+def test_reuse_share_is_zero_on_a_first_load_and_one_from_the_second(
+        tmp_path, monkeypatch):
+    native_or_skip()
+    import json
+    from paddlebox_tpu.utils import obs_server
+    small_chunks(monkeypatch)
+    files_a, _, _ = write_passes(tmp_path, "plain")
+    StatRegistry.instance().reset()
+    ds = one_reader(make_config())
+    load(ds, files_a)
+    first = json.loads(obs_server.render_statz(prefix="data.read"))
+    assert first["data.read.block_bytes_fresh"] > 0
+    assert "data.read.block_bytes_reused" not in first
+    for k in range(1, 4):
+        load(ds, files_a)
+        statz = json.loads(obs_server.render_statz(prefix="data.read"))
+        assert statz["data.read.block_bytes_fresh"] \
+            == first["data.read.block_bytes_fresh"]
+        assert statz["data.read.block_bytes_reused"] \
+            == k * first["data.read.block_bytes_fresh"]
+
+
+def test_preload_does_not_take_the_storage_of_the_live_blocks(
+        tmp_path, monkeypatch):
+    native_or_skip()
+    small_chunks(monkeypatch)
+    files_a, files_b, _ = write_passes(tmp_path, "plain")
+    cfg = make_config()
+    want_a, want_b = (load(one_reader(cfg), f) for f in (files_a, files_b))
+    ds = one_reader(cfg, ScribblingStore())
+    live = load(ds, files_a)
+    slabs = {id(b.storage) for b in live}
+    StatRegistry.instance().reset()
+    ds.set_filelist(files_b)
+    ds.preload_into_memory()
+    ds._preload_future.result()         # read to its end beside the live pass
+    assert "data.read.block_bytes_reused" not in stat_snapshot("data.read")
+    assert_blocks_equal(live, want_a)
+    ds.release_memory()                 # what end_pass does; then the swap
+    ds.wait_preload_done()
+    assert_blocks_equal(ds.get_blocks(), want_b)
+    assert not slabs & {id(b.storage) for b in ds.get_blocks()}
+    # the released pass is what the next preload draws on
+    ds.set_filelist(files_a)
+    ds.preload_into_memory()
+    ds.wait_preload_done()
+    assert stat_snapshot("data.read")["data.read.block_bytes_reused"] > 0
+    assert_blocks_equal(ds.get_blocks(), want_a)
+
+
+def test_store_holds_no_more_than_the_last_released_pass(tmp_path,
+                                                         monkeypatch):
+    native_or_skip()
+    small_chunks(monkeypatch)
+    large = tmp_path / "large"
+    large.write_text("\n".join(records(40 * CHUNK, 1)) + "\n")
+    small = tmp_path / "small"
+    small.write_text("\n".join(records(3 * CHUNK, 2)) + "\n")
+    ds = one_reader(make_config())
+
+    def held():
+        return sum(b.storage.nbytes for b in ds.get_blocks())
+
+    load(ds, [str(large)])
+    large_bytes = held()
+    assert ds._store.free_bytes == 0
+    load(ds, [str(small)])
+    small_bytes = held()
+    assert small_bytes < large_bytes / 8
+    # what the small pass did not take of the large one's is still there
+    assert ds._store.free_bytes == large_bytes - small_bytes
+    ds.release_memory()
+    assert ds._store.free_bytes == small_bytes
+    load(ds, [str(small)])
+    ds.release_memory()
+    assert ds._store.free_bytes == small_bytes
+
+
+def test_a_block_is_dead_once_its_dataset_replaces_it(tmp_path, monkeypatch):
+    native_or_skip()
+    small_chunks(monkeypatch)
+    files_a, files_b, _ = write_passes(tmp_path, "plain")
+    ds = one_reader(make_config())
+    for replace in (lambda: load(ds, files_b), ds.release_memory,
+                    ds.local_shuffle, ds.preprocess_instance):
+        held = load(ds, files_a)[0]
+        if replace == ds.preprocess_instance:
+            for b in ds.get_blocks():       # it groups by search id
+                b.search_ids = np.arange(b.n, dtype=np.uint64)
+        kept = SlotRecordBlock.concat([held])   # a copy outlives it
+        want = kept.all_keys().copy()
+        replace()
+        assert held.storage is None
+        for read in (held.all_keys, lambda: held.uint64_slots["slot_a"],
+                     lambda: held.feasign_count,
+                     lambda: SlotRecordBlock.concat([held])):
+            with pytest.raises(RuntimeError, match="valid until"):
+                read()
+        load(ds, files_b)
+        assert np.array_equal(kept.all_keys(), want)
+
+
+def test_sample_rate_gives_each_parsed_block_back_as_it_is_dropped(
+        tmp_path, monkeypatch):
+    native_or_skip()
+    import dataclasses
+    small_chunks(monkeypatch)
+    path = tmp_path / "part-00000"
+    path.write_text("\n".join(records(20 * CHUNK, 3)) + "\n")
+    cfg = dataclasses.replace(make_config(), sample_rate=0.5, rand_seed=7)
+    want = load(one_reader(cfg), [str(path)])
+    StatRegistry.instance().reset()
+    ds = one_reader(cfg, ScribblingStore())
+    assert_blocks_equal(load(ds, [str(path)]), want)
+    assert all(b.storage is None for b in ds.get_blocks())
+    stats = stat_snapshot("data.read")
+    # one slab serves the file: each chunk is parsed where the last lay
+    assert stats["data.read.block_bytes_reused"] \
+        > 10 * stats["data.read.block_bytes_fresh"]
+
+
+@pytest.mark.parametrize("which", ["use_native_false", "lines_only",
+                                   "python_plugin", "string_slots",
+                                   "so_plugin_without_the_entry"])
+def test_the_text_path_keeps_allocating_and_reads_the_same(which, tmp_path,
+                                                           monkeypatch):
+    native_or_skip()
+    from paddlebox_tpu.data.data_feed import load_parser_plugin, make_parser
+    cfg, kw = make_config(), {}
+    texts = ["\n".join(records(n, s)) + "\n" for n, s in ((50, 0), (37, 1))]
+    if which == "use_native_false":
+        swap = lambda feed: make_parser(feed.config, use_native=False)
+    elif which == "lines_only":
+        swap = lambda feed: LinesOnly(feed._parser)
+    elif which == "string_slots":
+        swap = None
+        cfg = _string_slot_feed()[0].config
+        texts = ["".join(f"1 {k + 1} 1 u{k % m}\n" for k in range(40))
+                 for m in (7, 5)]
+    else:
+        cfg = DataFeedConfig(slots=(SlotConfig("s", capacity=2),))
+        texts = ["".join(f"1 {k + s}\n" for k in range(40)) for s in (1, 9)]
+        spec = "tests.parser_plugin_fixture:create_parser" \
+            if which == "python_plugin" else \
+            _plugin_so_without_the_bytes_entry(tmp_path) + ":pbox_parse_block"
+        swap = lambda feed: load_parser_plugin(spec, feed.config)
+    small_chunks(monkeypatch, swap_parser=swap)
+    passes = []
+    for k, text in enumerate(texts):
+        path = tmp_path / f"part-{k:05d}"
+        path.write_text(text)
+        passes.append([str(path)])
+    StatRegistry.instance().reset()
+    ds = assert_alternating_loads_equal_fresh_ones(cfg, passes, kw)
+    assert all(b.storage is None for b in ds.get_blocks())
+    stats = stat_snapshot("data.read")
+    assert stats["data.read.text_lines"] > 0
+    assert not [k for k in stats if "block_bytes" in k]

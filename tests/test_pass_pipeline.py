@@ -373,36 +373,55 @@ def _write_slot_file(path, rng, n):
             f.write(" ".join(parts) + "\n")
 
 
-def test_fleet_train_passes_parity(tmp_path):
+@pytest.mark.parametrize("n_passes", [2, 5])
+def test_fleet_train_passes_parity(n_passes, tmp_path):
     """fleet.train_passes — the user-level day loop — trains identically
-    with the prefetcher on and off over real files."""
+    with the prefetcher on and off over real files: two seeded passes of
+    other lengths in turn, each after the first parsed into the storage
+    the last one's blocks held (the dataset's BlockStore; the prefetch
+    worker hands it back at its next load, after its own pack)."""
     from paddlebox_tpu import fleet
+    from paddlebox_tpu.native import slot_parser
+    from tests.test_data_pipeline import ScribblingStore
 
     cfg = _simple_cfg()
     files = []
     for p in range(2):
         path = str(tmp_path / f"p{p}.txt")
-        _write_slot_file(path, np.random.default_rng(p), 64)
+        _write_slot_file(path, np.random.default_rng(p), 64 + 23 * p)
         files.append([path])
+    passes = [files[k % 2] for k in range(n_passes)]
+    keys = np.arange(1, 500, dtype=np.uint64)   # all _write_slot_file draws
 
     def run(prefetch):
+        StatRegistry.instance().reset()
         eng = BoxPSEngine(EmbeddingTableConfig(
             embedding_dim=4, shard_num=4,
             sgd=SparseSGDConfig(mf_create_thresholds=0.0)), seed=0)
         ds = fleet.BoxPSDataset(cfg, engine=eng, read_threads=1)
+        # whoever still reads a block after it was handed back sees 0xFF
+        ds.dataset._store = ScribblingStore()
         model = DeepFM(num_slots=4, emb_width=3 + 4, dense_dim=3,
                        hidden=(8,))
         tr = SparseTrainer(eng, model, cfg, batch_size=32, seed=0,
                            sparse_path="fast")
-        return fleet.train_passes(tr, ds, files, date="20260801",
-                                  prefetch=prefetch)
+        metrics = fleet.train_passes(tr, ds, passes, date="20260801",
+                                     prefetch=prefetch)
+        return metrics, eng, tr, stat_snapshot("data.read")
 
-    m_serial, m_pipe = run(False), run(True)
-    assert len(m_serial) == len(m_pipe) == 2
-    np.testing.assert_array_equal([m["loss"] for m in m_serial],
-                                  [m["loss"] for m in m_pipe])
-    np.testing.assert_array_equal([m["batches"] for m in m_serial],
-                                  [m["batches"] for m in m_pipe])
+    serial, pipe = run(False), run(True)
+    assert len(serial[0]) == len(pipe[0]) == n_passes
+    np.testing.assert_array_equal([m["batches"] for m in serial[0]],
+                                  [m["batches"] for m in pipe[0]])
+    _assert_runs_identical(([m["loss"] for m in serial[0]], *serial[1:3]),
+                           ([m["loss"] for m in pipe[0]], *pipe[1:3]), keys)
+    if slot_parser.available():
+        for stats in (serial[3], pipe[3]):
+            # the first read is fresh; the second is too if no slab of the
+            # first is large enough; every later one is a reuse
+            reused = stats.get("data.read.block_bytes_reused", 0)
+            fresh = stats["data.read.block_bytes_fresh"]
+            assert reused / (reused + fresh) >= (n_passes - 2) / n_passes
 
 
 def test_prefetch_failure_surfaces_at_next_pass():
