@@ -61,6 +61,9 @@ class HostPassArrays:
     batch_base: Optional[np.ndarray] = None   # [N] int64
     rank_offset: Optional[np.ndarray] = None  # [N*B, 1+2*max_rank] int32
     ads_offset: Optional[np.ndarray] = None   # [N, B+1] int32 pv offsets
+    # a tied head's keys as working-set rows, the same in every batch of
+    # the pass (stacked so that a batch slices it as it slices the rest)
+    head_rows: Optional[np.ndarray] = None    # [N, V] int32
     # InputTable-resolved aux index planes {name: [N*B, cap] int32}
     aux: Optional[Dict[str, np.ndarray]] = None
     uid: Optional[np.ndarray] = None    # [N*B] uint64 (uid_slot, HOST-side:
@@ -129,7 +132,8 @@ def pack_pass(blocks: Sequence[SlotRecordBlock], feed_config: DataFeedConfig,
               batch_counts: Optional[Sequence[int]] = None,
               pack_threads: Optional[int] = None,
               on_plane: Optional[Callable[[str, np.ndarray], None]] = None,
-              seq_key_slot: Optional[str] = None
+              seq_key_slot: Optional[str] = None,
+              head_keys: Optional[np.ndarray] = None
               ) -> HostPassArrays:
     """Vectorized whole-pass pack: one call per slot, one key translation
     for every occurrence in the pass (vs per-batch searchsorted loops).
@@ -157,6 +161,11 @@ def pack_pass(blocks: Sequence[SlotRecordBlock], feed_config: DataFeedConfig,
     ``seq_keys`` [N*B, slot capacity] int32 of the slot's RAW keys (a
     sequence model's next-token targets are vocabulary ids; ``indices``
     holds working-set rows, which change every pass).  Keys must fit int32.
+
+    head_keys: the keys whose rows are a model's tied head
+    (``model.head_keys()``) — adds the per-batch plane ``head_rows``
+    [N, V] int32, their working-set rows by ``key_mapper`` (0 for a key
+    the pass does not hold), the same row of the plane for every batch.
     """
     t_pack = time.perf_counter()
     m_pack = time.monotonic()
@@ -353,6 +362,15 @@ def pack_pass(blocks: Sequence[SlotRecordBlock], feed_config: DataFeedConfig,
             merged.search_ids, batch_real, batch_base, batch_size)
         if on_plane is not None:
             on_plane("ads_offset", out.ads_offset)
+    if head_keys is not None:
+        if key_mapper is None:
+            raise ValueError("head_keys need the pass's key_mapper: the "
+                             "plane holds working-set rows")
+        out.head_rows = np.ascontiguousarray(np.broadcast_to(
+            np.asarray(key_mapper(np.asarray(head_keys, np.uint64)),
+                       np.int32), (n_batches, len(head_keys))))
+        if on_plane is not None:
+            on_plane("head_rows", out.head_rows)
     if feed_config.rank_offset or feed_config.ads_offset:
         stat_observe("data.pass_feed.plane_build_s",
                      time.perf_counter() - t_planes)
@@ -415,8 +433,9 @@ def _relayout(d, N: int, B: int):
     }
     lbl = d["labels"]
     out["labels"] = lbl.reshape((N, B) + lbl.shape[1:])
-    if "ads_offset" in d:                   # per-BATCH plane [N, B+1]
-        out["ads_offset"] = d["ads_offset"]
+    for k in ("ads_offset", "head_rows"):   # per-BATCH planes [N, .]
+        if k in d:
+            out[k] = d[k]
     for k in d:   # extra per-record planes ([N*B, w] -> [N, B, w])
         if k not in out and k != "labels":
             out[k] = d[k].reshape(N, B, -1)
@@ -489,7 +508,7 @@ def _h2d_sharding(name: str, sharding):
         return NamedSharding(mesh, P(None, spec))
     if name in ("dense", "labels", "valid"):
         return NamedSharding(mesh, P(spec))
-    if name == "ads_offset":
+    if name in ("ads_offset", "head_rows"):
         return NamedSharding(mesh, P())
     return NamedSharding(mesh, P(spec, None))   # rank_offset / aux planes
 
@@ -560,6 +579,8 @@ def upload_pass(host_arrays: HostPassArrays, keep_host: bool = False,
         # tiny per-batch plane, replicated over the mesh (a plain
         # process-local array cannot mix with global arrays under jit)
         dev["ads_offset"] = put("ads_offset", h.ads_offset)
+    if h.head_rows is not None:
+        dev["head_rows"] = put("head_rows", h.head_rows)
     data = _relayout(dev, N, B)
     if sharding is not None:
         data = {k: jax.device_put(v, sharding[k]) if k in sharding else v

@@ -415,18 +415,53 @@ def occurrence_payload(d_occ: jnp.ndarray, ins_cvm: jnp.ndarray,
         axis=-1)
 
 
+def pull_head(ws: Dict[str, jnp.ndarray], head_rows: jnp.ndarray
+              ) -> jnp.ndarray:
+    """The rows of a tied head, [V, D]: what a pull of those keys returns
+    (``pull_rows``'s mf columns: the row times its created mask, so a row
+    the table has not created yet reads zero), once a step and not per
+    position.  ``head_rows`` [V]: their working-set rows (the feed's
+    ``head_rows`` plane)."""
+    created = jnp.take(ws["mf_size"], head_rows) > 0
+    return jnp.take(ws["mf"], head_rows, axis=0) \
+        * created[:, None].astype(ws["mf"].dtype)
+
+
+def merge_head_grad(acc: Dict[str, jnp.ndarray], head_rows: jnp.ndarray,
+                    d_head: jnp.ndarray) -> Dict[str, jnp.ndarray]:
+    """The merged per-row accumulators with a tied head's gradient
+    ``d_head`` [V, D] added to ``g_embedx`` at ``head_rows`` [V].  Nothing
+    else moves: g_show / g_click count occurrences only, so the rule
+    (``optimizer.push_touched``) still updates only the rows an
+    occurrence touched, and divides their merged gradient by their
+    shows.  The rows are distinct, so the add is a gather: a working-set
+    row reads the head's gradient at its own place in the head, or
+    nothing."""
+    n, v = acc["g_embedx"].shape[0], head_rows.shape[0]
+    place = jnp.full((n,), -1, jnp.int32).at[head_rows].set(
+        jnp.arange(v, dtype=jnp.int32))
+    add = jnp.take(d_head, jnp.maximum(place, 0), axis=0) \
+        * (place >= 0).astype(d_head.dtype)[:, None]
+    return {**acc, "g_embedx": acc["g_embedx"] + add}
+
+
 def push_and_update(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
                     idx_slb: jnp.ndarray, d_pooled: jnp.ndarray,
                     ins_cvm: jnp.ndarray, slot_ids: jnp.ndarray,
                     cfg: SparseSGDConfig,
                     interpret: bool = False,
                     crossing: str = "take",
-                    d_occ: jnp.ndarray = None) -> Dict[str, jnp.ndarray]:
+                    d_occ: jnp.ndarray = None,
+                    head=None) -> Dict[str, jnp.ndarray]:
     """Merged push + sparse optimizer.
 
     d_occ [S, L, B, 1+D], in place of d_pooled (then None): a gradient per
     occurrence (``occurrence_payload``), for rows that were pulled
     unpooled; it crosses by ``perm`` ("take" only), the rest is the same.
+
+    head: ``(head_rows [V], d_head [V, D])`` of a model whose head is the
+    table's rows — the gradient a row, merged with the occurrences' after
+    the scatter and before the rule (``merge_head_grad``).
 
     d_pooled [B, S, 3+D] — cols 0,1 are ignored and replaced by the
     instance cvm (reference push semantics, box_wrapper_impl.h:373);
@@ -541,4 +576,7 @@ def push_and_update(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
     if wp != w:
         delta = delta[:w]
     acc = acc_from_delta(delta, n, d_main=ws["mf"].shape[1])
+    if head is not None:
+        with jax.named_scope("seq.head_push"):
+            acc = merge_head_grad(acc, *head)
     return sparse_opt.apply_push(ws, acc, cfg)

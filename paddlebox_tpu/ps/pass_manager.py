@@ -70,6 +70,7 @@ class BoxPSEngine:
 
         self._agent_lock = lockdep.lock("ps.pass_manager.BoxPSEngine._agent_lock")
         self._agent_keys: List[np.ndarray] = []
+        self._kept_keys: List[np.ndarray] = []   # keep_keys: in every pass
         self._feeding = False
 
         self.mapper: Optional[embedding.PassKeyMapper] = None
@@ -142,7 +143,7 @@ class BoxPSEngine:
     def begin_feed_pass(self) -> None:
         assert not self._feeding, "previous feed pass not closed"
         with self._agent_lock:
-            self._agent_keys = []
+            self._agent_keys = list(self._kept_keys)
         # per-pass observability baseline: the end_pass report prints
         # DELTAS against these (wire bytes, faults, timer seconds of this
         # pass only).  Held PENDING until begin_pass promotes it — under
@@ -195,6 +196,18 @@ class BoxPSEngine:
         if len(keys):
             with self._agent_lock:
                 self._agent_keys.append(np.asarray(keys, np.uint64))
+
+    def keep_keys(self, keys: np.ndarray) -> None:
+        """Keys every feed pass holds from now on, whether its data has
+        them or not (a model's tied head reads the rows of its whole
+        vocabulary slice: ``SparseTrainer`` hands over the model's
+        ``head_keys()``).  They enter through the sink the readers use,
+        at ``begin_feed_pass``, and at once where a feed pass is open."""
+        keys = np.asarray(keys, np.uint64)
+        with self._agent_lock:
+            self._kept_keys.append(keys)
+        if self._feeding:
+            self.add_keys(keys)
 
     def _dedup_agent_keys(self) -> np.ndarray:
         with self.timers("dedup_keys"), trace.span("ps.engine.dedup_keys"):

@@ -39,7 +39,7 @@ from paddlebox_tpu.ps import embedding, optimizer as sparse_opt
 from paddlebox_tpu.ps.pass_manager import BoxPSEngine
 from paddlebox_tpu.utils import compile_cache, intervals, trace
 from paddlebox_tpu.utils.channel import Channel, ChannelClosed
-from paddlebox_tpu.utils.monitor import (stat_observe, stat_set,
+from paddlebox_tpu.utils.monitor import (stat_add, stat_observe, stat_set,
                                          stat_snapshot)
 from paddlebox_tpu.utils.timer import TimerRegistry
 from paddlebox_tpu import flags
@@ -96,6 +96,18 @@ class SparseTrainer:
             self._seq_key_slot = feed_config.sparse_slots[
                 model.seq_key_slot].name
             have.add("seq_keys")
+        # a row model whose head is the table's rows names the head's keys
+        # (models/sambay.py): the engine keeps them in every pass's working
+        # set, the feed carries their rows (head_rows), the step pulls them
+        # once and pushes their gradient (mxu_path.pull_head / push head=)
+        self._head_keys = None
+        if getattr(model, "head_keys", None) is not None:
+            if not self._row_model:
+                raise ValueError(
+                    "model.head_keys: only a model that owns its loss "
+                    "(row_inputs) can tie its head to the table's rows")
+            self._head_keys = np.asarray(model.head_keys(), np.uint64)
+            engine.keep_keys(self._head_keys)
         unknown = need - have
         if unknown:
             raise ValueError(
@@ -245,7 +257,8 @@ class SparseTrainer:
                                 or self.amp or self.wuauc is not None
                                 or self.trainer_config.dump_path):
             raise ValueError(
-                "a model that takes unpooled rows (row_inputs) trains on "
+                "a model that takes unpooled rows (row_inputs), one that "
+                "ties its head to the table's rows (head_keys) too, trains on "
                 "the single-device mxu path, without an expand embedding, "
                 "per-slot mf dims, amp, the async dense table, per-user AUC "
                 "or a prediction dump "
@@ -390,20 +403,30 @@ class SparseTrainer:
         in the step's outputs."""
         model = self.model
         dense_tx = self.dense_tx
+        tied = self._head_keys is not None
 
         def half(params, opt_state, auc_state, rows, lengths, valid,
-                 extras):
+                 extras, head=None):
             kw = {k: extras[k] for k in getattr(model, "extra_inputs", ())}
-            (loss, aux), (d_params, d_rows) = jax.value_and_grad(
-                lambda p, x: model.loss(p, x, lengths, valid, **kw),
-                argnums=(0, 1), has_aux=True)(params, rows)
+            if tied:
+                # the head is the pulled rows of the model's head keys: a
+                # third argument of the loss, and a third gradient
+                (loss, aux), (d_params, d_rows, d_head) = jax.value_and_grad(
+                    lambda p, x, e: model.loss(p, x, lengths, valid, head=e,
+                                               **kw),
+                    argnums=(0, 1, 2), has_aux=True)(params, rows, head)
+            else:
+                (loss, aux), (d_params, d_rows) = jax.value_and_grad(
+                    lambda p, x: model.loss(p, x, lengths, valid, **kw),
+                    argnums=(0, 1), has_aux=True)(params, rows)
             with jax.named_scope("dense.adam"):
                 updates, opt_state = dense_tx.update(d_params, opt_state,
                                                      params)
                 params = optax.apply_updates(params, updates)
             auc_state = accumulate_auc(auc_state, aux["auc_pred"],
                                        aux["auc_label"], aux["auc_mask"])
-            return params, opt_state, auc_state, loss, aux["stats"], d_rows
+            return (params, opt_state, auc_state, loss, aux["stats"], d_rows,
+                    d_head if tied else None)
 
         return half
 
@@ -448,6 +471,7 @@ class SparseTrainer:
 
             if self._row_model:
                 rows_half = self._rows_dense_half()
+                tied = self._head_keys is not None
 
                 def core(ws, params, opt_state, auc_state, idx_slb, lengths,
                          dense, labels, valid, plan, extras=None):
@@ -463,9 +487,21 @@ class SparseTrainer:
                                                interpret=interpret)
                         rows = jax.lax.stop_gradient(
                             jnp.transpose(v[..., 3:], (2, 0, 1, 3)))
-                    (params, opt_state, auc_state, loss, stats,
-                     d_rows) = rows_half(params, opt_state, auc_state, rows,
-                                         lengths.T, valid, extras)
+                    e = None
+                    if tied:
+                        head_rows = extras["head_rows"]
+                        with jax.named_scope("seq.head_pull"):
+                            e = jax.lax.stop_gradient(
+                                mxu_path.pull_head(ws, head_rows))
+                    (params, opt_state, auc_state, loss, stats, d_rows,
+                     d_head) = rows_half(params, opt_state, auc_state, rows,
+                                         lengths.T, valid, extras, e)
+                    if tied:
+                        # the rule adds what the push hands it (w += lr *
+                        # ratio * g, ps/optimizer.py), and a head that is
+                        # the table's rows cannot climb its own loss
+                        # gradient: rows and head are pushed downhill
+                        d_rows, d_head = -d_rows, -d_head
                     with jax.named_scope("seq.push"):
                         # the tower's own columns: embed_w gets no gradient,
                         # show/click the instance's counts as in every push
@@ -475,11 +511,23 @@ class SparseTrainer:
                              d_mf], axis=-1)
                         ins_cvm = jnp.stack([jnp.ones_like(labels), labels],
                                             axis=1)
-                        ws = mxu_path.push_and_update(
+                        new_ws = mxu_path.push_and_update(
                             ws, plan, dims, idx_slb, None, ins_cvm,
                             slot_ids, sgd_cfg, interpret=interpret,
-                            d_occ=d_occ)
-                    return ws, params, opt_state, auc_state, loss, stats
+                            d_occ=d_occ,
+                            head=(head_rows, d_head) if tied else None)
+                    if tied:
+                        # seq.head.rows / rows_applied, behind the model's
+                        # own stats: the head rows read, and those whose
+                        # gradient the rule applied (an occurrence touched
+                        # the row, its show grew, and it was created)
+                        applied = (jnp.take(new_ws["show"], head_rows)
+                                   > jnp.take(ws["show"], head_rows)) \
+                            & (jnp.take(ws["mf_size"], head_rows) > 0)
+                        stats = jnp.concatenate([stats, jnp.stack([
+                            jnp.float32(head_rows.shape[0]),
+                            jnp.sum(applied).astype(jnp.float32)])])
+                    return new_ws, params, opt_state, auc_state, loss, stats
                 return core
             half = self._pooled_dense_half()
 
@@ -740,7 +788,8 @@ class SparseTrainer:
                               key_mapper=(self.engine.mapper if mapper is None
                                           else mapper),
                               batch_counts=counts, on_plane=on_plane,
-                              seq_key_slot=self._seq_key_slot)
+                              seq_key_slot=self._seq_key_slot,
+                              head_keys=self._head_keys)
         return arrays
 
     def pass_shardings(self, arrays) -> Optional[dict]:
@@ -1042,9 +1091,12 @@ class SparseTrainer:
             per_step = np.asarray(jnp.stack(losses)) if losses \
                 else np.zeros((0,), np.float32)
             if row_stats:
-                self.model.record_stats(
-                    np.asarray(jnp.sum(jnp.stack(row_stats), axis=0)),
-                    n_batches)
+                total = np.asarray(jnp.sum(jnp.stack(row_stats), axis=0))
+                if self._head_keys is not None:
+                    stat_add("seq.head.rows", float(total[-2]))
+                    stat_add("seq.head.rows_applied", float(total[-1]))
+                    total = total[:-2]
+                self.model.record_stats(total, n_batches)
         out["loss"] = float(per_step.mean()) if losses else float("nan")
         out["losses"] = [float(x) for x in per_step]
         return out
