@@ -9,9 +9,10 @@ per-batch host work.
 
 TPU-first shape of the same idea:
 
-* HOST, once per pass (vectorized numpy over every record at once): ragged
-  slot values -> translated pass-row ids (ONE searchsorted over the pass key
-  array for all occurrences of all batches) -> padded [S, N*B, L] planes.
+* HOST, once per pass (vectorized numpy over a slot's records at once,
+  read from the parsed blocks where they lie): ragged slot values ->
+  translated pass-row ids (one lookup a slot x record range, never one a
+  batch) -> padded [S, N*B, L] planes kept from pass to pass (PlaneStore).
 * DEVICE, once per pass: one relayout jit to the step's [N, S, L, B] layout
   plus (for the mxu path) the per-batch sort plans (ops/sorted_spmm
   build_plan mapped over batches) — the TPU equivalent of the reference
@@ -27,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence
 
@@ -36,9 +38,10 @@ import jax.numpy as jnp
 
 from paddlebox_tpu.config import DataFeedConfig, SlotConfig
 from paddlebox_tpu.data.batch_pack import BatchPacker
-from paddlebox_tpu.data.slot_record import SlotRecordBlock
+from paddlebox_tpu.data.slot_record import (SlotRecordBlock, aligned_slab,
+                                            size_class)
 from paddlebox_tpu.utils import intervals, trace, workpool
-from paddlebox_tpu.utils.monitor import stat_observe
+from paddlebox_tpu.utils.monitor import stat_add, stat_observe
 
 
 @dataclasses.dataclass
@@ -68,6 +71,9 @@ class HostPassArrays:
     aux: Optional[Dict[str, np.ndarray]] = None
     uid: Optional[np.ndarray] = None    # [N*B] uint64 (uid_slot, HOST-side:
     #   uids never ship to device — wuauc accumulates on host)
+    # the PlaneStore buffers the planes above are views of: whoever hands
+    # them back (PlaneStore.give_back) says that nothing reads a plane any more
+    storage: Optional[List[np.ndarray]] = None
 
     def extra_planes(self) -> Dict[str, np.ndarray]:
         """Every optional per-record plane (rank_offset + aux index
@@ -88,19 +94,151 @@ class HostPassArrays:
         return lo, max(0, min(self.batch_size, self.num_real - lo)), lo
 
 
-def _record_ranges(n: int, threads: int) -> List[tuple]:
+class PlaneStore:
+    """Memory of a packed pass's host planes, kept from pass to pass (the
+    pack's counterpart of the reader's ``BlockStore``).  ``take`` answers
+    with an UNINITIALISED array of the asked shape, a view of the smallest
+    buffer handed back that can hold it, else of a new one (a size class
+    of ``slot_record.size_class``, so passes of nearly one size find each
+    other's buffers); the pack writes every byte of it.  ``give_back``
+    takes the buffers of a pass whose planes nobody reads any more; the
+    store keeps those of the last two such passes (one trains while the
+    next is packed) and lets go of older ones.  It also keeps each pack
+    thread's scratch (gathered values, end offsets, lengths), so a task
+    allocates nothing of its group's size but the translated rows.
+    Thread-safe."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._sets: List[List[np.ndarray]] = []     # oldest first, <= 2
+        self._scratch = threading.local()
+
+    @property
+    def free_bytes(self) -> int:
+        with self._lock:
+            return sum(buf.nbytes for bufs in self._sets for buf in bufs)
+
+    def take(self, shape, dtype, held: List[np.ndarray]) -> np.ndarray:
+        """An uninitialised ``shape`` / ``dtype`` array; its buffer is
+        appended to ``held``, the list to give back."""
+        dtype = np.dtype(dtype)
+        nbytes = int(np.prod(shape)) * dtype.itemsize
+        buf = None
+        with self._lock:
+            fit = min(((buf.nbytes, si, bi)
+                       for si, bufs in enumerate(self._sets)
+                       for bi, buf in enumerate(bufs)
+                       if buf.nbytes >= nbytes), default=None)
+            if fit is not None:
+                buf = self._sets[fit[1]].pop(fit[2])
+        if buf is None:
+            stat_add("data.pack.plane_bytes_fresh", nbytes)
+            buf = aligned_slab(size_class(nbytes))
+        else:
+            stat_add("data.pack.plane_bytes_reused", nbytes)
+        held.append(buf)
+        return buf[:nbytes].view(dtype).reshape(shape)
+
+    def give_back(self, buffers: Sequence[np.ndarray]) -> None:
+        if not buffers:
+            return
+        with self._lock:
+            self._sets = self._sets[-1:] + [list(buffers)]
+
+    def scratch(self, what: str, n: int, dtype) -> np.ndarray:
+        """``n`` uninitialised elements that stay the calling thread's
+        until its next call for the same ``what`` (and dtype)."""
+        dtype = np.dtype(dtype)
+        kept = self._scratch.__dict__
+        buf = kept.get((what, dtype))
+        if buf is None or len(buf) < n:
+            buf = kept[what, dtype] = np.empty(
+                size_class(n * dtype.itemsize) // dtype.itemsize, dtype)
+        return buf[:n]
+
+
+def _record_ranges(n: int, threads: int, slots: int) -> List[tuple]:
     """Split [0, n) into contiguous record ranges for the pack fan-out.
-    More chunks than threads (2×) smooths slot-length skew; a floor keeps
-    tiny passes from paying per-task overhead.  Pure partitioning —
-    workers write disjoint plane rows, so any split is bit-identical."""
+    The tasks are (slot x range): the slots alone feed the pool where
+    there are enough of them, and ranges make up about two tasks a thread
+    (slot-length skew) where there are not (a sequence model's one slot,
+    the few dense and label slots of the second wave);
+    a floor keeps tiny passes from paying per-task overhead.  Few large
+    tasks, because a task is a handful of calls that release the
+    interpreter lock (one gather, one translation, one landing) and the
+    Python between them does not overlap.  Pure partitioning — workers
+    write disjoint plane rows, so any split is bit-identical."""
     if n == 0:
         return []
     if threads <= 1:
         return [(0, n)]
-    chunks = min(threads * 2, max(1, n // 4096))
+    chunks = min(-(-threads * 2 // max(1, slots)), max(1, n // 4096))
     bounds = np.linspace(0, n, chunks + 1).astype(np.int64)
     return [(int(bounds[i]), int(bounds[i + 1]))
             for i in range(len(bounds) - 1) if bounds[i + 1] > bounds[i]]
+
+
+def _gather_ragged(segments, slot_of, store: PlaneStore):
+    """One slot's records over a group of block segments ``(block, lo,
+    hi)`` as ragged ``(values, lengths)``.  The values are a view of the
+    block where the group lies in one, else joined by ONE
+    ``np.concatenate`` (a copy a segment from Python costs several times
+    more once pack threads contend for the interpreter lock); values and
+    lengths lie in the calling thread's scratch, valid until its next
+    gather."""
+    vals, ends, first, before = [], [], [], []
+    n = 0
+    for block, lo, hi in segments:
+        values, offsets = slot_of(block)
+        vals.append(values[int(offsets[lo]):int(offsets[hi])])
+        ends.append(offsets[lo + 1:hi + 1])
+        first.append(n)                     # the segment's first record,
+        before.append(int(offsets[lo]))     # and the offset it starts at
+        n += hi - lo
+    if len(vals) == 1:
+        v = vals[0]
+    else:
+        v = store.scratch("values", sum(map(len, vals)), vals[0].dtype)
+        np.concatenate(vals, out=v)
+    # every record's end offset in its own block; a length is the
+    # difference of two, but at a segment's first record
+    end = np.concatenate(ends, out=store.scratch("ends", n, np.int64))
+    lens = store.scratch("lengths", n, np.int64)
+    np.subtract(end[1:], end[:-1], out=lens[1:])
+    lens[first] = end[first] - np.asarray(before, np.int64)
+    return v, lens
+
+
+def _land_ragged(plane: np.ndarray, rows, col: int, width: int, cap: int,
+                 values: np.ndarray, lens: np.ndarray):
+    """Write the ragged records ``(values, lens)`` into
+    ``plane[rows, col:col + width]``: a record's values from position 0,
+    clipped at ``cap``, zero beyond them.  ``rows`` is a slice or an index
+    array of the plane's rows, one a record.  Where every record of the
+    group holds the same number of values that is one strided copy;
+    otherwise a scatter over the values present, whose temporaries grow
+    with them and not with ``n x width``.  Returns (lengths clipped at
+    cap: a scalar on the strided landing, strided?)."""
+    n, k = len(lens), int(lens[0])      # a group holds a record at least
+    if int(lens.min()) == k == int(lens.max()):
+        kk = min(k, cap)
+        if kk == 1:     # one column: numpy walks an [n, 1] block row by row
+            plane[rows, col] = values[::k]
+        elif kk:
+            plane[rows, col:col + kk] = values.reshape(n, k)[:, :kk]
+        if kk < width:
+            plane[rows, col + kk:col + width] = 0
+        return kk, True
+    plane[rows, col:col + width] = 0
+    rec = np.repeat(np.arange(n), lens)
+    at = np.arange(len(values)) - np.repeat(np.cumsum(lens) - lens, lens)
+    if int(lens.max()) > cap:
+        keep = at < cap
+        rec, at, values = rec[keep], at[keep], values[keep]
+        lens = np.minimum(lens, cap)
+    row = rec + rows.start if isinstance(rows, slice) else rows[rec]
+    plane[row, col + at] = values
+    return lens, False
 
 
 def route_keys(block: SlotRecordBlock) -> np.ndarray:
@@ -133,10 +271,13 @@ def pack_pass(blocks: Sequence[SlotRecordBlock], feed_config: DataFeedConfig,
               pack_threads: Optional[int] = None,
               on_plane: Optional[Callable[[str, np.ndarray], None]] = None,
               seq_key_slot: Optional[str] = None,
-              head_keys: Optional[np.ndarray] = None
+              head_keys: Optional[np.ndarray] = None,
+              planes: Optional[PlaneStore] = None
               ) -> HostPassArrays:
-    """Vectorized whole-pass pack: one call per slot, one key translation
-    for every occurrence in the pass (vs per-batch searchsorted loops).
+    """Vectorized whole-pass pack, straight from the parsed blocks into
+    the planes: a block's records are read where they lie (no merged copy
+    of the pass) and land in the plane rows they own, one key translation
+    a (slot x record range).
 
     prebatched: each input block IS one batch (≤ batch_size records, e.g.
     pv-aligned cuts from dataset.batches) and lands at its own batch slot,
@@ -144,12 +285,12 @@ def pack_pass(blocks: Sequence[SlotRecordBlock], feed_config: DataFeedConfig,
     SlotPaddleBoxDataFeed.  batch_counts: same semantics but the cuts are
     given as per-batch record counts over the CONCATENATED block order
     (dataset.batch_bounds) — no per-batch block copies needed.  Otherwise
-    blocks are concatenated and sliced densely every batch_size records.
+    the blocks' records are sliced densely every batch_size records.
 
-    pack_threads: fan the per-slot/per-record-range pad+translate work
-    across the shared pack WorkPool (None = FLAGS_pass_pack_threads; an
-    explicit int uses a private pool of that size).  Every worker writes a
-    DISJOINT row range of the preallocated SoA planes, so the result is
+    pack_threads: fan the per-slot/per-record-range gather+translate+land
+    work across the shared pack WorkPool (None = FLAGS_pass_pack_threads;
+    an explicit int uses a private pool of that size).  Every worker
+    writes a DISJOINT row range of the SoA planes, so the result is
     bit-identical at any thread count (≙ the reference's per-device
     PackBatchTask threads, boxps_worker.cc:1259).
 
@@ -166,25 +307,36 @@ def pack_pass(blocks: Sequence[SlotRecordBlock], feed_config: DataFeedConfig,
     (``model.head_keys()``) — adds the per-batch plane ``head_rows``
     [N, V] int32, their working-set rows by ``key_mapper`` (0 for a key
     the pass does not hold), the same row of the plane for every batch.
+
+    planes: the store the planes are taken from (and whose buffers the
+    result names in ``storage``, for whoever may hand them back); None
+    packs into memory of its own.  A taken plane is uninitialised, so the
+    pack writes every byte: records' rows in the fan-out, padding rows
+    ahead of it.
     """
     t_pack = time.perf_counter()
     m_pack = time.monotonic()
     packer = BatchPacker(feed_config, batch_size, label_slot)
+    store = planes if planes is not None else PlaneStore()
     own_pool = None
     if pack_threads is None:
         pool = workpool.pack_pool()
     else:
         own_pool = pool = workpool.WorkPool(max(1, int(pack_threads)),
                                             kind="pack")
-    blocks = list(blocks)
-    merged = SlotRecordBlock.concat(blocks)
+    if prebatched and batch_counts is None:
+        batch_counts = [b.n for b in blocks]
+    blocks = [b for b in blocks if b.n > 0]
+    # record base of every block over the joined order, and the total
+    bases = np.concatenate([[0], np.cumsum([b.n for b in blocks])]
+                           ).astype(np.int64)
+    n = int(bases[-1])
+    head = blocks[0] if blocks else SlotRecordBlock(n=0)
     if batch_counts is not None:
         counts = [int(c) for c in batch_counts]
-        if sum(counts) != merged.n:
+        if sum(counts) != n:
             raise ValueError(
-                f"batch_counts sum {sum(counts)} != {merged.n} records")
-    elif prebatched:
-        counts = [b.n for b in blocks]
+                f"batch_counts sum {sum(counts)} != {n} records")
     else:
         counts = None
     if ((feed_config.rank_offset or feed_config.ads_offset)
@@ -211,120 +363,156 @@ def pack_pass(blocks: Sequence[SlotRecordBlock], feed_config: DataFeedConfig,
                                 np.int64)
         batch_base = np.concatenate([[0], np.cumsum(batch_real)[:-1]])
     else:
-        n_batches = max(1, -(-merged.n // batch_size))
-        pos = slice(0, merged.n)   # contiguous writes on the dense path
+        n_batches = max(1, -(-n // batch_size))
+        pos = slice(0, n)   # contiguous writes on the dense path
         batch_real = batch_base = None
-    n = merged.n
     nb = n_batches * batch_size
     S, L = len(packer.sparse_slots), packer.capacity
 
-    indices = np.zeros((S, nb, L), dtype=np.int32)
-    lengths = np.zeros((S, nb), dtype=np.int32)
+    storage: List[np.ndarray] = []
+    valid = store.take((nb,), bool, storage)
+    valid[:] = False
+    valid[pos] = True
+    # the rows no record owns: a taken plane holds an earlier pass there
+    pad = (np.flatnonzero(~valid) if isinstance(pos, np.ndarray)
+           else slice(n, nb))
+
+    def new_plane(shape, dtype, record_axis: int = 0) -> np.ndarray:
+        """A plane from the store, its padding rows zeroed; the records'
+        rows are the fan-out's to write."""
+        plane = store.take(shape, dtype, storage)
+        plane[(slice(None),) * record_axis + (pad,)] = 0
+        return plane
+
+    indices = new_plane((S, nb, L), np.int32, record_axis=1)
+    lengths = new_plane((S, nb), np.int32, record_axis=1)
 
     def rows_of(r0: int, r1: int):
         """Plane rows of record range [r0, r1) — a contiguous slice on the
         dense path, a fancy-index slice of the position map otherwise."""
         return pos[r0:r1] if isinstance(pos, np.ndarray) else slice(r0, r1)
 
-    def pack_sparse_range(si: int, slot, r0: int, r1: int) -> None:
-        values, offsets = merged.uint64_slots[slot.name]
-        v = values[offsets[r0]:offsets[r1]]
-        o = offsets[r0:r1 + 1] - offsets[r0]
-        if key_mapper is not None:
-            # translate the ragged values ONCE (real occurrences only),
-            # then pad the translated int32 plane
-            v = key_mapper(v)
-        elif len(v) and int(v.max()) > np.iinfo(np.int32).max:
-            raise ValueError(
+    def segments_of(r0: int, r1: int) -> List[tuple]:
+        """Record range [r0, r1) as (block, lo, hi) pieces of the blocks."""
+        b0 = int(np.searchsorted(bases, r0, side="right")) - 1
+        b1 = int(np.searchsorted(bases, r1, side="left"))
+        return [(blocks[bi], max(r0, int(bases[bi])) - int(bases[bi]),
+                 min(r1, int(bases[bi + 1])) - int(bases[bi]))
+                for bi in range(b0, b1)]
+
+    def land(plane, col, width, cap, slot_of, lengths=None, check=None,
+             translate=None):
+        """The task per record range that lands one slot's records in
+        ``plane[rows, col:col + width]`` (and their clipped lengths in
+        ``lengths[rows]``); it answers with (values clipped away,
+        strided?)."""
+        def task(rows, segments):
+            v, lens = _gather_ragged(segments, slot_of, store)
+            total = len(v)
+            if check is not None:
+                check(v)
+            if translate is not None:
+                v = translate(v)
+            kept, strided = _land_ragged(plane, rows, col, width, cap, v,
+                                         lens)
+            if lengths is not None:
+                lengths[rows] = kept
+            kept = int(kept.sum()) if isinstance(kept, np.ndarray) else \
+                kept * len(lens)
+            return total - kept, strided
+        return task
+
+    def fan_out(tasks) -> list:
+        """Every task over every record range (as many as these tasks
+        need to feed the pool); the tasks' answers."""
+        ranges = [(rows_of(r0, r1), segments_of(r0, r1))
+                  for r0, r1 in _record_ranges(n, pool.threads, len(tasks))]
+        return pool.map(lambda t: t[0](*t[1]),
+                        [(task, rng) for task in tasks for rng in ranges])
+
+    def fits_int32(what: str):
+        def check(v):
+            if len(v) and int(v.max()) > np.iinfo(np.int32).max:
+                raise ValueError(what)
+        return check
+
+    def sparse_task(si: int, slot):
+        # translate the ragged values ONCE (real occurrences only), then
+        # land the translated int32 rows; a record is clipped at ITS
+        # slot's capacity, so positions at or beyond it hold padding in
+        # every plane (the invariant ps/mxu_path.pull_pool_cvm relies on)
+        return land(
+            indices[si], 0, L, slot.capacity,
+            lambda b: b.uint64_slots[slot.name], lengths=lengths[si],
+            check=None if key_mapper is not None else fits_int32(
                 "pack_pass without a key_mapper stores raw feasigns in the "
                 "int32 index plane; keys exceed int32 — pass the engine's "
-                "PassKeyMapper (engine.mapper)")
-        # zero-filled beyond each record's length (clipped at the slot's
-        # own capacity), so padding already lands on the reserved zero
-        # row — no re-mask pass
-        padded, lens = packer.pad_sparse(slot, v, o)
-        rows = rows_of(r0, r1)
-        indices[si, rows] = padded
-        lengths[si, rows] = lens
+                "PassKeyMapper (engine.mapper)"),
+            translate=key_mapper)
 
     try:
         # wave 1 — the heavy planes: every (sparse slot × record range)
-        # pad/translate task runs concurrently, each writing a disjoint
-        # [si, rows] region of the preallocated planes (bit-identical at
-        # any thread count: no accumulation, no ordering)
-        ranges = _record_ranges(n, pool.threads)
-        pool.map(lambda t: pack_sparse_range(*t),
-                 [(si, slot, r0, r1)
-                  for si, slot in enumerate(packer.sparse_slots)
-                  for r0, r1 in ranges])
+        # task runs concurrently, each writing a disjoint [si, rows] region
+        # of the planes (bit-identical at any thread count: no
+        # accumulation, no ordering)
+        done = fan_out([sparse_task(si, slot)
+                        for si, slot in enumerate(packer.sparse_slots)])
+        clipped = sum(c for c, _ in done)
+        if clipped:
+            stat_add("data.pack.clipped_keys", float(clipped))
         if on_plane is not None:
             on_plane("indices", indices)
             on_plane("lengths", lengths)
 
-        # wave 2 — the light per-record planes, one task per plane column
-        # group (dense slots / label columns / uid / aux), overlapping the
-        # caller's H2D dispatch of wave 1 when on_plane is staged
-        dense = np.zeros((nb, packer.dense_dim), dtype=np.float32)
-        multi = np.zeros((nb, len(packer.label_slots)), np.float32)
-        valid = np.zeros((nb,), dtype=bool)
-        uid = np.zeros((nb,), np.uint64) if feed_config.uid_slot else None
-        aux = {} if feed_config.string_slots or seq_key_slot else None
-
-        def pack_dense(slot, col: int) -> None:
-            values, offsets = merged.float_slots[slot.name]
-            padded, _ = packer._pad_ragged(values, offsets, slot.dim)
-            dense[pos, col:col + slot.dim] = padded
-
-        def pack_label(t: int, name: str) -> None:
-            src = merged.float_slots if name in merged.float_slots else \
-                merged.uint64_slots
-            if name in src:
-                lv, lo = src[name]
-                lp, _ = packer._pad_ragged(lv, lo, 1)
-                multi[pos, t] = lp[:, 0].astype(np.float32)
-
-        def pack_uid() -> None:
-            vals, offs = merged.uint64_slots[feed_config.uid_slot]
-            uid[pos] = packer._pad_ragged(vals, offs, 1)[0][:, 0]
-
-        def pack_aux(slot) -> None:
-            # InputTable index planes (≙ InputTableDataFeed,
-            # data_feed.h:2224)
-            vals, offs = merged.aux_slots[slot.name]
-            padded, _ = packer._pad_ragged(vals, offs, slot.capacity)
-            plane = np.zeros((nb, slot.capacity), np.int32)
-            plane[pos] = padded.astype(np.int32)
-            aux[slot.name] = plane
-
-        def pack_seq_keys(slot) -> None:
-            with trace.span("data.feed.seq_keys"):
-                vals, offs = merged.uint64_slots[slot.name]
-                if len(vals) and int(vals.max()) > np.iinfo(np.int32).max:
-                    raise ValueError(
-                        f"seq_keys: keys of slot {slot.name!r} exceed int32")
-                padded, _ = packer._pad_ragged(vals, offs, slot.capacity)
-                plane = np.zeros((nb, slot.capacity), np.int32)
-                plane[pos] = padded.astype(np.int32)
-                aux["seq_keys"] = plane
-
-        tasks: List[Callable[[], None]] = []
+        # wave 2 — the light per-record planes (dense slots / label columns
+        # / uid / aux), overlapping the caller's H2D dispatch of wave 1
+        # when on_plane is staged
+        dense = new_plane((nb, packer.dense_dim), np.float32)
+        multi = new_plane((nb, len(packer.label_slots)), np.float32)
+        uid = aux = None
+        tasks = []
         col = 0
         for slot in packer.dense_slots:
-            tasks.append(functools.partial(pack_dense, slot, col))
+            tasks.append(land(dense, col, slot.dim, slot.dim,
+                              lambda b, k=slot.name: b.float_slots[k]))
             col += slot.dim
         for t, name in enumerate(packer.label_slots):
-            tasks.append(functools.partial(pack_label, t, name))
-        if uid is not None:
-            tasks.append(pack_uid)
-        if aux is not None:
+            if name in head.float_slots:
+                tasks.append(land(multi, t, 1, 1,
+                                  lambda b, k=name: b.float_slots[k]))
+            elif name in head.uint64_slots:
+                tasks.append(land(multi, t, 1, 1,
+                                  lambda b, k=name: b.uint64_slots[k]))
+            else:
+                multi[:, t] = 0
+        if feed_config.uid_slot:
+            uid = new_plane((nb, 1), np.uint64)
+            tasks.append(land(
+                uid, 0, 1, 1,
+                lambda b: b.uint64_slots[feed_config.uid_slot]))
+            uid = uid[:, 0]
+        if feed_config.string_slots or seq_key_slot:
+            aux = {}
+            # InputTable index planes (≙ InputTableDataFeed,
+            # data_feed.h:2224)
             for slot in feed_config.string_slots:
-                tasks.append(functools.partial(pack_aux, slot))
+                aux[slot.name] = new_plane((nb, slot.capacity), np.int32)
+                tasks.append(land(aux[slot.name], 0, slot.capacity,
+                                  slot.capacity,
+                                  lambda b, k=slot.name: b.aux_slots[k]))
             if seq_key_slot:
-                tasks.append(functools.partial(pack_seq_keys, next(
-                    s for s in packer.sparse_slots
-                    if s.name == seq_key_slot)))
-        pool.map(lambda fn: fn(), tasks)
-        valid[pos] = True
+                slot = next(s for s in packer.sparse_slots
+                            if s.name == seq_key_slot)
+                aux["seq_keys"] = new_plane((nb, slot.capacity), np.int32)
+                tasks.append(trace.span("data.feed.seq_keys")(land(
+                    aux["seq_keys"], 0, slot.capacity, slot.capacity,
+                    lambda b, k=slot.name: b.uint64_slots[k],
+                    check=fits_int32(f"seq_keys: keys of slot "
+                                     f"{slot.name!r} exceed int32"))))
+        landings = [s for _, s in done + fan_out(tasks)]
+        stat_add("data.pack.groups_strided", float(sum(landings)))
+        stat_add("data.pack.groups_scattered",
+                 float(len(landings) - sum(landings)))
     finally:
         if own_pool is not None:
             own_pool.shutdown()
@@ -337,21 +525,34 @@ def pack_pass(blocks: Sequence[SlotRecordBlock], feed_config: DataFeedConfig,
             for name, plane in aux.items():
                 on_plane(name, plane)
 
+    ins_ids = None
+    if head.ins_ids is not None:
+        ins_ids = [i for b in blocks for i in (b.ins_ids or [])]
     out = HostPassArrays(indices=indices, lengths=lengths, dense=dense,
                          labels=labels, valid=valid, n_batches=n_batches,
                          batch_size=batch_size, num_real=n,
-                         ins_ids=merged.ins_ids, batch_real=batch_real,
-                         batch_base=batch_base, aux=aux, uid=uid)
+                         ins_ids=ins_ids, batch_real=batch_real,
+                         batch_base=batch_base, aux=aux, uid=uid,
+                         storage=storage)
     # wave 3 — pv planes, vectorized over the WHOLE pass (the former
     # per-batch python loops; bit-identical, see rank_offset.py) and
-    # metered apart from pad/translate cost
+    # metered apart from the landing cost.  They read whole per-record
+    # columns, the only ones still joined.
     t_planes = time.perf_counter()
+
+    def column(name: str):
+        if getattr(head, name) is None:
+            return None
+        return np.concatenate([getattr(b, name) for b in blocks])
+
+    if feed_config.rank_offset or feed_config.ads_offset:
+        search_ids = column("search_ids")
     if feed_config.rank_offset:
         # ≙ GetRankOffset per batch (data_feed.cc:1855) — batch-local row
         # indices; meaningful under pv grouping (whole pvs per batch)
         from paddlebox_tpu.data.rank_offset import build_rank_offset_batched
         out.rank_offset = build_rank_offset_batched(
-            merged.search_ids, merged.cmatch, merged.rank,
+            search_ids, column("cmatch"), column("rank"),
             batch_real, batch_base, batch_size, feed_config.max_rank)
         if on_plane is not None:
             on_plane("rank_offset", out.rank_offset)
@@ -359,7 +560,7 @@ def pack_pass(blocks: Sequence[SlotRecordBlock], feed_config: DataFeedConfig,
         # ≙ GetAdsOffset per batch (data_feed.cc:3592): pv prefix offsets
         from paddlebox_tpu.data.rank_offset import build_ads_offset_batched
         out.ads_offset = build_ads_offset_batched(
-            merged.search_ids, batch_real, batch_base, batch_size)
+            search_ids, batch_real, batch_base, batch_size)
         if on_plane is not None:
             on_plane("ads_offset", out.ads_offset)
     if head_keys is not None:
@@ -406,6 +607,11 @@ class PackedPassFeed:
     uid: Optional[np.ndarray] = None        # [N*B] uint64 host-side uids
     host_labels: Optional[np.ndarray] = None  # [N*B(,T)] (uid_slot only)
     host_valid: Optional[np.ndarray] = None   # [N*B] bool (uid_slot only)
+    # the PlaneStore buffers of the host planes this feed was uploaded
+    # from, to hand back once a pass has trained on it (every transfer has
+    # then completed); None where the feed keeps reading host planes
+    # (``host``, ``uid``), which are then its own for good
+    storage: Optional[List[np.ndarray]] = None
 
     def device_bytes(self) -> int:
         tot = sum(int(np.prod(a.shape)) * a.dtype.itemsize
@@ -586,11 +792,13 @@ def upload_pass(host_arrays: HostPassArrays, keep_host: bool = False,
         data = {k: jax.device_put(v, sharding[k]) if k in sharding else v
                 for k, v in data.items()}
     intervals.record("upload", m_up, time.monotonic())
+    owns = keep_host or h.uid is not None
     return PackedPassFeed(data=data, n_batches=N, batch_size=B,
                           num_real=h.num_real,
                           host=h if keep_host else None, uid=h.uid,
                           host_labels=h.labels if h.uid is not None else None,
-                          host_valid=h.valid if h.uid is not None else None)
+                          host_valid=h.valid if h.uid is not None else None,
+                          storage=None if owns else h.storage)
 
 
 def precompute_plans(feed: PackedPassFeed, dims, eff=None,

@@ -83,6 +83,13 @@ def size_class(nbytes: int) -> int:
     return -(-nbytes // step) * step
 
 
+def aligned_slab(nbytes: int) -> np.ndarray:
+    """``nbytes`` uninitialised bytes that start on a page."""
+    raw = np.empty(nbytes + PAGE, np.uint8)
+    lo = -raw.ctypes.data % PAGE
+    return raw[lo:lo + nbytes]
+
+
 class BlockStore:
     """Storage of parsed blocks, recycled from pass to pass (≙ SlotObjPool,
     data_feed.h:305).  ``take`` answers with a page-aligned ``uint8`` slab of
@@ -116,9 +123,7 @@ class BlockStore:
             stat_add("data.read.block_bytes_reused", nbytes)
             return slab
         stat_add("data.read.block_bytes_fresh", nbytes)
-        raw = np.empty(want + PAGE, np.uint8)
-        lo = -raw.ctypes.data % PAGE
-        return raw[lo:lo + want]
+        return aligned_slab(want)
 
     def give_back(self, slabs: Iterable[np.ndarray]) -> None:
         with self._lock:

@@ -29,8 +29,8 @@ import optax
 from paddlebox_tpu.config import DataFeedConfig, TrainerConfig
 from paddlebox_tpu.data.batch_pack import BatchPacker, PackedBatch
 from paddlebox_tpu.data.dataset import SlotDataset
-from paddlebox_tpu.data.pass_feed import (PackedPassFeed, plan_tuple,
-                                          slice_batch)
+from paddlebox_tpu.data.pass_feed import (PackedPassFeed, PlaneStore,
+                                          plan_tuple, slice_batch)
 from paddlebox_tpu.metrics.auc import (AucCalculator, WuAucCalculator,
                                        accumulate_auc, make_auc_state)
 from paddlebox_tpu.ops.seqpool_cvm import fused_seqpool_cvm
@@ -57,6 +57,8 @@ class SparseTrainer:
         self.engine = engine
         self.model = model
         self.packer = BatchPacker(feed_config, batch_size, label_slot)
+        # the host planes of packed passes, handed back by _train_packed
+        self._plane_store = PlaneStore()
         self.batch_size = batch_size
         self.use_cvm = use_cvm
         self.topology = topology
@@ -789,7 +791,8 @@ class SparseTrainer:
                                           else mapper),
                               batch_counts=counts, on_plane=on_plane,
                               seq_key_slot=self._seq_key_slot,
-                              head_keys=self._head_keys)
+                              head_keys=self._head_keys,
+                              planes=self._plane_store)
         return arrays
 
     def pass_shardings(self, arrays) -> Optional[dict]:
@@ -1097,6 +1100,11 @@ class SparseTrainer:
                     stat_add("seq.head.rows_applied", float(total[-1]))
                     total = total[:-2]
                 self.model.record_stats(total, n_batches)
+        # the losses are read, so the pass has trained: every transfer
+        # from the feed's host planes completed and the relayout consumed
+        # them — the next pack may write them (ARCHITECTURE.md, planes)
+        self._plane_store.give_back(feed.storage)
+        feed.storage = None
         out["loss"] = float(per_step.mean()) if losses else float("nan")
         out["losses"] = [float(x) for x in per_step]
         return out

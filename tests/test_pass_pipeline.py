@@ -379,10 +379,13 @@ def test_fleet_train_passes_parity(n_passes, tmp_path):
     with the prefetcher on and off over real files: two seeded passes of
     other lengths in turn, each after the first parsed into the storage
     the last one's blocks held (the dataset's BlockStore; the prefetch
-    worker hands it back at its next load, after its own pack)."""
+    worker hands it back at its next load, after its own pack), and
+    packed into the planes an earlier pass's feed was uploaded from (the
+    trainer's PlaneStore; a pass hands them back when it has trained)."""
     from paddlebox_tpu import fleet
     from paddlebox_tpu.native import slot_parser
     from tests.test_data_pipeline import ScribblingStore
+    from tests.test_pass_feed import ScribblingPlaneStore
 
     cfg = _simple_cfg()
     files = []
@@ -405,9 +408,12 @@ def test_fleet_train_passes_parity(n_passes, tmp_path):
                        hidden=(8,))
         tr = SparseTrainer(eng, model, cfg, batch_size=32, seed=0,
                            sparse_path="fast")
+        # and whoever still reads a host plane after its pass has trained
+        tr._plane_store = ScribblingPlaneStore()
         metrics = fleet.train_passes(tr, ds, passes, date="20260801",
                                      prefetch=prefetch)
-        return metrics, eng, tr, stat_snapshot("data.read")
+        return (metrics, eng, tr, stat_snapshot("data.read"),
+                stat_snapshot("data.pack"))
 
     serial, pipe = run(False), run(True)
     assert len(serial[0]) == len(pipe[0]) == n_passes
@@ -422,6 +428,17 @@ def test_fleet_train_passes_parity(n_passes, tmp_path):
             reused = stats.get("data.read.block_bytes_reused", 0)
             fresh = stats["data.read.block_bytes_fresh"]
             assert reused / (reused + fresh) >= (n_passes - 2) / n_passes
+    # the two passes are of other shapes (two batches of 32, three): at
+    # most the first two packs allocate (a buffer is a page at least, so
+    # the second may fit some of the first's), every later one finds its
+    # planes kept
+    per_pass = [nb * (4 * CAP * 4 + 4 * 4 + 3 * 4 + 4 + 1)
+                for nb in ([64, 96] * n_passes)[:n_passes]]
+    for stats in (serial[4], pipe[4]):
+        fresh = stats["data.pack.plane_bytes_fresh"]
+        reused = stats.get("data.pack.plane_bytes_reused", 0)
+        assert fresh + reused == sum(per_pass)
+        assert per_pass[0] <= fresh <= sum(per_pass[:2])
 
 
 def test_prefetch_failure_surfaces_at_next_pass():
