@@ -211,17 +211,14 @@ def make_trainer_class():
             rec["step_compile_count"] = compile_count() - compiles0
             rec.update(self.compile_log.since(mark))
             if len(self.passes) == 1 and rec["path"].startswith("mxu"):
-                rec["mosaic_kernels"] = self._mosaic_kernels(feed)
+                rec["mosaic_kernels"] = self._mosaic_kernels()
             return stats
 
-        def _mosaic_kernels(self, feed):
+        def _mosaic_kernels(self):
             """Names of the Mosaic custom calls in the step this pass ran
             (a kernel that gave way to the XLA gather/scatter or to
             interpret mode leaves none)."""
-            text = self._packed_step_fn.lower(
-                self.engine.ws, self.params, self.opt_state,
-                self.auc_state, np.int32(0), feed.data,
-                feed.plans or {}).as_text()
+            text = self.step_lowered().as_text()
             found = [k for k in (sorted_spmm.GATHER_KERNEL,
                                  sorted_spmm.SCATTER_KERNEL)
                      if "@tpu_custom_call" in text

@@ -82,6 +82,7 @@ import jax.numpy as jnp
 from paddlebox_tpu.models import rowlm
 from paddlebox_tpu.models.rowlm import rms_norm
 from paddlebox_tpu.parallel import moe
+from paddlebox_tpu.utils import trace
 from paddlebox_tpu.utils.monitor import stat_add
 
 _NEG = -1e30          # finite "minus infinity": a masked row stays finite
@@ -434,13 +435,13 @@ class HybridLM:
         the layer's output and its counts (``moe.routed_experts``)."""
         b, n, h = x.shape
         flat = x.reshape(b * n, h)
-        with jax.named_scope("tower.moe"):
+        with trace.device_scope("tower.moe"):
             out, counts = moe.routed_experts(
                 flat, live.reshape(-1), w["router"],
                 jax.lax.stop_gradient(w["router_bias"]),
                 (w["wg"], w["wu"], w["wd"]), self.experts_held, self.top_k,
                 self.routed_scale)
-            with jax.named_scope("shared_expert"):
+            with trace.device_scope("shared_expert"):
                 out = out + swiglu(flat, w["sg"], w["su"], w["sd"])
         return out.reshape(b, n, h), counts
 
@@ -451,7 +452,7 @@ class HybridLM:
         chunked copies are a few dozen [tokens, heads x d] arrays."""
         def one(h, lengths):
             a = rms_norm(h, w["g1"], self.eps)
-            with jax.named_scope("tower." + mixer):
+            with trace.device_scope("tower." + mixer):
                 return h + (self.kda(w["mixer"], a) if mixer == "kda"
                             else self.mla(w["mixer"], a, lengths))
 
@@ -469,7 +470,7 @@ class HybridLM:
         None)."""
         x = rms_norm(h, w["g2"], self.eps)
         if ffn == "dense":
-            with jax.named_scope("tower.ffn_dense"):
+            with trace.device_scope("tower.ffn_dense"):
                 return h + swiglu(x, w["ffn"]["wg"], w["ffn"]["wu"],
                                   w["ffn"]["wd"]), None
         live = jnp.arange(h.shape[1])[None, :] < lengths[:, None]
@@ -507,7 +508,7 @@ class HybridLM:
         ce, lp_pos, lp_neg = self.head_terms(
             params, h.reshape(b * n, hd), targets.reshape(-1),
             negatives.reshape(-1))
-        with jax.named_scope("tower.head_loss"):
+        with trace.device_scope("tower.head_loss"):
             wt = has_target.astype(jnp.float32)
             count = jnp.sum(wt)
             loss = jnp.sum(ce * wt) / jnp.maximum(count, 1.0)

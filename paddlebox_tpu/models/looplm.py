@@ -63,6 +63,7 @@ import jax.numpy as jnp
 
 from paddlebox_tpu.models import rowlm
 from paddlebox_tpu.models.rowlm import rms_norm, sampled_negatives  # noqa: F401
+from paddlebox_tpu.utils import trace
 from paddlebox_tpu.utils.monitor import stat_add, stat_set
 
 STATS = ("targets", "exit_expected_step_sum", "tokens_valid",
@@ -230,7 +231,7 @@ class LoopLM:
                     gate + jnp.where(at, g[None], 0.0), lp), None
 
         zeros = jnp.zeros((ns, m), jnp.float32)
-        with jax.named_scope("tower.ut"):
+        with trace.device_scope("tower.ut"):
             (_, ce, gate, lp), _ = jax.lax.scan(
                 application,
                 (x, zeros, zeros, jnp.zeros((2, m), jnp.float32)),
@@ -252,7 +253,7 @@ class LoopLM:
             seq_keys, ln, valid, n, self.key_base, self.vocab, self.neg_seed)
         ce, gate, (lp_pos, lp_neg) = self.tower_terms(
             params, x, ln, targets.reshape(-1), negatives.reshape(-1))
-        with jax.named_scope("tower.head_loss"):
+        with trace.device_scope("tower.head_loss"):
             log_p = self.exit_log_probs(gate)
             p = jnp.exp(log_p)
             per = jnp.sum(p * ce, axis=0) + self.beta * jnp.sum(

@@ -13,6 +13,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from paddlebox_tpu.utils import trace
 from paddlebox_tpu.utils.monitor import stat_add
 
 
@@ -84,7 +85,7 @@ def map_token_blocks(block, blk: int, h, targets, negatives):
         h = jnp.pad(h, ((0, pad), (0, 0)))
         targets = jnp.pad(targets, (0, pad))
         negatives = jnp.pad(negatives, (0, pad))
-    with jax.named_scope("tower.head_loss"):
+    with trace.device_scope("tower.head_loss"):
         out = jax.lax.map(block, (h.reshape(-1, blk, hd),
                                   targets.reshape(-1, blk),
                                   negatives.reshape(-1, blk)))

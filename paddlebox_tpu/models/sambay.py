@@ -94,6 +94,7 @@ import numpy as np
 from paddlebox_tpu.models import rowlm
 from paddlebox_tpu.models.hybridlm import causal_conv, swiglu
 from paddlebox_tpu.models.rowlm import rms_norm
+from paddlebox_tpu.utils import trace
 
 _NEG = -1e30          # finite "minus infinity": a masked row stays finite
 MAMBA_CHUNK = 8       # tokens a chunk of the selective scan (unrolled)
@@ -405,7 +406,7 @@ class SambaYLM:
         def segment(carry, hs):
             tail, state = carry
             a = layer_norm(hs, w["ln1_g"], w["ln1_b"], self.eps)
-            with jax.named_scope("tower.mamba"):
+            with trace.device_scope("tower.mamba"):
                 xz = a @ mx["w_in"]
                 pre = jnp.concatenate([tail, xz[:, :di]])
                 x = jax.nn.silu(causal_conv(pre[None], mx["conv"])[0, ck - 1:]
@@ -473,7 +474,7 @@ class SambaYLM:
             def one(args):
                 h, length, shared = args
                 a = layer_norm(h, w["ln1_g"], w["ln1_b"], self.eps)
-                with jax.named_scope("tower." + kind):
+                with trace.device_scope("tower." + kind):
                     if kind == "gmu":
                         out, handed = (shared * jax.nn.silu(
                             a @ w["mixer"]["w1"])) @ w["mixer"]["w2"], None
@@ -497,7 +498,7 @@ class SambaYLM:
         @jax.checkpoint
         def block(hb):
             x = layer_norm(hb, w["ln2_g"], w["ln2_b"], self.eps)
-            with jax.named_scope("tower.mlp"):
+            with trace.device_scope("tower.mlp"):
                 return hb + swiglu(x, w["mlp"]["wg"], w["mlp"]["wu"],
                                    w["mlp"]["wd"])
 
@@ -535,7 +536,7 @@ class SambaYLM:
         ce, lp_pos, lp_neg = self.head_terms(
             head, h.reshape(b * n, hd), targets.reshape(-1),
             negatives.reshape(-1))
-        with jax.named_scope("tower.head_loss"):
+        with trace.device_scope("tower.head_loss"):
             wt = has_target.astype(jnp.float32)
             count = jnp.sum(wt)
             loss = jnp.sum(ce * wt) / jnp.maximum(count, 1.0)

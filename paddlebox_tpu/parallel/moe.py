@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from paddlebox_tpu.utils import trace
+
 
 # -- gates (≙ models/moe/gate/{naive,switch,gshard}_gate.py) ---------------
 
@@ -200,7 +202,7 @@ def _block(plan, i, size: int, top_k: int):
     rows hold one (an expert's last block is filled up with rows that
     hold none).  ``plan`` = (order, load, first_block)."""
     order, load, first_block = plan
-    with jax.named_scope("dispatch"):
+    with trace.device_scope("dispatch"):
         e = jnp.sum(first_block <= i) - 1            # the block's expert
         inside = (i - first_block[e]) * size + jnp.arange(size)
         keep = inside < load[e]
@@ -211,13 +213,13 @@ def _block(plan, i, size: int, top_k: int):
 
 def _expert_rows(xs, wt, wg, wu, wd):
     """One expert's SwiGLU on its rows, times their routing weights."""
-    with jax.named_scope("experts"):
+    with trace.device_scope("experts"):
         return ((jax.nn.silu(xs @ wg) * (xs @ wu)) @ wd) * wt[:, None]
 
 
 def _block_inputs(x, weights, experts, plan, i, size, top_k):
     e, rows, token, keep = _block(plan, i, size, top_k)
-    with jax.named_scope("dispatch"):
+    with trace.device_scope("dispatch"):
         xs = jnp.where(keep[:, None], x[token], 0.0)
         wt = jnp.where(keep, weights[rows], 0.0)
         w_e = tuple(lax.dynamic_index_in_dim(w, e, keepdims=False)
@@ -240,7 +242,7 @@ def _expert_blocks(x, weights, wg, wu, wd, plan, size: int, top_k: int):
         _, _, token, keep, xs, wt, w_e = _block_inputs(
             x, weights, (wg, wu, wd), plan, i, size, top_k)
         ys = _expert_rows(xs, wt, *w_e)
-        with jax.named_scope("combine"):
+        with trace.device_scope("combine"):
             out = out.at[token].add(jnp.where(keep[:, None], ys, 0.0))
         return out, taken + jnp.sum(keep).astype(jnp.float32)
 
@@ -262,7 +264,7 @@ def _expert_blocks_bwd(size, top_k, saved, g):
             x, weights, (wg, wu, wd), plan, i, size, top_k)
         _, vjp = jax.vjp(_expert_rows, xs, wt, *w_e)
         dxs, dwt_rows, *dw_e = vjp(jnp.where(keep[:, None], g[0][token], 0.0))
-        with jax.named_scope("combine"):
+        with trace.device_scope("combine"):
             dx = dx.at[token].add(jnp.where(keep[:, None], dxs, 0.0))
             dwt = dwt.at[rows].add(jnp.where(keep, dwt_rows, 0.0))
             dw = tuple(a.at[e].add(b) for a, b in zip(dw, dw_e))
@@ -306,9 +308,9 @@ def routed_experts(x, live, router, bias, experts, held, top_k: int,
     each expert received."""
     n_held = len(held)
     size = min(EXPERT_BLOCK, x.shape[0] * top_k)
-    with jax.named_scope("router"):
+    with trace.device_scope("router"):
         idx, w = route_top_k(x, router, bias, top_k, scale)
-    with jax.named_scope("dispatch"):
+    with trace.device_scope("dispatch"):
         # a held expert's place in ``experts``; n_held: it lies elsewhere
         local = jnp.full((router.shape[1],), n_held, jnp.int32).at[
             jnp.asarray(held)].set(jnp.arange(n_held, dtype=jnp.int32))

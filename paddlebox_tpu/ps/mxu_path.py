@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from paddlebox_tpu.config import SparseSGDConfig
 from paddlebox_tpu.ops import sorted_spmm as sp
 from paddlebox_tpu.ps import optimizer as sparse_opt
+from paddlebox_tpu.utils import trace
 
 
 def make_dims(num_occurrences: int, num_rows: int) -> sp.SpmmDims:
@@ -91,18 +92,19 @@ def _pull_table(ws: Dict[str, jnp.ndarray], dims: sp.SpmmDims) -> jnp.ndarray:
     n = ws["show"].shape[0]
     d = ws["mf"].shape[1]
     dx = _ex_dim(ws)
-    tab = jnp.zeros((sp.padded_width(3 + d + dx + 1), dims.n_kernel),
-                    jnp.float32)
-    tab = tab.at[0, :n].set(ws["show"])
-    tab = tab.at[1, :n].set(ws["click"])
-    tab = tab.at[2, :n].set(ws["embed_w"])
-    # pboxlint: disable-next=PB301 -- documented pull-table build cost (one relayout per step, not per-row math)
-    tab = tab.at[3:3 + d, :n].set(mf_values(ws, ws["mf"]).T)
-    if dx:
+    with trace.device_scope("ps.pull.table"):
+        tab = jnp.zeros((sp.padded_width(3 + d + dx + 1), dims.n_kernel),
+                        jnp.float32)
+        tab = tab.at[0, :n].set(ws["show"])
+        tab = tab.at[1, :n].set(ws["click"])
+        tab = tab.at[2, :n].set(ws["embed_w"])
         # pboxlint: disable-next=PB301 -- documented pull-table build cost (one relayout per step, not per-row math)
-        tab = tab.at[3 + d:3 + d + dx, :n].set(ws["mf_ex"].T)
-    # pboxlint: disable-next=PB301 -- documented pull-table build cost (one relayout per step, not per-row math)
-    tab = tab.at[3 + d + dx, :n].set(ws["mf_size"].astype(jnp.float32))
+        tab = tab.at[3:3 + d, :n].set(mf_values(ws, ws["mf"]).T)
+        if dx:
+            # pboxlint: disable-next=PB301 -- documented pull-table build cost (one relayout per step, not per-row math)
+            tab = tab.at[3 + d:3 + d + dx, :n].set(ws["mf_ex"].T)
+        # pboxlint: disable-next=PB301 -- documented pull-table build cost (one relayout per step, not per-row math)
+        tab = tab.at[3 + d + dx, :n].set(ws["mf_size"].astype(jnp.float32))
     return tab
 
 
@@ -116,21 +118,22 @@ def pool_cvm_values(v: jnp.ndarray, use_cvm: bool = True,
     to the mf columns (the mxu path does this in the SORTED domain so the
     mf_size column never rides the crossing)."""
     d = v.shape[-1] - (3 if premasked else 4)
-    mf = v[..., 3:3 + d]
-    if not premasked:
-        mf = mf * (v[..., 3 + d:] > 0).astype(v.dtype)     # [S,L,B,1] mask
-    show = jnp.sum(v[..., 0], axis=1)                      # [S, B]
-    click = jnp.sum(v[..., 1], axis=1)
-    w = jnp.sum(v[..., 2], axis=1)
-    mf = jnp.sum(mf, axis=1)                               # [S, B, D]
-    if use_cvm:
-        show_t = jnp.log(show + 1.0)
-        click_t = jnp.log(click + 1.0) - show_t
-    else:
-        show_t, click_t = show, click
-    head = jnp.stack([show_t, click_t, w], axis=-1)        # [S, B, 3]
-    pooled = jnp.concatenate([head, mf], axis=-1)
-    return jnp.transpose(pooled, (1, 0, 2))                # [B, S, E]
+    with trace.device_scope("ps.pull.pool"):
+        mf = v[..., 3:3 + d]
+        if not premasked:
+            mf = mf * (v[..., 3 + d:] > 0).astype(v.dtype)  # [S,L,B,1] mask
+        show = jnp.sum(v[..., 0], axis=1)                   # [S, B]
+        click = jnp.sum(v[..., 1], axis=1)
+        w = jnp.sum(v[..., 2], axis=1)
+        mf = jnp.sum(mf, axis=1)                            # [S, B, D]
+        if use_cvm:
+            show_t = jnp.log(show + 1.0)
+            click_t = jnp.log(click + 1.0) - show_t
+        else:
+            show_t, click_t = show, click
+        head = jnp.stack([show_t, click_t, w], axis=-1)     # [S, B, 3]
+        pooled = jnp.concatenate([head, mf], axis=-1)
+        return jnp.transpose(pooled, (1, 0, 2))             # [B, S, E]
 
 
 def push_payload(d_pooled: jnp.ndarray, ins_cvm: jnp.ndarray,
@@ -185,16 +188,17 @@ def _pull_sorted(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
     d = ws["mf"].shape[1] + _ex_dim(ws)
     rows2d, ch, tl, fg = plan[0], plan[3], plan[4], plan[5]
     tab = _pull_table(ws, dims)
-    g = sp.gather_sorted(tab, rows2d, ch, tl, fg,
-                         plan_eff_dims(plan, dims) or dims,
-                         interpret=interpret)              # [3+D+1, p_pad]
-    # created-mask the mf rows in the SORTED domain: the mf_size column is
-    # consumed here and never rides the crossing (w shrinks by 1, and the
-    # canonical-domain mask multiply disappears)
-    created = (g[3 + d:4 + d] > 0).astype(g.dtype)         # [1, p_pad]
-    g = jnp.concatenate([g[:3], g[3:3 + d] * created], axis=0)
-    if flags.get_flags("mxu_crossing_bf16"):
-        g = g.astype(jnp.bfloat16)
+    with trace.device_scope("ps.pull.gather"):
+        g = sp.gather_sorted(tab, rows2d, ch, tl, fg,
+                             plan_eff_dims(plan, dims) or dims,
+                             interpret=interpret)          # [3+D+1, p_pad]
+        # created-mask the mf rows in the SORTED domain: the mf_size column
+        # is consumed here and never rides the crossing (w shrinks by 1,
+        # and the canonical-domain mask multiply disappears)
+        created = (g[3 + d:4 + d] > 0).astype(g.dtype)     # [1, p_pad]
+        g = jnp.concatenate([g[:3], g[3:3 + d] * created], axis=0)
+        if flags.get_flags("mxu_crossing_bf16"):
+            g = g.astype(jnp.bfloat16)
     return g
 
 
@@ -311,17 +315,19 @@ def pull_rows(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
     eff = plan_eff_dims(plan, dims)
     g = _pull_sorted(ws, plan, dims, interpret)
     w = g.shape[0]
-    if crossing == "sort":
-        if eff is not None:
-            # dropped (row-0) positions re-enter as leading zero columns —
-            # exactly the value row 0 holds
-            p0 = dims.p_pad - eff.p_pad
-            g = jnp.concatenate([jnp.zeros((w, p0), g.dtype), g], axis=1)
-        v = cx.permute_by_dest(tuple(g[:, :dims.p]), perm).T  # [p, W]
-    else:
-        v = _take_canonical(g, inv_perm, dims, eff is not None,
-                            _lane_rows(g))
-    return v.reshape(s, l, b, w).astype(jnp.float32)
+    with trace.device_scope("ps.pull.cross"):
+        if crossing == "sort":
+            if eff is not None:
+                # dropped (row-0) positions re-enter as leading zero
+                # columns — exactly the value row 0 holds
+                p0 = dims.p_pad - eff.p_pad
+                g = jnp.concatenate([jnp.zeros((w, p0), g.dtype), g],
+                                    axis=1)
+            v = cx.permute_by_dest(tuple(g[:, :dims.p]), perm).T  # [p, W]
+        else:
+            v = _take_canonical(g, inv_perm, dims, eff is not None,
+                                _lane_rows(g))
+        return v.reshape(s, l, b, w).astype(jnp.float32)
 
 
 def capacity_groups(capacities: Optional[Sequence[int]], s: int,
@@ -382,20 +388,24 @@ def pull_pool_cvm(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
         v = pull_rows(ws, plan, dims, shape_slb, interpret, crossing)
         return pool_cvm_values(v, use_cvm, premasked=True)
     g = _pull_sorted(ws, plan, dims, interpret)
-    src = _lane_rows(g)         # once, for every capacity group
+    with trace.device_scope("ps.pull.cross"):
+        src = _lane_rows(g)     # once, for every capacity group
     trimmed = plan_eff_dims(plan, dims) is not None
     ip = plan[2].reshape(s, l, b)
     pieces = []                 # (first slot, pooled [B, run, 3 + D])
     for c, slots in groups:
         runs = list(_runs(slots))
-        ip_c = jnp.concatenate([ip[s0:s0 + n, :c] for _, s0, n in runs])
-        v = _take_canonical(g, ip_c.reshape(-1), dims, trimmed, src)
-        v = v.reshape(len(slots), c, b, -1).astype(jnp.float32)
+        with trace.device_scope("ps.pull.cross"):
+            ip_c = jnp.concatenate([ip[s0:s0 + n, :c] for _, s0, n in runs])
+            v = _take_canonical(g, ip_c.reshape(-1), dims, trimmed, src)
+            v = v.reshape(len(slots), c, b, -1).astype(jnp.float32)
         pooled = pool_cvm_values(v, use_cvm, premasked=True)
-        pieces += [(s0, pooled[:, j:j + n]) for j, s0, n in runs]
+        with trace.device_scope("ps.pull.pool"):
+            pieces += [(s0, pooled[:, j:j + n]) for j, s0, n in runs]
     # back into slot order: the runs' static slices, by first slot
-    return jnp.concatenate([x for _, x in sorted(pieces, key=lambda t: t[0])],
-                           axis=1)
+    with trace.device_scope("ps.pull.pool"):
+        return jnp.concatenate(
+            [x for _, x in sorted(pieces, key=lambda t: t[0])], axis=1)
 
 
 def occurrence_payload(d_occ: jnp.ndarray, ins_cvm: jnp.ndarray,
@@ -445,49 +455,21 @@ def merge_head_grad(acc: Dict[str, jnp.ndarray], head_rows: jnp.ndarray,
     return {**acc, "g_embedx": acc["g_embedx"] + add}
 
 
-def push_and_update(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
-                    idx_slb: jnp.ndarray, d_pooled: jnp.ndarray,
-                    ins_cvm: jnp.ndarray, slot_ids: jnp.ndarray,
-                    cfg: SparseSGDConfig,
-                    interpret: bool = False,
-                    crossing: str = "take",
-                    d_occ: jnp.ndarray = None,
-                    head=None) -> Dict[str, jnp.ndarray]:
-    """Merged push + sparse optimizer.
-
-    d_occ [S, L, B, 1+D], in place of d_pooled (then None): a gradient per
-    occurrence (``occurrence_payload``), for rows that were pulled
-    unpooled; it crosses by ``perm`` ("take" only), the rest is the same.
-
-    head: ``(head_rows [V], d_head [V, D])`` of a model whose head is the
-    table's rows — the gradient a row, merged with the occurrences' after
-    the scatter and before the rule (``merge_head_grad``).
-
-    d_pooled [B, S, 3+D] — cols 0,1 are ignored and replaced by the
-    instance cvm (reference push semantics, box_wrapper_impl.h:373);
-    ins_cvm [B, 2]; slot_ids [S].
-    crossing: canonical→sorted lowering (ops/crossing.py) — "take" gathers
-    by perm, "sort" re-sorts keyed by inv_perm (the destination index).
-
-    When the plan carries static sorted-domain planes (len > 8: bs,
-    labelcol, slotcol — pass_feed builds them at feed time), only the
-    DYNAMIC payload columns cross (g_embed + D×g_mf = 1+D channels):
-    g_show ≡ 1 rides as a constant, g_click and slot are feed-time planes
-    (the label and slot of an occurrence never change within a pass), and
-    the crossing gathers from the [B*S, 1+D] pooled-grad matrix instead of
-    a materialized [S, L, B, D+4] broadcast — the payload is constant over
-    L, so the broadcast carried 3x redundant rows through the crossing.
-    ≙ CopyForPush building the payload directly per key slot,
-    box_wrapper.cu:1168.
+def _push_sorted(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
+                 idx_slb: jnp.ndarray, d_pooled: jnp.ndarray,
+                 ins_cvm: jnp.ndarray, slot_ids: jnp.ndarray,
+                 crossing: str, d_occ: jnp.ndarray) -> jnp.ndarray:
+    """The canonical→sorted half of a push: the payload in the sorted
+    domain, [padded_width(D + 4), p_pad kept], one column a kept sorted
+    position (``push_and_update`` has the contract).
     """
     from paddlebox_tpu import flags
     from paddlebox_tpu.ops import crossing as cx
     assert crossing in ("take", "sort"), crossing
     s, l, b = idx_slb.shape
     d = ws["mf"].shape[1] + _ex_dim(ws)
-    n = ws["show"].shape[0]
     w = d + 4
-    rows2d, perm, inv_perm, ch, tl, fg, fs, first_occ = plan[:8]
+    perm, inv_perm, first_occ = plan[1], plan[2], plan[7]
     eff = plan_eff_dims(plan, dims)
     kd = eff or dims
     bf16 = bool(flags.get_flags("mxu_crossing_bf16"))
@@ -571,12 +553,59 @@ def push_and_update(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
     if wp != w:                     # whole kernel blocks of rows
         srt_cm = jnp.concatenate(
             [srt_cm, jnp.zeros((wp - w, kd.p_pad), jnp.float32)], axis=0)
-    delta = sp.scatter_add_sorted(srt_cm, rows2d, ch, tl, fs, kd,
-                                  interpret=interpret)     # [D+4, n_kernel]
-    if wp != w:
-        delta = delta[:w]
-    acc = acc_from_delta(delta, n, d_main=ws["mf"].shape[1])
-    if head is not None:
-        with jax.named_scope("seq.head_push"):
-            acc = merge_head_grad(acc, *head)
-    return sparse_opt.apply_push(ws, acc, cfg)
+    return srt_cm
+
+
+def push_and_update(ws: Dict[str, jnp.ndarray], plan, dims: sp.SpmmDims,
+                    idx_slb: jnp.ndarray, d_pooled: jnp.ndarray,
+                    ins_cvm: jnp.ndarray, slot_ids: jnp.ndarray,
+                    cfg: SparseSGDConfig,
+                    interpret: bool = False,
+                    crossing: str = "take",
+                    d_occ: jnp.ndarray = None,
+                    head=None) -> Dict[str, jnp.ndarray]:
+    """Merged push + sparse optimizer.
+
+    d_occ [S, L, B, 1+D], in place of d_pooled (then None): a gradient per
+    occurrence (``occurrence_payload``), for rows that were pulled
+    unpooled; it crosses by ``perm`` ("take" only), the rest is the same.
+
+    head: ``(head_rows [V], d_head [V, D])`` of a model whose head is the
+    table's rows — the gradient a row, merged with the occurrences' after
+    the scatter and before the rule (``merge_head_grad``).
+
+    d_pooled [B, S, 3+D] — cols 0,1 are ignored and replaced by the
+    instance cvm (reference push semantics, box_wrapper_impl.h:373);
+    ins_cvm [B, 2]; slot_ids [S].
+    crossing: canonical→sorted lowering (ops/crossing.py) — "take" gathers
+    by perm, "sort" re-sorts keyed by inv_perm (the destination index).
+
+    When the plan carries static sorted-domain planes (len > 8: bs,
+    labelcol, slotcol — pass_feed builds them at feed time), only the
+    DYNAMIC payload columns cross (g_embed + D×g_mf = 1+D channels):
+    g_show ≡ 1 rides as a constant, g_click and slot are feed-time planes
+    (the label and slot of an occurrence never change within a pass), and
+    the crossing gathers from the [B*S, 1+D] pooled-grad matrix instead of
+    a materialized [S, L, B, D+4] broadcast — the payload is constant over
+    L, so the broadcast carried 3x redundant rows through the crossing.
+    ≙ CopyForPush building the payload directly per key slot,
+    box_wrapper.cu:1168.
+    """
+    w = ws["mf"].shape[1] + _ex_dim(ws) + 4
+    rows2d, ch, tl, fs = plan[0], plan[3], plan[4], plan[6]
+    with trace.device_scope("ps.push.cross"):
+        srt_cm = _push_sorted(ws, plan, dims, idx_slb, d_pooled, ins_cvm,
+                              slot_ids, crossing, d_occ)
+    with trace.device_scope("ps.push.scatter"):
+        delta = sp.scatter_add_sorted(
+            srt_cm, rows2d, ch, tl, fs, plan_eff_dims(plan, dims) or dims,
+            interpret=interpret)                           # [D+4, n_kernel]
+        if delta.shape[0] != w:         # whole kernel blocks of rows
+            delta = delta[:w]
+    with trace.device_scope("ps.push.rule"):
+        acc = acc_from_delta(delta, ws["show"].shape[0],
+                             d_main=ws["mf"].shape[1])
+        if head is not None:
+            with trace.device_scope("seq.head_push"):
+                acc = merge_head_grad(acc, *head)
+        return sparse_opt.apply_push(ws, acc, cfg)

@@ -163,6 +163,7 @@ class SparseTrainer:
         self._step_fn = None
         self._packed_step_fn = None
         self._packed_sig = None
+        self._packed_step_args = None   # abstract, of its first dispatch
         # a dispatch during which JAX compiled (the first after a (re)build,
         # or a silent retrace on a new shape) is compile cost, not
         # steady-state dispatch: jit.compile_s has its seconds, and it stays
@@ -384,13 +385,16 @@ class SparseTrainer:
                 loss = jnp.sum(per * w) / jnp.maximum(jnp.sum(w), 1.0)
                 return loss, jax.nn.sigmoid(logits)
 
-            (loss, preds), (d_params, d_pooled) = jax.value_and_grad(
-                loss_fn, argnums=(0, 1), has_aux=True)(params, pooled)
+            with trace.device_scope("dense.tower"):
+                (loss, preds), (d_params, d_pooled) = jax.value_and_grad(
+                    loss_fn, argnums=(0, 1), has_aux=True)(params, pooled)
             if apply_dense:
-                updates, opt_state = dense_tx.update(d_params, opt_state,
-                                                     params)
-                params = optax.apply_updates(params, updates)
-            auc_state = accumulate_auc(auc_state, preds, labels, valid)
+                with trace.device_scope("dense.adam"):
+                    updates, opt_state = dense_tx.update(d_params, opt_state,
+                                                         params)
+                    params = optax.apply_updates(params, updates)
+            with trace.device_scope("metrics.auc"):
+                auc_state = accumulate_auc(auc_state, preds, labels, valid)
             return (params, opt_state, auc_state, loss, preds, d_pooled,
                     d_params)
 
@@ -421,12 +425,13 @@ class SparseTrainer:
                 (loss, aux), (d_params, d_rows) = jax.value_and_grad(
                     lambda p, x: model.loss(p, x, lengths, valid, **kw),
                     argnums=(0, 1), has_aux=True)(params, rows)
-            with jax.named_scope("dense.adam"):
+            with trace.device_scope("dense.adam"):
                 updates, opt_state = dense_tx.update(d_params, opt_state,
                                                      params)
                 params = optax.apply_updates(params, updates)
-            auc_state = accumulate_auc(auc_state, aux["auc_pred"],
-                                       aux["auc_label"], aux["auc_mask"])
+            with trace.device_scope("metrics.auc"):
+                auc_state = accumulate_auc(auc_state, aux["auc_pred"],
+                                           aux["auc_label"], aux["auc_mask"])
             return (params, opt_state, auc_state, loss, aux["stats"], d_rows,
                     d_head if tied else None)
 
@@ -484,7 +489,7 @@ class SparseTrainer:
                             "a model that takes unpooled rows trains from a "
                             "feed with precomputed plans (build_pass_feed)")
                     lane_gauge(ws, plan, dims, "take")
-                    with jax.named_scope("seq.pull"):
+                    with trace.device_scope("seq.pull"):
                         v = mxu_path.pull_rows(ws, plan, dims, (s, l, b),
                                                interpret=interpret)
                         rows = jax.lax.stop_gradient(
@@ -492,7 +497,7 @@ class SparseTrainer:
                     e = None
                     if tied:
                         head_rows = extras["head_rows"]
-                        with jax.named_scope("seq.head_pull"):
+                        with trace.device_scope("seq.head_pull"):
                             e = jax.lax.stop_gradient(
                                 mxu_path.pull_head(ws, head_rows))
                     (params, opt_state, auc_state, loss, stats, d_rows,
@@ -504,7 +509,7 @@ class SparseTrainer:
                         # the table's rows cannot climb its own loss
                         # gradient: rows and head are pushed downhill
                         d_rows, d_head = -d_rows, -d_head
-                    with jax.named_scope("seq.push"):
+                    with trace.device_scope("seq.push"):
                         # the tower's own columns: embed_w gets no gradient,
                         # show/click the instance's counts as in every push
                         d_mf = jnp.transpose(d_rows, (1, 2, 0, 3))
@@ -673,8 +678,10 @@ class SparseTrainer:
                               P(None, None, batch_axes, None)) + plan_specs,
                     out_specs=P(None, tbl_axes),
                     check_vma=False)(idx_slb, payload, *splan)  # [D+4, n_rows]
-                acc = mxu_path.acc_from_delta(delta, n_rows, d_main=d_main)
-                ws = sparse_opt.apply_push(ws, acc, sgd_cfg)
+                with trace.device_scope("ps.push.rule"):
+                    acc = mxu_path.acc_from_delta(delta, n_rows,
+                                                  d_main=d_main)
+                    ws = sparse_opt.apply_push(ws, acc, sgd_cfg)
                 out = (ws, params, opt_state, auc_state, loss, preds)
                 return out + ((d_params,) if async_dense else ())
             return core
@@ -954,8 +961,10 @@ class SparseTrainer:
         core = self._make_core(path, crossing)
 
         def step(ws, params, opt_state, auc_state, i, data, plans):
-            bt = slice_batch(data, i)
-            plan = plan_tuple(slice_batch(plans, i)) if with_plans else None
+            with trace.device_scope("feed.slice"):
+                bt = slice_batch(data, i)
+                plan = plan_tuple(slice_batch(plans, i)) \
+                    if with_plans else None
             extras = {k: bt[k] for k in bt
                       if k not in ("indices", "lengths", "dense", "labels",
                                    "valid")}
@@ -964,9 +973,28 @@ class SparseTrainer:
                         bt["valid"], plan, extras)
 
         self._packed_step_fn = jax.jit(step, donate_argnums=(0, 1, 2, 3))
+        self._packed_step_args = None
         # n_rows + feed geometry drive retrace via shapes, but the plan
         # presence/path/async/crossing flags are trace-structural — key them
         self._packed_sig = sig
+
+    def step_lowered(self):
+        """The packed step as it last ran, lowered again from the abstract
+        arguments of its first dispatch (shapes, dtypes, shardings: no
+        array is held): the one way the program and its tools print the
+        step.  ``.as_text()`` is the StableHLO with the Mosaic kernels'
+        names; ``.compile()`` answers from the compile cache and its
+        ``.as_text()`` holds the instructions a profiler trace names."""
+        if self._packed_step_args is None:
+            raise ValueError("no packed step has been dispatched yet "
+                             "(train_pass(feed) notes its arguments)")
+        return self._packed_step_fn.lower(*self._packed_step_args)
+
+    def step_scopes(self) -> Dict[str, str]:
+        """Instruction name -> ``op_name`` of the compiled packed step:
+        which ``trace.DEVICE_SCOPES`` each instruction lies under."""
+        return trace.instruction_scopes(
+            self.step_lowered().compile().as_text())
 
     def _train_packed(self, feed: PackedPassFeed,
                       progress=None) -> Dict[str, float]:
@@ -1009,6 +1037,15 @@ class SparseTrainer:
         ws, params = engine.ws, self.params
         opt_state, auc_state = self.opt_state, self.auc_state
         plans = feed.plans if feed.plans is not None else {}
+        if self._packed_step_args is None:      # once a build, not a step
+            # a committed array's sharding is part of the lowering, an
+            # uncommitted one's is not: step_lowered() must key the same
+            self._packed_step_args = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=a.sharding
+                    if getattr(a, "committed", False) else None),
+                (ws, params, opt_state, auc_state, np.int32(0), feed.data,
+                 plans))
         losses = []
         row_stats = []     # a row model's per-step stats (its "preds")
         n_batches = 0

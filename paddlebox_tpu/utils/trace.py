@@ -52,11 +52,13 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import re
 import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
 
+import jax
 from jax.profiler import TraceAnnotation
 
 from paddlebox_tpu import flags
@@ -283,3 +285,57 @@ def span(name: str, parent: Optional[str] = None, **attrs):
         stat_observe(name + "_s", time.perf_counter() - t0)
         if s is not None:
             tracer.finish(s)
+
+
+# -- the device side: named scopes and named programs ------------------------
+# A host span says what the host was doing; a device scope says what an
+# instruction of the compiled step is part of.  ``device_scope(name)`` is
+# the only way the program names a stretch of traced device work: the name
+# becomes a path element of every instruction's ``op_name`` under it (bare,
+# or inside a transform's brackets: ``jvp(dense.tower)``), which a profiler
+# trace carries in the program's ``Hlo Proto``, and costs nothing at run
+# time.  The list is closed, as the host spans' names are literals: a
+# reader (``benchmark/harness/step_scopes.py``) lays device time under
+# these names and reports the share that lies under none.
+DEVICE_SCOPES = (
+    # the step's shared path (trainer._build_packed_step, ps/mxu_path.py,
+    # trainer._pooled_dense_half / _rows_dense_half), in the order it runs
+    "feed.slice",
+    "ps.pull.table", "ps.pull.gather", "ps.pull.cross", "ps.pull.pool",
+    "dense.tower", "dense.adam", "metrics.auc",
+    "ps.push.cross", "ps.push.scatter", "ps.push.rule",
+    # a row model's step (trainer._make_core): they hold the ps.* scopes
+    "seq.pull", "seq.head_pull", "seq.push", "seq.head_push",
+    # the row models' towers (models/looplm.py, hybridlm.py, sambay.py,
+    # rowlm.py) and what parallel/moe.py names inside tower.moe
+    "tower.ut", "tower.head_loss",
+    "tower.kda", "tower.mla", "tower.moe", "tower.ffn_dense",
+    "tower.mamba", "tower.swa", "tower.attn_full", "tower.attn_cross",
+    "tower.gmu", "tower.mlp",
+    "router", "dispatch", "experts", "combine", "shared_expert",
+)
+
+# The feed's device programs (data/pass_feed.py: once a pass, outside the
+# step), by the name their runs carry on a trace's ``XLA Modules`` line.
+DEVICE_PROGRAMS = ("jit__relayout", "jit__build_plans",
+                   "jit__build_static_planes")
+
+
+def device_scope(name: str):
+    """``jax.named_scope(name)`` for a name of ``DEVICE_SCOPES`` (checked
+    while the step is traced, never while it runs)."""
+    if name not in DEVICE_SCOPES:
+        raise ValueError(
+            f"device scope {name!r} is not in utils/trace.DEVICE_SCOPES: "
+            "add it there, with the metric that reads it")
+    return jax.named_scope(name)
+
+
+_HLO_OP_NAME = re.compile(
+    r'^\s*(?:ROOT )?%?([\w.\-]+) = .*op_name="([^"]*)"', re.M)
+
+
+def instruction_scopes(hlo_text: str) -> Dict[str, str]:
+    """Instruction name -> ``op_name`` for every instruction of a compiled
+    program's text (``compiled.as_text()``) that carries one."""
+    return dict(_HLO_OP_NAME.findall(hlo_text))

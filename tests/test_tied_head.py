@@ -161,6 +161,15 @@ def two_passes(tmp_path_factory):
     return cfg, trainer, metrics, engine, counted
 
 
+@pytest.mark.parametrize("path", [
+    "seq.head_pull", "seq.push/ps.push.rule/seq.head_push"])
+def test_the_heads_pull_and_merge_are_scoped_in_the_step(two_passes, path):
+    """The head's merge lies inside the sparse rule's scope, so
+    ``step.sparse_rule_ms`` reads it with the rule."""
+    trainer = two_passes[1]
+    assert any(path + "/" in op for op in trainer.step_scopes().values())
+
+
 def test_fleet_path_keeps_every_head_key_in_the_working_set(two_passes):
     cfg, trainer, metrics, _, counted = two_passes
     assert trainer.sparse_path == "auto" and trainer._row_model
