@@ -72,6 +72,9 @@ class BoxPSEngine:
         self._agent_keys: List[np.ndarray] = []
         self._kept_keys: List[np.ndarray] = []   # keep_keys: in every pass
         self._feeding = False
+        # the pass's key dedup: native, scratch kept from pass to pass
+        # (False = not tried yet, None = np.unique serves)
+        self._key_dedup = False
 
         self.mapper: Optional[embedding.PassKeyMapper] = None
         self.ws: Optional[Dict[str, jnp.ndarray]] = None
@@ -209,11 +212,31 @@ class BoxPSEngine:
         if self._feeding:
             self.add_keys(keys)
 
+    def _native_dedup(self):
+        if self._key_dedup is False:
+            from paddlebox_tpu.native import build
+            dedup = None
+            try:
+                from paddlebox_tpu.native import hash_map
+                if hash_map.available():
+                    dedup = hash_map.KeyDedup()
+            except Exception as e:  # noqa: BLE001 — np.unique serves
+                build.warn_fallback("pass_key_dedup", e)
+            # pboxlint: disable-next=PB102 -- set once, by the one thread that runs the feed lifecycle
+            self._key_dedup = dedup
+        return self._key_dedup
+
     def _dedup_agent_keys(self) -> np.ndarray:
         with self.timers("dedup_keys"), trace.span("ps.engine.dedup_keys"):
             with self._agent_lock:
                 parts = self._agent_keys
                 self._agent_keys = []
+            n_in = float(sum(len(p) for p in parts))
+            stat_add("ps.engine.dedup_keys_in", n_in)
+            dedup = self._native_dedup()
+            if dedup is not None:
+                stat_add("ps.engine.dedup_keys_native", n_in)
+                return dedup(parts)
             allk = np.concatenate(parts) if parts else \
                 np.empty((0,), np.uint64)
             uniq = np.unique(allk)
