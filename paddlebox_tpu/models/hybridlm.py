@@ -12,7 +12,9 @@ and trained by the sparse rule, and the model owns its next-token loss
 (``row_inputs``; what the two share is ``rowlm.py``).
 
 The layers differ, so the tower is a Python loop over them, each half of
-a layer (mixer, feed-forward) under a ``jax.checkpoint``.  Equations (one
+a layer (mixer, feed-forward) under a ``jax.checkpoint`` (``RoutedLM``:
+the loop, the feed-forwards, head, loss and counters, which
+``afmoe.AfmoeLM`` shares).  Equations (one
 sequence of n tokens, x_i the row of token i, RMS as ``rowlm.rms_norm``):
 
     layer l:  h' = h + mixer_l(RMS(h; g1));  h'' = h' + ffn_l(RMS(h'; g2))
@@ -78,12 +80,13 @@ from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from paddlebox_tpu.models import rowlm
 from paddlebox_tpu.models.rowlm import rms_norm
 from paddlebox_tpu.parallel import moe
 from paddlebox_tpu.utils import trace
-from paddlebox_tpu.utils.monitor import stat_add
+from paddlebox_tpu.utils.monitor import stat_add, stat_set
 
 _NEG = -1e30          # finite "minus infinity": a masked row stays finite
 _HI = jax.lax.Precision.HIGHEST
@@ -93,9 +96,11 @@ KDA_BLOCK = 8         # chunks a block of the chunk-local algebra
 KDA_SEQS = 2          # sequences a group of a KDA mixer
 MLA_QBLOCK = 128      # queries a block of latent attention
 HEAD_BLOCK = 1024     # tokens a block of the head (logits [block, vocabulary])
+ROUTED_OUT = "tower.moe.held_out"   # the name ``keep_routed`` saves by
 STATS = ("targets", "tokens_valid", "tokens_padded",
          "moe_assignments_held", "moe_dropped_assignments")
-#   ... then tokens received, one a (routed layer, held expert)
+#   ... then tokens received, one a (routed layer, held expert); under a
+#   balancing bias then positions that chose, one a (routed layer, expert)
 
 
 def swiglu(x, wg, wu, wd):
@@ -295,36 +300,56 @@ def mla_attention(q_nope, q_rope, k_nope, k_rope, v, lengths):
     return jnp.moveaxis(out, 0, 1).reshape((b, n + pad) + out.shape[3:])[:, :n]
 
 
-class HybridLM:
+class RoutedLM:
+    """What a tower of layers that differ shares (``HybridLM`` here,
+    ``afmoe.AfmoeLM``): the layer loop, each half layer (mixer,
+    feed-forward) under a ``jax.checkpoint``; the dense and routed
+    feed-forwards; the head in token blocks, the loss and the counters.
+    A subclass brings its mixers: ``init_mixer(kind, w, ones, keys)``
+    their leaves, ``mixer(kind, w, a, lengths)`` their output on a [B,
+    n, H] and ``seqs_a_group(kind)`` how many sequences one checkpoint
+    of it takes (None: the whole batch).
+
+    Options a subclass may set: ``sandwich`` (a norm after each sublayer
+    as well as before it: h' = h + RMS(mixer(RMS(h; g1)); g1_post), the
+    same for the feed-forward), ``input_scale`` (the rows times a
+    constant before the first layer), ``balance_rate`` (the routing
+    bias is moved after every update by the counts the step routed:
+    ``after_update``; 0 holds it where it is) and ``keep_routed`` (the
+    held experts' part of a routed layer is kept from the forward for the
+    backward, by name past the feed-forward's checkpoint, instead of
+    being computed again: the expert blocks run once fewer a step, for
+    [B * n, H] float32 more memory a routed layer)."""
     row_inputs = True                 # takes unpooled rows, owns its loss
     extra_inputs = ("seq_keys",)
     seq_key_slot = 0                  # the sparse slot whose rows are the
                                       # sequence and fill the seq_keys plane
+    after_update = None               # (params, aux) -> params after Adam
 
     def __init__(self, hidden: int, layers: Sequence[Tuple[str, str]],
-                 vocab: int, *, kda_heads: int, kda_head_dim: int,
-                 conv_kernel: int, gate_rank: int, mla_heads: int,
-                 kv_rank: int, qk_nope: int, qk_rope: int, v_dim: int,
-                 ffn: int, experts: int, experts_held: Sequence[int],
-                 top_k: int, expert_ffn: int, shared_experts: int,
-                 routed_scale: float, eps: float = 1e-5,
+                 vocab: int, *, ffn: int, experts: int,
+                 experts_held: Sequence[int], top_k: int, expert_ffn: int,
+                 shared_experts: int, routed_scale: float, eps: float = 1e-5,
                  init_std: float = 0.02, key_base: int = 1,
-                 neg_seed: int = 0):
-        """``layers``: a (mixer, ffn) pair a layer, mixer ``kda`` | ``mla``,
-        ffn ``dense`` | ``moe``.  ``experts`` is the router's width,
-        ``experts_held`` the ids of the experts this chip holds."""
+                 neg_seed: int = 0, sandwich: bool = False,
+                 input_scale: float = 1.0, balance_rate: float = 0.0,
+                 keep_routed: bool = False):
+        """``layers``: a (mixer, ffn) pair a layer, ffn ``dense`` |
+        ``moe``.  ``experts`` is the router's width, ``experts_held`` the
+        ids of the experts this chip holds."""
         self.hidden, self.layers, self.vocab = hidden, tuple(layers), vocab
-        self.kda_heads, self.kda_head_dim = kda_heads, kda_head_dim
-        self.conv_kernel, self.gate_rank = conv_kernel, gate_rank
-        self.mla_heads, self.kv_rank = mla_heads, kv_rank
-        self.qk_nope, self.qk_rope, self.v_dim = qk_nope, qk_rope, v_dim
         self.ffn, self.experts = ffn, experts
         self.experts_held = tuple(int(e) for e in experts_held)
         self.top_k, self.expert_ffn = top_k, expert_ffn
         self.shared_experts, self.routed_scale = shared_experts, routed_scale
         self.eps, self.init_std = eps, init_std
         self.key_base, self.neg_seed = key_base, neg_seed
+        self.sandwich, self.input_scale = sandwich, input_scale
+        self.balance_rate = balance_rate
+        self.keep_routed = keep_routed
         self.moe_layers = sum(f == "moe" for _, f in self.layers)
+        if balance_rate and self.moe_layers:
+            self.after_update = self.balance_bias
 
     # -- parameters ---------------------------------------------------------
     def init(self, key):
@@ -342,37 +367,6 @@ class HybridLM:
         def ones(*shape):   # one buffer each: the step donates every leaf
             return jnp.ones(shape, jnp.float32)
 
-        def kda():
-            nh, d = self.kda_heads, self.kda_head_dim
-            a, r, ck = nh * d, self.gate_rank, self.conv_kernel
-
-            def conv():     # PyTorch's Conv1d default: U(+-1/sqrt(kernel))
-                lim = 1.0 / math.sqrt(ck)
-                return jax.random.uniform(next(keys), (ck, a), jnp.float32,
-                                          -lim, lim)
-
-            # A in U(1, 16), the step dt log-uniform in [1e-3, 1e-1] and
-            # dt_bias its inverse softplus
-            dt = jnp.exp(jax.random.uniform(
-                next(keys), (a,), jnp.float32, math.log(1e-3),
-                math.log(1e-1)))
-            return {"wq": w(h, a), "wk": w(h, a), "wv": w(h, a),
-                    "cq": conv(), "ck": conv(), "cv": conv(),
-                    "wf1": w(h, r), "wf2": w(r, a),
-                    "a_log": jnp.log(jax.random.uniform(
-                        next(keys), (nh,), jnp.float32, 1.0, 16.0)),
-                    "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-                    "wb": w(h, nh), "wg1": w(h, r), "wg2": w(r, a),
-                    "g_o": ones(d), "wo": w(a, h)}
-
-        def mla():
-            nh = self.mla_heads
-            return {"wq": w(h, nh * (self.qk_nope + self.qk_rope)),
-                    "wkva": w(h, self.kv_rank + self.qk_rope),
-                    "g_c": ones(self.kv_rank),
-                    "wkvb": w(self.kv_rank, nh * (self.qk_nope + self.v_dim)),
-                    "wo": w(nh * self.v_dim, h)}
-
         def dense():
             return {"wg": w(h, self.ffn), "wu": w(h, self.ffn),
                     "wd": w(self.ffn, h)}
@@ -385,16 +379,258 @@ class HybridLM:
                     "wg": w(e, h, f), "wu": w(e, h, f), "wd": w(e, f, h),
                     "sg": w(h, fs), "su": w(h, fs), "sd": w(fs, h)}
 
+        def layer(mixer, ffn):
+            out = {"g1": ones(h), "g2": ones(h)}
+            if self.sandwich:
+                out["g1_post"], out["g2_post"] = ones(h), ones(h)
+            out["mixer"] = self.init_mixer(mixer, w, ones, keys)
+            out["ffn"] = dense() if ffn == "dense" else routed()
+            return out
+
         return {
-            "layers": [{"g1": ones(h), "g2": ones(h),
-                        "mixer": kda() if mixer == "kda" else mla(),
-                        "ffn": dense() if ffn == "dense" else routed()}
-                       for mixer, ffn in self.layers],
+            "layers": [layer(mixer, ffn) for mixer, ffn in self.layers],
             "gf": ones(h),
             "head": w(h, self.vocab),
         }
 
     # -- the layers ---------------------------------------------------------
+    def routed(self, w, x, live):
+        """The routed feed-forward on x [B, n, H]; ``live`` [B, n] marks
+        the positions that are routed (inside their sequence).  Returns
+        the layer's output and its counts (``moe.routed_experts``)."""
+        b, n, h = x.shape
+        flat = x.reshape(b * n, h)
+        with trace.device_scope("tower.moe"):
+            out, counts = moe.routed_experts(
+                flat, live.reshape(-1), w["router"],
+                jax.lax.stop_gradient(w["router_bias"]),
+                (w["wg"], w["wu"], w["wd"]), self.experts_held, self.top_k,
+                self.routed_scale)
+            out = checkpoint_name(out, ROUTED_OUT)
+            with trace.device_scope("shared_expert"):
+                out = out + swiglu(flat, w["sg"], w["su"], w["sd"])
+        return out.reshape(b, n, h), counts
+
+    def mix(self, mixer, w, h, lengths):
+        """h + mixer(RMS(h; g1)) on h [B, n, H], ``seqs_a_group(mixer)``
+        sequences at a time, one group after another, each under its own
+        checkpoint."""
+        def one(h, lengths):
+            a = rms_norm(h, w["g1"], self.eps)
+            with trace.device_scope("tower." + mixer):
+                out = self.mixer(mixer, w["mixer"], a, lengths)
+                if self.sandwich:
+                    out = rms_norm(out, w["g1_post"], self.eps)
+                return h + out
+
+        b, g = h.shape[0], self.seqs_a_group(mixer)
+        if not g or b <= g or b % g:
+            return jax.checkpoint(one)(h, lengths)
+        out = jax.lax.map(
+            lambda args: jax.checkpoint(one)(*args),
+            (h.reshape((b // g, g) + h.shape[1:]),
+             lengths.reshape(b // g, g)))
+        return out.reshape(h.shape)
+
+    def feed_forward(self, ffn, w, h, lengths):
+        """h + ffn(RMS(h; g2)) -> (h, the routed layer's counts or
+        None)."""
+        x = rms_norm(h, w["g2"], self.eps)
+        if ffn == "dense":
+            with trace.device_scope("tower.ffn_dense"):
+                out = swiglu(x, w["ffn"]["wg"], w["ffn"]["wu"],
+                             w["ffn"]["wd"])
+                if self.sandwich:
+                    out = rms_norm(out, w["g2_post"], self.eps)
+                return h + out, None
+        live = jnp.arange(h.shape[1])[None, :] < lengths[:, None]
+        out, counts = self.routed(w["ffn"], x, live)
+        if self.sandwich:
+            out = rms_norm(out, w["g2_post"], self.eps)
+        return h + out, counts
+
+    def head_terms(self, params, h, targets, negatives):
+        """h [M, H] -> cross-entropy [M], log p of target and of negative
+        [M]; token blocks under a checkpoint, as ``looplm``'s."""
+        @jax.checkpoint
+        def block(args):
+            hb, yb, nb = args
+            lse, zy, zn = rowlm.head_logits(params["head"], hb, yb, nb)
+            return lse - zy, zy - lse, zn - lse
+
+        return rowlm.map_token_blocks(block, HEAD_BLOCK, h, targets,
+                                      negatives)
+
+    def loss(self, params, rows, lengths, valid, seq_keys):
+        x = rows[:, self.seq_key_slot]                        # [B, n, H]
+        if self.input_scale != 1.0:
+            x = x * self.input_scale
+        ln = jnp.where(valid, lengths[:, self.seq_key_slot], 0)
+        b, n, hd = x.shape
+        targets, has_target, negatives = rowlm.next_token_plan(
+            seq_keys, ln, valid, n, self.key_base, self.vocab, self.neg_seed)
+        h, counts = x, []
+        keep = jax.checkpoint_policies.save_only_these_names(ROUTED_OUT) \
+            if self.keep_routed else None
+        # a checkpoint a half layer: the backward recomputes a mixer or a
+        # feed-forward, never both at once
+        for (mixer, ffn), w in zip(self.layers, params["layers"]):
+            h = self.mix(mixer, w, h, ln)
+            h, c = jax.checkpoint(
+                lambda w, h, ffn=ffn: self.feed_forward(ffn, w, h, ln),
+                policy=keep)(w, h)
+            if c is not None:
+                counts.append(c)
+        h = rms_norm(h, params["gf"], self.eps)
+        ce, lp_pos, lp_neg = self.head_terms(
+            params, h.reshape(b * n, hd), targets.reshape(-1),
+            negatives.reshape(-1))
+        with trace.device_scope("tower.head_loss"):
+            wt = has_target.astype(jnp.float32)
+            count = jnp.sum(wt)
+            loss = jnp.sum(ce * wt) / jnp.maximum(count, 1.0)
+            tokens = rowlm.token_counts(ln, valid, n)
+            zero = jnp.zeros((), jnp.float32)
+            held = sum((c["held"] for c in counts), zero)
+            dropped = sum((c["dropped"] for c in counts), zero)
+            # where a balancing bias moves: the choices over all experts
+            # (behind the held experts' loads in the stats, and by routed
+            # layer for after_update) and the spread of the bias the step
+            # routed with
+            route, spread = [], []
+            if self.after_update is not None:
+                route = [c["route"] for c in counts]
+                bias = jnp.stack([w["ffn"]["router_bias"]
+                                  for w, (_, f) in zip(params["layers"],
+                                                       self.layers)
+                                  if f == "moe"])
+                spread = [(jnp.max(bias) - jnp.min(bias))[None]]
+            aux = {
+                **rowlm.auc_pairs(lp_pos, lp_neg, has_target, self.vocab),
+                "stats": jnp.concatenate(
+                    [jnp.stack([count, tokens, b * n - tokens, held,
+                                dropped])]
+                    + [c["load"] for c in counts] + route + spread),
+            }
+            if route:
+                aux["route"] = jnp.stack(route)
+        return loss, jax.lax.stop_gradient(aux)
+
+    def balance_bias(self, params, aux):
+        """The routing bias's own update, after Adam (the bias takes no
+        gradient): with c_e the dispatched positions that chose expert e
+        this step, over all experts, d_e = rate * sign(mean(c) - c_e) and
+        bias_e += d_e - mean(d), a routed layer each."""
+        with trace.device_scope("tower.moe_balance"):
+            layers, i = list(params["layers"]), 0
+            for l, (_, ffn) in enumerate(self.layers):
+                if ffn != "moe":
+                    continue
+                c = aux["route"][i]
+                i += 1
+                d = self.balance_rate * jnp.sign(jnp.mean(c) - c)
+                f = layers[l]["ffn"]
+                layers[l] = {**layers[l], "ffn": {
+                    **f, "router_bias": f["router_bias"] + d - jnp.mean(d)}}
+        return {**params, "layers": layers}
+
+    def record_stats(self, total, steps: int) -> None:
+        """Counters of a pass: ``total`` is ``stats`` summed over its
+        ``steps`` steps (the trainer reads it back once a pass)."""
+        _, valid, padded, held, dropped = (
+            float(v) for v in total[:len(STATS)])
+        rowlm.record_padding(valid, padded)
+        if not self.moe_layers:
+            return
+        n_load = len(self.experts_held) * self.moe_layers
+        load = [float(v) for v in total[len(STATS):len(STATS) + n_load]]
+        stat_add("tower.moe.assignments_held", held)
+        stat_add("tower.moe.assignments", valid * self.top_k
+                 * self.moe_layers)
+        stat_add("tower.moe.dropped_assignments", dropped)
+        # tokens an expert of this chip received over the pass, the
+        # busiest (layer, expert) and the mean
+        stat_add("tower.moe.expert_load_max", max(load))
+        stat_add("tower.moe.expert_load_mean", sum(load) / len(load))
+        if self.after_update is None:
+            return
+        # the positions that chose an expert over the pass, the busiest
+        # (layer, expert of all of them) and the mean; the bias's spread
+        # (max - min over the routed layers) as the pass's steps routed
+        # with it, their mean
+        route = [float(v) for v in total[len(STATS) + n_load:-1]]
+        stat_add("tower.moe.route_load_max", max(route))
+        stat_add("tower.moe.route_load_mean", sum(route) / len(route))
+        stat_set("tower.moe.bias_range", float(total[-1]) / max(steps, 1))
+
+
+class HybridLM(RoutedLM):
+    """Kimi Linear's tower (module docstring): KDA and latent-attention
+    mixers over ``RoutedLM``'s loop."""
+
+    def __init__(self, hidden: int, layers: Sequence[Tuple[str, str]],
+                 vocab: int, *, kda_heads: int, kda_head_dim: int,
+                 conv_kernel: int, gate_rank: int, mla_heads: int,
+                 kv_rank: int, qk_nope: int, qk_rope: int, v_dim: int,
+                 ffn: int, experts: int, experts_held: Sequence[int],
+                 top_k: int, expert_ffn: int, shared_experts: int,
+                 routed_scale: float, eps: float = 1e-5,
+                 init_std: float = 0.02, key_base: int = 1,
+                 neg_seed: int = 0):
+        """``layers``: a (mixer, ffn) pair a layer, mixer ``kda`` | ``mla``,
+        ffn ``dense`` | ``moe``.  ``experts`` is the router's width,
+        ``experts_held`` the ids of the experts this chip holds."""
+        super().__init__(
+            hidden, layers, vocab, ffn=ffn, experts=experts,
+            experts_held=experts_held, top_k=top_k, expert_ffn=expert_ffn,
+            shared_experts=shared_experts, routed_scale=routed_scale,
+            eps=eps, init_std=init_std, key_base=key_base,
+            neg_seed=neg_seed)
+        self.kda_heads, self.kda_head_dim = kda_heads, kda_head_dim
+        self.conv_kernel, self.gate_rank = conv_kernel, gate_rank
+        self.mla_heads, self.kv_rank = mla_heads, kv_rank
+        self.qk_nope, self.qk_rope, self.v_dim = qk_nope, qk_rope, v_dim
+
+    def init_mixer(self, kind, w, ones, keys):
+        h = self.hidden
+        if kind == "mla":
+            nh = self.mla_heads
+            return {"wq": w(h, nh * (self.qk_nope + self.qk_rope)),
+                    "wkva": w(h, self.kv_rank + self.qk_rope),
+                    "g_c": ones(self.kv_rank),
+                    "wkvb": w(self.kv_rank, nh * (self.qk_nope + self.v_dim)),
+                    "wo": w(nh * self.v_dim, h)}
+        nh, d = self.kda_heads, self.kda_head_dim
+        a, r, ck = nh * d, self.gate_rank, self.conv_kernel
+
+        def conv():     # PyTorch's Conv1d default: U(+-1/sqrt(kernel))
+            lim = 1.0 / math.sqrt(ck)
+            return jax.random.uniform(next(keys), (ck, a), jnp.float32,
+                                      -lim, lim)
+
+        # A in U(1, 16), the step dt log-uniform in [1e-3, 1e-1] and
+        # dt_bias its inverse softplus
+        dt = jnp.exp(jax.random.uniform(
+            next(keys), (a,), jnp.float32, math.log(1e-3),
+            math.log(1e-1)))
+        return {"wq": w(h, a), "wk": w(h, a), "wv": w(h, a),
+                "cq": conv(), "ck": conv(), "cv": conv(),
+                "wf1": w(h, r), "wf2": w(r, a),
+                "a_log": jnp.log(jax.random.uniform(
+                    next(keys), (nh,), jnp.float32, 1.0, 16.0)),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "wb": w(h, nh), "wg1": w(h, r), "wg2": w(r, a),
+                "g_o": ones(d), "wo": w(a, h)}
+
+    def seqs_a_group(self, kind):
+        """A KDA mixer takes ``KDA_SEQS`` sequences at a time: its q, k,
+        v, decays, gates and their chunked copies are a few dozen
+        [tokens, heads x d] arrays; latent attention the whole batch."""
+        return KDA_SEQS if kind == "kda" else None
+
+    def mixer(self, kind, w, a, lengths):
+        return self.kda(w, a) if kind == "kda" else self.mla(w, a, lengths)
+
     def kda(self, w, a):
         """KDA's mixer on a = RMS(h; g1) [B, n, H]."""
         b, n, _ = a.shape
@@ -428,117 +664,3 @@ class HybridLM:
         o = mla_attention(q[..., :dn], q[..., dn:], kv[..., :dn],
                           ckv[..., self.kv_rank:], kv[..., dn:], lengths)
         return o.reshape(b, n, nh * dv) @ w["wo"]
-
-    def routed(self, w, x, live):
-        """The routed feed-forward on x [B, n, H]; ``live`` [B, n] marks
-        the positions that are routed (inside their sequence).  Returns
-        the layer's output and its counts (``moe.routed_experts``)."""
-        b, n, h = x.shape
-        flat = x.reshape(b * n, h)
-        with trace.device_scope("tower.moe"):
-            out, counts = moe.routed_experts(
-                flat, live.reshape(-1), w["router"],
-                jax.lax.stop_gradient(w["router_bias"]),
-                (w["wg"], w["wu"], w["wd"]), self.experts_held, self.top_k,
-                self.routed_scale)
-            with trace.device_scope("shared_expert"):
-                out = out + swiglu(flat, w["sg"], w["su"], w["sd"])
-        return out.reshape(b, n, h), counts
-
-    def mix(self, mixer, w, h, lengths):
-        """h + mixer(RMS(h; g1)) on h [B, n, H].  A KDA mixer takes
-        ``KDA_SEQS`` sequences at a time, one group after another, each
-        under its own checkpoint: its q, k, v, decays, gates and their
-        chunked copies are a few dozen [tokens, heads x d] arrays."""
-        def one(h, lengths):
-            a = rms_norm(h, w["g1"], self.eps)
-            with trace.device_scope("tower." + mixer):
-                return h + (self.kda(w["mixer"], a) if mixer == "kda"
-                            else self.mla(w["mixer"], a, lengths))
-
-        b = h.shape[0]
-        if mixer != "kda" or b <= KDA_SEQS or b % KDA_SEQS:
-            return jax.checkpoint(one)(h, lengths)
-        out = jax.lax.map(
-            lambda args: jax.checkpoint(one)(*args),
-            (h.reshape((b // KDA_SEQS, KDA_SEQS) + h.shape[1:]),
-             lengths.reshape(b // KDA_SEQS, KDA_SEQS)))
-        return out.reshape(h.shape)
-
-    def feed_forward(self, ffn, w, h, lengths):
-        """h + ffn(RMS(h; g2)) -> (h, the routed layer's counts or
-        None)."""
-        x = rms_norm(h, w["g2"], self.eps)
-        if ffn == "dense":
-            with trace.device_scope("tower.ffn_dense"):
-                return h + swiglu(x, w["ffn"]["wg"], w["ffn"]["wu"],
-                                  w["ffn"]["wd"]), None
-        live = jnp.arange(h.shape[1])[None, :] < lengths[:, None]
-        out, counts = self.routed(w["ffn"], x, live)
-        return h + out, counts
-
-    def head_terms(self, params, h, targets, negatives):
-        """h [M, H] -> cross-entropy [M], log p of target and of negative
-        [M]; token blocks under a checkpoint, as ``looplm``'s."""
-        @jax.checkpoint
-        def block(args):
-            hb, yb, nb = args
-            lse, zy, zn = rowlm.head_logits(params["head"], hb, yb, nb)
-            return lse - zy, zy - lse, zn - lse
-
-        return rowlm.map_token_blocks(block, HEAD_BLOCK, h, targets,
-                                      negatives)
-
-    def loss(self, params, rows, lengths, valid, seq_keys):
-        x = rows[:, self.seq_key_slot]                        # [B, n, H]
-        ln = jnp.where(valid, lengths[:, self.seq_key_slot], 0)
-        b, n, hd = x.shape
-        targets, has_target, negatives = rowlm.next_token_plan(
-            seq_keys, ln, valid, n, self.key_base, self.vocab, self.neg_seed)
-        h, counts = x, []
-        # a checkpoint a half layer: the backward recomputes a mixer or a
-        # feed-forward, never both at once
-        for (mixer, ffn), w in zip(self.layers, params["layers"]):
-            h = self.mix(mixer, w, h, ln)
-            h, c = jax.checkpoint(
-                lambda w, h, ffn=ffn: self.feed_forward(ffn, w, h, ln))(w, h)
-            if c is not None:
-                counts.append(c)
-        h = rms_norm(h, params["gf"], self.eps)
-        ce, lp_pos, lp_neg = self.head_terms(
-            params, h.reshape(b * n, hd), targets.reshape(-1),
-            negatives.reshape(-1))
-        with trace.device_scope("tower.head_loss"):
-            wt = has_target.astype(jnp.float32)
-            count = jnp.sum(wt)
-            loss = jnp.sum(ce * wt) / jnp.maximum(count, 1.0)
-            tokens = rowlm.token_counts(ln, valid, n)
-            zero = jnp.zeros((), jnp.float32)
-            held = sum((c["held"] for c in counts), zero)
-            dropped = sum((c["dropped"] for c in counts), zero)
-            aux = {
-                **rowlm.auc_pairs(lp_pos, lp_neg, has_target, self.vocab),
-                "stats": jnp.concatenate(
-                    [jnp.stack([count, tokens, b * n - tokens, held,
-                                dropped])]
-                    + [c["load"] for c in counts]),
-            }
-        return loss, jax.lax.stop_gradient(aux)
-
-    def record_stats(self, total, steps: int) -> None:
-        """Counters of a pass: ``total`` is ``stats`` summed over its
-        ``steps`` steps (the trainer reads it back once a pass)."""
-        _, valid, padded, held, dropped = (
-            float(v) for v in total[:len(STATS)])
-        rowlm.record_padding(valid, padded)
-        if not self.moe_layers:
-            return
-        load = [float(v) for v in total[len(STATS):]]
-        stat_add("tower.moe.assignments_held", held)
-        stat_add("tower.moe.assignments", valid * self.top_k
-                 * self.moe_layers)
-        stat_add("tower.moe.dropped_assignments", dropped)
-        # tokens an expert of this chip received over the pass, the
-        # busiest (layer, expert) and the mean
-        stat_add("tower.moe.expert_load_max", max(load))
-        stat_add("tower.moe.expert_load_mean", sum(load) / len(load))

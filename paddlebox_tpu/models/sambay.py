@@ -73,7 +73,9 @@ whole square: 53% at 16 groups, where the causal half is 50%).  A block
 holds the queries of ``ATTN_KV_GROUPS`` kv groups (4 softmax maps each),
 not of all ten: its [maps, block, keys] float32 scores then fit the
 chip's fast memory, and the same blocks run 15-18x faster than with all
-forty maps in one (PERF.md section 6).
+forty maps in one (PERF.md section 6).  The blocking (``causal_blocks``,
+``sliding_blocks``) is handed what a block computes, so ``afmoe.py``'s
+grouped-query heads run through the same code.
 
 A layer passes on more than the residual stream (the memory, the shared
 KV), so the half layers are a Python loop, a mixer one sequence at a time
@@ -168,29 +170,116 @@ def _diff_block(qb, k, v, keep, lam, scale):
 
 
 def _query_blocks(q, blk: int, gb: int):
-    """q [G, 2, 2, n, d] -> [n / blk, G / gb, gb, 2, 2, blk, d]: blocks of
-    ``blk`` queries of ``gb`` kv groups."""
-    g, n = q.shape[0], q.shape[3]
-    q = q.reshape(g // gb, gb, 2, 2, n // blk, blk, q.shape[-1])
-    return jnp.transpose(q, (4, 0, 1, 2, 3, 5, 6))
+    """q [G, *inner, n, d] -> [n / blk, G / gb, gb, *inner, blk, d]:
+    blocks of ``blk`` queries of ``gb`` kv groups (``inner``: the heads a
+    group holds, and a differential head's two sides)."""
+    g, n, d = q.shape[0], q.shape[-2], q.shape[-1]
+    r = q.ndim - 3
+    q = q.reshape((g // gb, gb) + q.shape[1:-2] + (n // blk, blk, d))
+    return jnp.transpose(q, (2 + r,) + tuple(range(2 + r)) + (3 + r, 4 + r))
 
 
 def _join_blocks(o):
-    """[n / blk, G / gb, gb, 2, blk, e] -> [G, 2, n, e]."""
-    nb, ng, gb, _, blk, e = o.shape
-    return jnp.transpose(o, (1, 2, 3, 0, 4, 5)).reshape(
-        ng * gb, 2, nb * blk, e)
+    """[n / blk, G / gb, gb, *inner, blk, e] -> [G, *inner, n, e]."""
+    nb, ng, gb = o.shape[:3]
+    blk, e = o.shape[-2:]
+    r = o.ndim - 5
+    o = jnp.transpose(o, (1, 2) + tuple(range(3, 3 + r)) + (0, 3 + r, 4 + r))
+    return o.reshape((ng * gb,) + o.shape[2:2 + r] + (nb * blk, e))
 
 
 def _map_blocks(block, q, los, gb: int):
     """``block((qb, lo, first group))`` over every (query block, run of
-    ``gb`` kv groups) of q [n / blk, G / gb, gb, 2, 2, blk, d], one
+    ``gb`` kv groups) of q [n / blk, G / gb, gb, *inner, blk, d], one
     after another."""
     nb, ng = q.shape[:2]
     out = jax.lax.map(block, (
         q.reshape((nb * ng,) + q.shape[2:]), jnp.repeat(los, ng),
         jnp.tile(jnp.arange(0, ng * gb, gb), nb)))
     return _join_blocks(out.reshape((nb, ng) + out.shape[1:]))
+
+
+def _pad_tokens(t, before: int, after: int):
+    """Pad the token axis (second to last) of t."""
+    return jnp.pad(t, ((0, 0),) * (t.ndim - 2) + ((before, after), (0, 0)))
+
+
+def causal_blocks(attend, q, k, v, length, *, qblock: int, groups: int,
+                  gb: int):
+    """Causal attention of one sequence over all its keys, in blocks:
+    ``attend(qb, kb, vb, keep)`` for each block of ``qblock`` queries of
+    ``gb`` kv groups (q [G, *inner, n, d]; k, v [G, ..., n, .], the token
+    axis second to last; keep [block, keys]), one after another, each
+    under a checkpoint.  The query blocks fall into ``groups`` groups, a
+    group's keys cut where the group ends: the keys ahead of a group are
+    skipped, the ones ahead of a block inside its group are masked.
+    Returns [G, *inner_out, n, e]."""
+    n = q.shape[-2]
+    blk = min(qblock, n)
+    pad = -n % blk
+    if pad:
+        q = _pad_tokens(q, 0, pad)
+    nb = (n + pad) // blk
+    per = -(-nb // min(groups, nb))                # blocks a group
+    out = []
+    for first in range(0, nb, per):
+        last = min(first + per, nb)
+        hi = min(last * blk, n)                    # keys this group meets
+        kpos = jnp.arange(hi)
+        k_g, v_g = k[..., :hi, :], v[..., :hi, :]
+
+        @jax.checkpoint
+        def block(args, k_g=k_g, v_g=v_g, kpos=kpos):
+            qb, lo, g0 = args
+            qpos = lo + jnp.arange(blk)
+            keep = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] < length)
+            return attend(
+                qb, jax.lax.dynamic_slice_in_dim(k_g, g0, gb, axis=0),
+                jax.lax.dynamic_slice_in_dim(v_g, g0, gb, axis=0), keep)
+
+        out.append(_map_blocks(
+            block, _query_blocks(q[..., first * blk:last * blk, :], blk, gb),
+            jnp.arange(first * blk, last * blk, blk), gb))
+    return jnp.concatenate(out, axis=-2)[..., :n, :]
+
+
+def sliding_blocks(attend, q, k, v, length, window: int, *, qblock: int,
+                   gb: int):
+    """The same over a sliding window: a query at t meets the keys
+    t - window < j <= t.  A block of ``qblock`` queries that starts at
+    ``lo`` slices keys lo - window .. lo + block - 1 out of the sequence
+    and multiplies with nothing else."""
+    n = q.shape[-2]
+    blk = min(qblock, n)
+    pad = -n % blk
+    if pad:
+        q = _pad_tokens(q, 0, pad)
+    # keys in front of the sequence and behind it, so that every block's
+    # slice lies inside
+    k, v = _pad_tokens(k, window, pad), _pad_tokens(v, window, pad)
+
+    def cut(t, g0, lo):          # kv groups g0.., keys lo - window..
+        return jax.lax.dynamic_slice(
+            t, (g0,) + (0,) * (t.ndim - 3) + (lo, 0),
+            (gb,) + t.shape[1:-2] + (window + blk, t.shape[-1]))
+
+    @jax.checkpoint
+    def block(args):
+        qb, lo, g0 = args
+        kb, vb = cut(k, g0, lo), cut(v, g0, lo)
+        qpos = lo + jnp.arange(blk)
+        kpos = lo - window + jnp.arange(window + blk)
+        keep = (kpos[None, :] <= qpos[:, None]) \
+            & (kpos[None, :] > qpos[:, None] - window) \
+            & (kpos[None, :] >= 0) & (kpos[None, :] < length)
+        return attend(qb, kb, vb, keep)
+
+    return _map_blocks(block, _query_blocks(q, blk, gb),
+                       jnp.arange(0, n + pad, blk), gb)[..., :n, :]
+
+
+def _kv_groups_a_block(g: int) -> int:
+    return ATTN_KV_GROUPS if g % ATTN_KV_GROUPS == 0 else g
 
 
 def diff_attention(q, k, v, length, lam):
@@ -201,72 +290,21 @@ def diff_attention(q, k, v, length, lam):
     checkpoint (a block's scores, [4 maps a group, block, keys] float32,
     then stay in the chip's fast memory); ``ATTN_GROUPS`` groups of query
     blocks, a group's keys cut where the group ends."""
-    g, n = q.shape[0], q.shape[3]
     scale = 1.0 / math.sqrt(q.shape[-1])
-    blk = min(ATTN_QBLOCK, n)
-    gb = ATTN_KV_GROUPS if g % ATTN_KV_GROUPS == 0 else g
-    pad = -n % blk
-    if pad:
-        q = jnp.pad(q, ((0, 0),) * 3 + ((0, pad), (0, 0)))
-    nb = (n + pad) // blk
-    per = -(-nb // min(ATTN_GROUPS, nb))           # blocks a group
-    out = []
-    for first in range(0, nb, per):
-        last = min(first + per, nb)
-        hi = min(last * blk, n)                    # keys this group meets
-        kpos = jnp.arange(hi)
-        k_g, v_g = k[:, :, :hi], v[:, :hi]
-
-        @jax.checkpoint
-        def block(args, k_g=k_g, v_g=v_g, kpos=kpos):
-            qb, lo, g0 = args
-            qpos = lo + jnp.arange(blk)
-            keep = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] < length)
-            return _diff_block(
-                qb, jax.lax.dynamic_slice_in_dim(k_g, g0, gb, axis=0),
-                jax.lax.dynamic_slice_in_dim(v_g, g0, gb, axis=0), keep, lam,
-                scale)
-
-        out.append(_map_blocks(
-            block, _query_blocks(q[:, :, :, first * blk:last * blk], blk, gb),
-            jnp.arange(first * blk, last * blk, blk), gb))
-    return jnp.concatenate(out, axis=2)[:, :, :n]
+    return causal_blocks(
+        lambda qb, kb, vb, keep: _diff_block(qb, kb, vb, keep, lam, scale),
+        q, k, v, length, qblock=ATTN_QBLOCK, groups=ATTN_GROUPS,
+        gb=_kv_groups_a_block(q.shape[0]))
 
 
 def window_attention(q, k, v, length, lam, window: int):
-    """The same over a sliding window: a query at t meets the keys
-    t - window < j <= t.  A block of ``SWA_QBLOCK`` queries that starts
-    at ``lo`` slices keys lo - window .. lo + block - 1 out of the
-    sequence and multiplies with nothing else."""
-    n = q.shape[3]
+    """The same over a sliding window (``sliding_blocks``, blocks of
+    ``SWA_QBLOCK`` queries)."""
     scale = 1.0 / math.sqrt(q.shape[-1])
-    blk = min(SWA_QBLOCK, n)
-    pad = -n % blk
-    if pad:
-        q = jnp.pad(q, ((0, 0),) * 3 + ((0, pad), (0, 0)))
-    # keys in front of the sequence and behind it, so that every block's
-    # slice lies inside
-    k = jnp.pad(k, ((0, 0), (0, 0), (window, pad), (0, 0)))
-    v = jnp.pad(v, ((0, 0), (window, pad), (0, 0)))
-
-    gb = ATTN_KV_GROUPS if q.shape[0] % ATTN_KV_GROUPS == 0 else q.shape[0]
-
-    @jax.checkpoint
-    def block(args):
-        qb, lo, g0 = args
-        kb = jax.lax.dynamic_slice(
-            k, (g0, 0, lo, 0), (gb, 2, window + blk, k.shape[-1]))
-        vb = jax.lax.dynamic_slice(
-            v, (g0, lo, 0), (gb, window + blk, v.shape[-1]))
-        qpos = lo + jnp.arange(blk)
-        kpos = lo - window + jnp.arange(window + blk)
-        keep = (kpos[None, :] <= qpos[:, None]) \
-            & (kpos[None, :] > qpos[:, None] - window) \
-            & (kpos[None, :] >= 0) & (kpos[None, :] < length)
-        return _diff_block(qb, kb, vb, keep, lam, scale)
-
-    return _map_blocks(block, _query_blocks(q, blk, gb),
-                       jnp.arange(0, n + pad, blk), gb)[:, :, :n]
+    return sliding_blocks(
+        lambda qb, kb, vb, keep: _diff_block(qb, kb, vb, keep, lam, scale),
+        q, k, v, length, window, qblock=SWA_QBLOCK,
+        gb=_kv_groups_a_block(q.shape[0]))
 
 
 def lambda_init(layer_id: int) -> float:

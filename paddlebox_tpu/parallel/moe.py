@@ -197,18 +197,20 @@ def route_top_k(x, router, bias, top_k: int, scale: float):
 
 
 def _block(plan, i, size: int, top_k: int):
-    """Block i of the sorted held assignments: its expert, the
-    assignments in it (ids into [T * k]), their tokens, and which of its
-    rows hold one (an expert's last block is filled up with rows that
-    hold none).  ``plan`` = (order, load, first_block)."""
-    order, load, first_block = plan
+    """Block i of the sorted held assignments: its expert, where it starts
+    in the sort, the assignments in it (ids into [T * k]), their tokens,
+    and which of its rows hold one (an expert's last block is filled up
+    with rows that hold none).  ``plan`` = (key, order, load,
+    first_block), ``order`` padded by a block, so that a block is one
+    slice of it."""
+    _, order, load, first_block = plan
     with trace.device_scope("dispatch"):
         e = jnp.sum(first_block <= i) - 1            # the block's expert
-        inside = (i - first_block[e]) * size + jnp.arange(size)
-        keep = inside < load[e]
+        inside = (i - first_block[e]) * size
+        keep = inside + jnp.arange(size) < load[e]
         at = jnp.cumsum(load)[e] - load[e] + inside  # place in the sort
-        rows = order[jnp.clip(at, 0, order.shape[0] - 1)]
-    return e, rows, rows // top_k, keep
+        rows = lax.dynamic_slice_in_dim(order, at, size)
+    return e, at, rows, rows // top_k, keep
 
 
 def _expert_rows(xs, wt, wg, wu, wd):
@@ -217,14 +219,60 @@ def _expert_rows(xs, wt, wg, wu, wd):
         return ((jax.nn.silu(xs @ wg) * (xs @ wu)) @ wd) * wt[:, None]
 
 
-def _block_inputs(x, weights, experts, plan, i, size, top_k):
-    e, rows, token, keep = _block(plan, i, size, top_k)
+def _sorted_weights(weights, plan, size: int):
+    """The routing weights [T * k] in the order of the sort, padded as
+    ``order`` is: a block's weights are then one slice."""
     with trace.device_scope("dispatch"):
-        xs = jnp.where(keep[:, None], x[token], 0.0)
-        wt = jnp.where(keep, weights[rows], 0.0)
+        _, w = lax.sort((plan[0], weights), num_keys=1, is_stable=True)
+        return jnp.concatenate([w, jnp.zeros((size,), w.dtype)])
+
+
+def _block_inputs(x, w_sorted, experts, plan, i, size, top_k):
+    """What block i needs besides its rows of x: its expert, place in the
+    sort, tokens and kept rows (``_block``), their routing weights, the
+    expert's weights, and whether the kept rows are the consecutive tokens
+    from ``token[0]`` on (as when every live position chose the expert)
+    with ``size`` positions from there inside x: such a block moves its
+    rows as one slice, any other one row by row (``_take`` / ``_put``)."""
+    e, at, _, token, keep = _block(plan, i, size, top_k)
+    with trace.device_scope("dispatch"):
+        wt = jnp.where(keep, lax.dynamic_slice_in_dim(w_sorted, at, size),
+                       0.0)
         w_e = tuple(lax.dynamic_index_in_dim(w, e, keepdims=False)
                     for w in experts)
-    return e, rows, token, keep, xs, wt, w_e
+        kept = jnp.sum(keep)
+        sliced = (token[jnp.maximum(kept - 1, 0)] - token[0] == kept - 1) \
+            & (token[0] + size <= x.shape[0])
+    return e, at, token, keep, wt, w_e, sliced
+
+
+def _take(a, token, keep, sliced: bool):
+    """The block's rows of ``a`` [T, H], 0 where a row holds no
+    assignment."""
+    with trace.device_scope("dispatch"):
+        rows = lax.dynamic_slice_in_dim(a, token[0], keep.shape[0]) \
+            if sliced else a[token]
+        return jnp.where(keep[:, None], rows, 0.0)
+
+
+def _put(a, token, keep, ys, sliced: bool):
+    """``a`` with the block's kept rows ``ys`` added at their tokens."""
+    with trace.device_scope("combine"):
+        ys = jnp.where(keep[:, None], ys, 0.0)
+        if not sliced:
+            return a.at[token].add(ys)
+        return lax.dynamic_update_slice_in_dim(
+            a, lax.dynamic_slice_in_dim(a, token[0], ys.shape[0]) + ys,
+            token[0], 0)
+
+
+def _by_block(block, sliced, size: int, n: int, carry):
+    """``block(True)(carry)`` where the block's rows are one slice of the
+    ``n`` positions, else ``block(False)(carry)``; a block of more rows
+    than there are positions is never one slice."""
+    if size > n:
+        return block(False)(carry)
+    return lax.cond(sliced, block(True), block(False), carry)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
@@ -233,20 +281,25 @@ def _expert_blocks(x, weights, wg, wu, wd, plan, size: int, top_k: int):
     ``size`` rows none of which holds two experts, multiplied block by
     block: as many blocks as the data fill (a ``while``, so the backward
     is written out below: the same blocks again, each recomputed and
-    differentiated alone, an expert's weight gradient added in place).
-    Returns the output [T, H] and the assignments the blocks took."""
-    blocks = plan[2][-1]
+    differentiated alone, an expert's weight gradient added in place, the
+    routing weights' gradient laid down in the order of the sort and put
+    back in the assignments' by one more sort).  Returns the output
+    [T, H] and the assignments the blocks took."""
+    w_sorted = _sorted_weights(weights, plan, size)
 
     def one(i, carry):
         out, taken = carry
-        _, _, token, keep, xs, wt, w_e = _block_inputs(
-            x, weights, (wg, wu, wd), plan, i, size, top_k)
-        ys = _expert_rows(xs, wt, *w_e)
-        with trace.device_scope("combine"):
-            out = out.at[token].add(jnp.where(keep[:, None], ys, 0.0))
+        _, _, token, keep, wt, w_e, sliced = _block_inputs(
+            x, w_sorted, (wg, wu, wd), plan, i, size, top_k)
+
+        def block(sliced):
+            return lambda out: _put(out, token, keep, _expert_rows(
+                _take(x, token, keep, sliced), wt, *w_e), sliced)
+
+        out = _by_block(block, sliced, size, x.shape[0], out)
         return out, taken + jnp.sum(keep).astype(jnp.float32)
 
-    return lax.fori_loop(0, blocks, one,
+    return lax.fori_loop(0, plan[3][-1], one,
                          (jnp.zeros_like(x), jnp.zeros((), jnp.float32)))
 
 
@@ -257,23 +310,37 @@ def _expert_blocks_fwd(x, weights, wg, wu, wd, plan, size, top_k):
 
 def _expert_blocks_bwd(size, top_k, saved, g):
     x, weights, wg, wu, wd, plan = saved
+    w_sorted = _sorted_weights(weights, plan, size)
 
     def one(i, grads):
         dx, dwt, dw = grads
-        e, rows, token, keep, xs, wt, w_e = _block_inputs(
-            x, weights, (wg, wu, wd), plan, i, size, top_k)
-        _, vjp = jax.vjp(_expert_rows, xs, wt, *w_e)
-        dxs, dwt_rows, *dw_e = vjp(jnp.where(keep[:, None], g[0][token], 0.0))
+        e, at, token, keep, wt, w_e, sliced = _block_inputs(
+            x, w_sorted, (wg, wu, wd), plan, i, size, top_k)
+
+        def block(sliced):
+            def f(dx):
+                _, vjp = jax.vjp(_expert_rows, _take(x, token, keep, sliced),
+                                 wt, *w_e)
+                dxs, dwt_rows, *dw_e = vjp(_take(g[0], token, keep, sliced))
+                return _put(dx, token, keep, dxs, sliced), dwt_rows, dw_e
+            return f
+
+        dx, dwt_rows, dw_e = _by_block(block, sliced, size, x.shape[0], dx)
         with trace.device_scope("combine"):
-            dx = dx.at[token].add(jnp.where(keep[:, None], dxs, 0.0))
-            dwt = dwt.at[rows].add(jnp.where(keep, dwt_rows, 0.0))
+            # a block's filler rows write 0 where the next expert's blocks,
+            # later in the loop, write their own
+            dwt = lax.dynamic_update_slice_in_dim(
+                dwt, jnp.where(keep, dwt_rows, 0.0), at, 0)
             dw = tuple(a.at[e].add(b) for a, b in zip(dw, dw_e))
         return dx, dwt, dw
 
     dx, dwt, dw = lax.fori_loop(
-        0, plan[2][-1], one,
-        (jnp.zeros_like(x), jnp.zeros_like(weights),
+        0, plan[3][-1], one,
+        (jnp.zeros_like(x), jnp.zeros_like(w_sorted),
          tuple(jnp.zeros_like(w) for w in (wg, wu, wd))))
+    with trace.device_scope("combine"):
+        n = weights.shape[0]
+        _, dwt = lax.sort((plan[1][:n], dwt[:n]), num_keys=1)
     return (dx, dwt, *dw, None)
 
 
@@ -290,7 +357,12 @@ def routed_experts(x, live, router, bias, experts, held, top_k: int,
     F, H]) are, in that order.  The step's assignments are sorted by
     expert; those of held experts are cut into blocks of ``EXPERT_BLOCK``
     rows, an expert's last block filled up so that no block holds two,
-    and each block is one expert's three plain products.  The blocks in
+    and each block is one expert's three plain products; a block whose
+    rows are consecutive tokens (every live position there chose its
+    expert: a router that herds makes most blocks so) reads and adds
+    them as one slice, any other block row by row; a block's routing
+    weights and their gradient are one slice of the sort's order
+    always.  The blocks in
     use are as many as the data fill (the mean need is ``k * n_held / E``
     assignments a position, the worst case ``min(k, n_held)``: a router
     may send every token here, and while a pass's rows are new it does):
@@ -305,26 +377,32 @@ def routed_experts(x, live, router, bias, experts, held, top_k: int,
     Returns the output [T, H] and counts: ``held`` assignments to held
     experts, ``dropped`` of them that no block took (``held`` less the
     rows the blocks counted as they ran: 0), ``load`` [n_held] tokens
-    each expert received."""
-    n_held = len(held)
+    each expert received, ``route`` [E] the dispatched positions that
+    chose each expert of all of them (what a balancing bias reads)."""
+    n_held, n_all = len(held), router.shape[1]
     size = min(EXPERT_BLOCK, x.shape[0] * top_k)
     with trace.device_scope("router"):
         idx, w = route_top_k(x, router, bias, top_k, scale)
     with trace.device_scope("dispatch"):
         # a held expert's place in ``experts``; n_held: it lies elsewhere
-        local = jnp.full((router.shape[1],), n_held, jnp.int32).at[
+        local = jnp.full((n_all,), n_held, jnp.int32).at[
             jnp.asarray(held)].set(jnp.arange(n_held, dtype=jnp.int32))
         live = live & jnp.any(x != 0, axis=-1)
+        route = jnp.sum((idx[:, :, None] == jnp.arange(n_all))
+                        & live[:, None, None], axis=(0, 1)
+                        ).astype(jnp.float32)
         key = jnp.where(live[:, None], local[idx], n_held).reshape(-1)
-        order = jnp.argsort(key, stable=True)
+        order = jnp.concatenate([jnp.argsort(key, stable=True),
+                                 jnp.zeros((size,), jnp.int32)])
         load = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :], axis=0
                        ).astype(jnp.int32)
         # the first block of each expert, and past the last: all in use
         first_block = jnp.concatenate([
             jnp.zeros((1,), jnp.int32), jnp.cumsum(-(-load // size))])
     out, taken = _expert_blocks(x, w.reshape(-1), *experts,
-                                (order, load, first_block), size, top_k)
+                                (key, order, load, first_block), size,
+                                top_k)
     held_n = jnp.sum(load).astype(jnp.float32)
     counts = {"held": held_n, "dropped": held_n - taken,
-              "load": load.astype(jnp.float32)}
+              "load": load.astype(jnp.float32), "route": route}
     return out, counts

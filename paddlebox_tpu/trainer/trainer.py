@@ -410,6 +410,10 @@ class SparseTrainer:
         model = self.model
         dense_tx = self.dense_tx
         tied = self._head_keys is not None
+        # state of the model that takes no gradient and moves by a rule of
+        # its own after Adam (a routing bias balanced by the step's counts:
+        # ARCHITECTURE.md, the after-update hook)
+        after_update = getattr(model, "after_update", None)
 
         def half(params, opt_state, auc_state, rows, lengths, valid,
                  extras, head=None):
@@ -429,6 +433,8 @@ class SparseTrainer:
                 updates, opt_state = dense_tx.update(d_params, opt_state,
                                                      params)
                 params = optax.apply_updates(params, updates)
+            if after_update is not None:
+                params = after_update(params, aux)
             with trace.device_scope("metrics.auc"):
                 auc_state = accumulate_auc(auc_state, aux["auc_pred"],
                                            aux["auc_label"], aux["auc_mask"])

@@ -307,9 +307,11 @@ DEVICE_SCOPES = (
     # a row model's step (trainer._make_core): they hold the ps.* scopes
     "seq.pull", "seq.head_pull", "seq.push", "seq.head_push",
     # the row models' towers (models/looplm.py, hybridlm.py, sambay.py,
-    # rowlm.py) and what parallel/moe.py names inside tower.moe
+    # afmoe.py, rowlm.py; tower.moe_balance: the routing bias's update
+    # after Adam) and what parallel/moe.py names inside tower.moe
     "tower.ut", "tower.head_loss",
     "tower.kda", "tower.mla", "tower.moe", "tower.ffn_dense",
+    "tower.moe_balance",
     "tower.mamba", "tower.swa", "tower.attn_full", "tower.attn_cross",
     "tower.gmu", "tower.mlp",
     "router", "dispatch", "experts", "combine", "shared_expert",

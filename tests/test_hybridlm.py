@@ -240,6 +240,33 @@ def test_a_router_that_herds_the_tokens(bias, first_column, held):
     assert err(got[1], want[1]) <= 1e-4
 
 
+@pytest.mark.parametrize("chose,sliced", [
+    (range(40), [True, True, False]),   # the last block would pass the end
+    (range(0, 40, 2), [False, False]),  # every other token
+    (range(3, 19), [True])])            # one run, started anywhere
+def test_a_block_of_consecutive_tokens_moves_as_one_slice(chose, sliced):
+    """The tokens ``chose`` picked held expert 1 first, every other choice
+    lies elsewhere: a block (16 rows) of consecutive tokens that fits
+    inside the 40 positions from its first on is moved as one slice, any
+    other row by row (both paths against the reference:
+    ``test_a_router_that_herds_the_tokens``)."""
+    tokens, size, chose = 40, 16, list(chose)
+    key = np.full((tokens, 2), 2)
+    key[chose, 0] = 1
+    key = jnp.asarray(key.reshape(-1))
+    load = jnp.asarray([0, len(chose)], jnp.int32)
+    order = jnp.concatenate([jnp.argsort(key, stable=True),
+                             jnp.zeros((size,), jnp.int32)])
+    plan = (key, order, load, jnp.concatenate([
+        jnp.zeros((1,), jnp.int32), jnp.cumsum(-(-load // size))]))
+    x = jnp.ones((tokens, HIDDEN), jnp.float32)
+    experts = tuple(jnp.zeros((2, HIDDEN, 3)) for _ in range(3))
+    got = [bool(moe._block_inputs(x, jnp.ones(2 * tokens + size), experts,
+                                  plan, i, size, 2)[-1])
+           for i in range(int(plan[3][-1]))]
+    assert got == sliced
+
+
 def test_positions_that_are_padding_or_all_zero_are_not_dispatched():
     """A zero input ties every score, and a top-k of ties names the
     lowest ids, the held experts: such positions are left out of the

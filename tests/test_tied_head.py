@@ -127,13 +127,15 @@ class Keep(SparseTrainer):
     ("ouro",
      "2c835e223e7422122882b221c4035bb8ae70be63071f23ccaca5d845287e0b71"),
     ("kimi",
-     "201d385ed7d8fff1d356f32161fb9245d825b63966c83b47e58ff19b2519c5f1")])
+     "dcc7f34962272b0ae7ad3280decf1dc32e713bd3a7cc131bb2ceadebb7389232")])
 def test_untied_row_models_step_text_is_the_parents(monkeypatch, name, sha):
     """The push took an input (``head=``) and the feed a plane
     (``head_rows``) that exist only where a model names head keys: the
     fixture-size train steps of the two row models that name none lower
     to the text they had before (sha256 of the StableHLO taken on
-    40f9bdb, the parent of the PR that added the tied head)."""
+    40f9bdb, the parent of the change that added the tied head; Kimi's
+    taken again once its routed layers moved a block of consecutive
+    tokens, and a block's routing weights, as one slice)."""
     cfg, model = looplm_fixture.config(), None
     if name == "kimi":
         cfg = hybridlm_fixture.config()
