@@ -13,10 +13,12 @@ device idles ~95% of the wall waiting on serial pull+pack.
 worker thread while the trainer runs the current pass:
 
     worker (pass N+1):  begin_feed_pass -> load_fn() [reader threads feed
-                        keys] -> end_feed_pass(async_build=True) [host
-                        bulk_pull on the engine's build thread] ->
-                        peek_next_mapper -> trainer.pack_pass_host
-                        [fans across the pack WorkPool] -> buffer.put
+                        keys] -> end_feed_pass(async_build=True) [key
+                        dedup + mapper here; host bulk_pull starts on the
+                        engine's build thread] -> peek_next_mapper ->
+                        trainer.pack_pass_host [fans across the pack
+                        WorkPool, beside the pull] -> wait_feed_pass_done
+                        [joins the pull] -> buffer.put
     main   (pass N+1):  next_pass(): buffer.get -> engine.begin_pass
                         [adopt + ws upload + stale-row refresh] ->
                         trainer.finish_pass_feed [H2D + plans] -> train
@@ -37,13 +39,22 @@ regardless of how many specs are queued.  The worker also gates each
 spec on the PREVIOUS pass's adoption, because the engine holds a single
 ``_next`` working-set slot (and a single pending feed-obs window).
 
+Pack beside pull: the engine builds the pass's key mapper from the
+deduplicated keys as soon as the dedup returns, so the pack (which needs
+only the mapper) runs while the build thread still pulls the table rows,
+and the wait is max(pull, pack) instead of their sum.  The worker joins
+the pull after the pack: a pass in the buffer has both its planes packed
+and its host rows pulled, and a failed pull fails that pass at
+:meth:`PassPrefetcher.next_pass`.  ``data.prefetch.pack_beside_pull``
+counts the passes whose pack began while the pull still ran.
+
 Bit-identity: the worker packs against ``engine.peek_next_mapper()`` —
-the mapper the upcoming ``begin_pass`` will adopt.  Key translation reads
-only the mapper's sorted key array, which adoption's stale-row refresh
-never mutates (it rewrites working-set VALUES for keys the previous pass
-wrote), so packing before adoption produces byte-identical planes to
-packing after — pinned by tests/test_pass_pipeline.py, including under
-fault injection.
+the mapper object the upcoming ``begin_pass`` will adopt.  Key
+translation reads only the mapper's sorted key array, which neither the
+pull nor adoption's stale-row refresh touches (they fill working-set
+VALUES), so packing before the pull ends produces byte-identical planes
+to packing after adoption — pinned by tests/test_pass_pipeline.py,
+including under fault injection.
 """
 
 from __future__ import annotations
@@ -180,11 +191,17 @@ class PassPrefetcher:
                     self.engine.begin_feed_pass()
                     dataset = spec.load_fn()
                     self.engine.end_feed_pass(async_build=True)
-                    # waits for the host working-set build (bulk_pull),
-                    # then packs against the mapper begin_pass will adopt
+                    # the mapper begin_pass will adopt exists once the
+                    # dedup returns: pack against it while the build
+                    # thread still pulls the host rows
                     mapper = self.engine.peek_next_mapper()
+                    if self.engine.feed_build_running():
+                        stat_add("data.prefetch.pack_beside_pull")
                     arrays = self.trainer.pack_pass_host(dataset,
                                                          mapper=mapper)
+                    # a pass is handed over packed AND pulled; a failed
+                    # pull raises here and fails this pass
+                    self.engine.wait_feed_pass_done()
                 stat_add("data.prefetch.passes")
                 flight.record("prefetch_pass_ready", tag=spec.tag,
                               records=arrays.num_real,

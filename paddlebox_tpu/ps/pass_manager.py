@@ -85,6 +85,8 @@ class BoxPSEngine:
         # working set builds in the background while the current one trains
         self._build_thread: Optional[threading.Thread] = None
         self._next: Optional[tuple] = None  # (mapper, n, host_rows, plan)
+        # the pending pass's mapper, there from the dedup on (_next[0])
+        self._next_mapper: Optional[embedding.PassKeyMapper] = None
         self._last_written: Optional[np.ndarray] = None
 
         # HBM tier: device-resident hot-row cache (ps/device_cache.py).
@@ -242,7 +244,9 @@ class BoxPSEngine:
             uniq = np.unique(allk)
             return uniq[uniq != 0]  # key 0 = reserved zero row
 
-    def _build_host(self, uniq: np.ndarray) -> tuple:
+    def _build_host(self, uniq: np.ndarray,
+                    mapper: Optional[embedding.PassKeyMapper] = None
+                    ) -> tuple:
         # the pass-build bulk pull is one of the two big wire transfers
         # per pass (with the end-pass delta push) — surface its wall time
         # in the monitor so the pipelined PS wire path's effect shows up
@@ -283,7 +287,9 @@ class BoxPSEngine:
             intervals.record("pull", t0, t1)
             stat_add("ps.engine.build_pull_s", t1 - t0)
             stat_add("ps.engine.build_pull_rows", float(pulled_n))
-        return embedding.PassKeyMapper(uniq), len(uniq), host_rows, plan
+        if mapper is None:
+            mapper = embedding.PassKeyMapper(uniq)
+        return mapper, len(uniq), host_rows, plan
 
     def _upload(self, host_rows) -> Dict[str, jnp.ndarray]:
         # The ws built here is the one contract every step path consumes
@@ -414,6 +420,12 @@ class BoxPSEngine:
             self.mapper, self.num_keys, self.ws = self._build(uniq)
             return
         assert self._build_thread is None, "previous async build not adopted"
+        # the pass's one mapper needs the dedup'd keys alone: made here, it
+        # lets the prefetcher pack while the pull runs (peek_next_mapper),
+        # and the build hands the SAME object to begin_pass, so the native
+        # hash the pack builds is the one adoption looks keys up in
+        mapper = embedding.PassKeyMapper(uniq)
+        self._next_mapper = mapper
 
         # host-only work in the thread (dedup'd table pull — the slow DRAM/
         # SSD part); the device upload happens in begin_pass on the MAIN
@@ -421,7 +433,7 @@ class BoxPSEngine:
         # deadlock single-stream runtimes
         def run():
             try:
-                self._next = self._build_host(uniq)
+                self._next = self._build_host(uniq, mapper)
             except BaseException as e:  # re-raised in begin_pass, not lost
                 self._build_error = e
 
@@ -447,18 +459,25 @@ class BoxPSEngine:
                 "async working-set build failed (end_feed_pass "
                 "background thread)") from err
 
+    def feed_build_running(self) -> bool:
+        """True while the async host build (the table pull) still runs."""
+        t = self._build_thread
+        return t is not None and t.is_alive()
+
     def peek_next_mapper(self) -> Optional[embedding.PassKeyMapper]:
         """The key mapper the NEXT begin_pass will adopt — available as
-        soon as the async host build finishes (this waits on it), WITHOUT
-        adopting the working set.  The pass prefetcher packs pass N+1's
-        feed against this on a background thread while pass N still
-        trains; key translation reads only the sorted key array, which
-        begin_pass's stale-row refresh never mutates (it rewrites working-
-        set VALUES), so the pre-adoption pack is bit-identical to packing
-        after adoption."""
-        self.wait_feed_pass_done()
-        if self._next is not None:
-            return self._next[0]
+        soon as ``end_feed_pass(async_build=True)`` has deduplicated the
+        keys, WITHOUT waiting on the host build or adopting the working
+        set (the current mapper when no async build is pending).  The pass
+        prefetcher packs pass N+1's feed against this on a background
+        thread while the table pull runs and pass N still trains, then
+        joins the pull (:meth:`wait_feed_pass_done`, which raises if it
+        failed).  Key translation reads only the sorted key array, which
+        neither the pull nor begin_pass's stale-row refresh touches (they
+        fill working-set VALUES), so the early pack is bit-identical to
+        packing after adoption."""
+        if self._build_thread is not None or self._next is not None:
+            return self._next_mapper
         return self.mapper
 
     # -- train pass ----------------------------------------------------------
@@ -471,7 +490,7 @@ class BoxPSEngine:
                 with trace.span("ps.engine.upload_ws", rows=self.num_keys):
                     self.ws = self._adopt(self.mapper, self.num_keys,
                                           host_rows, plan)
-                self._next = None
+                self._next = self._next_mapper = None
                 with trace.span("ps.engine.refresh_stale"):
                     self._refresh_stale_rows()
                 self._cache_fresh_keys = None
@@ -646,6 +665,7 @@ class BoxPSEngine:
         self._build_thread = None
         self._build_error = None
         self._next = None
+        self._next_mapper = None
         with self._agent_lock:
             self._agent_keys = []
         # pboxlint: disable-next=PB102 -- single-coordinator lifecycle flag

@@ -782,9 +782,11 @@ class SparseTrainer:
         """Host half of :meth:`build_pass_feed`: pack + translate the
         whole pass into SoA planes.  No device dispatch (unless the caller
         passes an ``on_plane`` stager) and no dependence on the ADOPTED
-        working set — with an explicit ``mapper`` (e.g.
+        working set, nor on any pulled row: it reads only the mapper's
+        sorted keys.  With an explicit ``mapper`` (e.g.
         ``engine.peek_next_mapper()``) the prefetcher runs this on a
-        background thread while the previous pass still trains."""
+        background thread while the engine's build thread still pulls
+        the pass's rows and the previous pass still trains."""
         from paddlebox_tpu.data import pass_feed as pf
         self._require_pv_for_rank(dataset)
         label = (self.packer.label_slots

@@ -8,6 +8,9 @@ change WALL CLOCK only — every plane, every loss, and the final table
 state are bit-identical to the serial single-threaded pass loop.
 """
 
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -26,7 +29,7 @@ from paddlebox_tpu.models.deepfm import DeepFM
 from paddlebox_tpu.ps.embedding import PassKeyMapper
 from paddlebox_tpu.ps.pass_manager import BoxPSEngine
 from paddlebox_tpu.trainer.trainer import SparseTrainer
-from paddlebox_tpu.utils.monitor import StatRegistry, stat_snapshot
+from paddlebox_tpu.utils.monitor import StatRegistry, stat_get, stat_snapshot
 
 S, CAP, D = 5, 3, 4
 
@@ -235,18 +238,30 @@ def _day_keys(cfg):
     return np.unique(np.concatenate(parts))
 
 
-def _run_days(prefetch: bool, table=None):
-    """2 days x 3 passes of real DeepFM training; serial pass loop or the
-    PassPrefetcher driving the same deterministic per-pass datasets."""
-    cfg = _simple_cfg()
+def _engine_trainer(table=None):
     eng = BoxPSEngine(EmbeddingTableConfig(
         embedding_dim=4, shard_num=4,
         sgd=SparseSGDConfig(mf_create_thresholds=0.0)), seed=0)
     if table is not None:
         eng.table = table
     model = DeepFM(num_slots=4, emb_width=3 + 4, dense_dim=3, hidden=(8,))
-    tr = SparseTrainer(eng, model, cfg, batch_size=B, seed=0,
+    tr = SparseTrainer(eng, model, _simple_cfg(), batch_size=B, seed=0,
                        sparse_path="fast")
+    return eng, tr
+
+
+def _run_days(prefetch: bool, table=None, wrap=None):
+    """2 days x 3 passes of real DeepFM training; serial pass loop or the
+    PassPrefetcher driving the same deterministic per-pass datasets.
+    ``wrap(eng, tr)`` may hook the pair before the first pass."""
+    eng, tr = _engine_trainer(table)
+    if wrap is not None:
+        wrap(eng, tr)
+    return _drive_days(eng, tr, prefetch)
+
+
+def _drive_days(eng, tr, prefetch: bool):
+    cfg = _simple_cfg()
     losses = []
     if not prefetch:
         for day in range(N_DAYS):
@@ -265,15 +280,7 @@ def _run_days(prefetch: bool, table=None):
 
     pre = PassPrefetcher(eng, tr)
     try:
-        for day in range(N_DAYS):
-            for p in range(N_PASSES):
-                def load(day=day, p=p):
-                    ds = _mk_ds(cfg, day, p)
-                    for b in ds.get_blocks():
-                        eng.add_keys(b.all_keys())
-                    return ds
-                pre.submit(load, tag=f"d{day}p{p}",
-                           date=f"2026080{day + 1}")
+        _submit_days(pre, eng)
         for _ in range(N_DAYS * N_PASSES):
             feed = pre.next_pass()
             losses.append(tr.train_pass(feed)["loss"])
@@ -281,6 +288,18 @@ def _run_days(prefetch: bool, table=None):
     finally:
         pre.close()
     return losses, eng, tr
+
+
+def _submit_days(pre, eng):
+    cfg = _simple_cfg()
+    for day in range(N_DAYS):
+        for p in range(N_PASSES):
+            def load(day=day, p=p):
+                ds = _mk_ds(cfg, day, p)
+                for b in ds.get_blocks():
+                    eng.add_keys(b.all_keys())
+                return ds
+            pre.submit(load, tag=f"d{day}p{p}", date=f"2026080{day + 1}")
 
 
 def _assert_runs_identical(a, b, keys):
@@ -459,6 +478,153 @@ def test_prefetch_failure_surfaces_at_next_pass():
         pre.submit(boom, tag="doomed")
         with pytest.raises(RuntimeError, match="prefetch failed"):
             pre.next_pass()
+
+
+# ---------------------------------------------------------------------------
+# Pack beside pull: the worker packs while the table pull still runs.
+# ---------------------------------------------------------------------------
+
+_PLANES = ("indices", "lengths", "dense", "labels", "valid")
+_HOLD_S = 20.0
+
+
+def _wait(event, what):
+    if not event.wait(_HOLD_S):
+        raise TimeoutError(what)
+
+
+class _HeldPullTable:
+    """A host table whose pulls off the main thread (the engine's build
+    thread) first call ``hold``: a test's hook to order the pull against
+    the pack.  Main-thread pulls (the stale-row refresh, the test's own
+    reads) go straight through."""
+
+    def __init__(self, inner, hold):
+        self._inner = inner
+        self._hold = hold
+
+    def bulk_pull(self, keys):
+        if threading.current_thread() is not threading.main_thread():
+            self._hold()
+        return self._inner.bulk_pull(keys)
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+
+def _hook_pack(tr, before=None, packs=None):
+    """Run ``before()`` as each pack begins; copy each pack's planes into
+    ``packs`` (a later pass may reuse their buffers)."""
+    orig = tr.pack_pass_host
+
+    def pack(dataset, mapper=None, **kw):
+        if before is not None:
+            before()
+        arrays = orig(dataset, mapper=mapper, **kw)
+        if packs is not None:
+            packs.append({f: np.array(getattr(arrays, f)) for f in _PLANES})
+        return arrays
+
+    tr.pack_pass_host = pack
+
+
+def test_pack_begins_before_the_pull_ends():
+    """Every pass's table pull waits until that pass's pack has begun, an
+    order in which packing after the pull would deadlock: the prefetched
+    days still pack the serial loop's planes byte for byte, train to the
+    same losses, params and table, and count each pass as packed beside
+    its pull."""
+    packing = threading.Event()
+
+    def pull_after_pack():
+        _wait(packing, "the pull waited for a pack that never began")
+        packing.clear()
+
+    serial_packs, pipe_packs = [], []
+
+    def gate(eng, tr):
+        eng.table = _HeldPullTable(eng.table, pull_after_pack)
+        _hook_pack(tr, before=packing.set, packs=pipe_packs)
+
+    StatRegistry.instance().reset()
+    pipe = _run_days(prefetch=True, wrap=gate)
+    beside = stat_get("data.prefetch.pack_beside_pull")
+    serial = _run_days(prefetch=False,
+                       wrap=lambda eng, tr: _hook_pack(tr, packs=serial_packs))
+    assert beside == N_DAYS * N_PASSES
+    assert len(serial_packs) == len(pipe_packs) == N_DAYS * N_PASSES
+    for i, (want, got) in enumerate(zip(serial_packs, pipe_packs)):
+        for f in _PLANES:
+            np.testing.assert_array_equal(want[f], got[f],
+                                          err_msg=f"pass {i} plane {f!r}")
+    _assert_runs_identical(serial, pipe, _day_keys(_simple_cfg()))
+
+
+def test_pull_failure_beside_the_pack_fails_that_pass():
+    """A pull that raises while its pass packs fails THAT pass at
+    next_pass, with the prefetch failure chained to the pull's error, and
+    the engine never adopts it."""
+    packing = threading.Event()
+
+    def pull_then_fail():
+        _wait(packing, "the pull waited for a pack that never began")
+        raise ConnectionError("table shard went away")
+
+    eng, tr = _engine_trainer()
+    eng.table = _HeldPullTable(eng.table, pull_then_fail)
+
+    def pack_while_the_pull_fails():
+        packing.set()
+        deadline = time.monotonic() + _HOLD_S
+        while eng.feed_build_running() and time.monotonic() < deadline:
+            time.sleep(0.005)
+
+    _hook_pack(tr, before=pack_while_the_pull_fails)
+    begun = []
+    begin_pass = eng.begin_pass
+    eng.begin_pass = lambda: (begun.append(1), begin_pass())
+    with PassPrefetcher(eng, tr) as pre:
+        _submit_days(pre, eng)
+        with pytest.raises(RuntimeError, match="prefetch failed") as ei:
+            pre.next_pass()
+    assert isinstance(ei.value.__cause__.__cause__, ConnectionError)
+    assert begun == [] and eng.pass_id == 0
+
+
+def test_abort_mid_pack_with_the_pull_in_flight():
+    """Crash teardown while the worker packs and the pull still runs:
+    abort joins both, leaves the engine at a clean pass boundary (no
+    pending build, mapper or working set), and the same engine and
+    trainer then re-drive both days bit-identically to the serial loop."""
+    aborting, pack_began = threading.Event(), threading.Event()
+
+    def pull_until_abort():
+        _wait(aborting, "the test never aborted")
+        time.sleep(0.05)        # still pulling while abort joins the worker
+
+    def pack_until_abort():
+        pack_began.set()
+        _wait(aborting, "the test never aborted")
+
+    eng, tr = _engine_trainer()
+    table = eng.table
+    eng.table = _HeldPullTable(table, pull_until_abort)
+    _hook_pack(tr, before=pack_until_abort)
+    pre = PassPrefetcher(eng, tr)
+    _submit_days(pre, eng)
+    _wait(pack_began, "the worker never began to pack")
+    assert eng.feed_build_running()
+    aborting.set()
+    pre.abort()
+    assert not eng.feed_build_running()
+    assert eng.peek_next_mapper() is None
+    assert eng.ws is None and eng.pass_id == 0
+
+    eng.table = table
+    del tr.pack_pass_host
+    _assert_runs_identical(_run_days(prefetch=False),
+                           _drive_days(eng, tr, prefetch=True),
+                           _day_keys(_simple_cfg()))
 
 
 # ---------------------------------------------------------------------------

@@ -596,6 +596,12 @@ class _StubEngine:
     def peek_next_mapper(self):
         return None
 
+    def feed_build_running(self):
+        return False
+
+    def wait_feed_pass_done(self):
+        pass
+
     def begin_pass(self):
         pass
 
