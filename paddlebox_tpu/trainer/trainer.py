@@ -970,9 +970,16 @@ class SparseTrainer:
 
         def step(ws, params, opt_state, auc_state, i, data, plans):
             with trace.device_scope("feed.slice"):
-                bt = slice_batch(data, i)
-                plan = plan_tuple(slice_batch(plans, i)) \
-                    if with_plans else None
+                # batch i of every pass plane, cut at the top behind one
+                # barrier: the pull reads the plan slices, so it waits for
+                # the dense slice too, and a whole-pass plane the compiler
+                # keeps in fast memory for that slice is handed back
+                # before the pull's crossing needs the room.  A plane the
+                # body never reads stays an argument; the compiled step
+                # drops its slice
+                bt, pl = jax.lax.optimization_barrier(
+                    slice_batch((data, plans), i))
+                plan = plan_tuple(pl) if with_plans else None
             extras = {k: bt[k] for k in bt
                       if k not in ("indices", "lengths", "dense", "labels",
                                    "valid")}

@@ -36,12 +36,14 @@ STEP_SCOPES = trace.DEVICE_SCOPES[:11]
 ROW_SCOPES = tuple(s for s in STEP_SCOPES
                    if s not in ("ps.pull.pool", "dense.tower")) + (
     "seq.pull", "seq.push", "tower.ut", "tower.head_loss")
-# sha256 of the packed step's StableHLO at these two geometries on
-# 6336e5a, the parent of the PR that added the scopes: a scope changes an
-# instruction's op_name and nothing the compile cache keys
+# sha256 of the packed step's StableHLO at these two geometries: a scope
+# changes an instruction's op_name and nothing the compile cache keys, so
+# this is the text of 6336e5a, the parent of the PR that added the scopes,
+# with batch i's slices since cut first behind one barrier
+# (test_step_slices.py; on 6336e5a: ac895e16... and 3b187b7a...)
 PARENT_TEXT = {
-    "deepfm": "ac895e16bb822cb3ef66dc6610d193843d4967ce61b9dab274685fd145ab2f92",
-    "sequence": "3b187b7a738db390640f9665132fcfa97003b14a09c5e36086711372e2542f77"}
+    "deepfm": "3e68499699cfb4f77feee0d606b966b46024f14633ac09ad7edb4eef8d27631e",
+    "sequence": "0c92faa29b581c9b7b5ffe37499809766c964a01d2f0b9d5b5a35527d713707d"}
 
 
 def feed_config(caps):

@@ -125,9 +125,9 @@ class Keep(SparseTrainer):
 
 @pytest.mark.parametrize("name,sha", [
     ("ouro",
-     "2c835e223e7422122882b221c4035bb8ae70be63071f23ccaca5d845287e0b71"),
+     "7bec2b2e97ec5ec36c91124b5b12a155e31687a1570892dc85eac7deb8019a52"),
     ("kimi",
-     "dcc7f34962272b0ae7ad3280decf1dc32e713bd3a7cc131bb2ceadebb7389232")])
+     "daea361e3249fa522d56678b4e1346be16d4b19957c50489e7efff9fed39627b")])
 def test_untied_row_models_step_text_is_the_parents(monkeypatch, name, sha):
     """The push took an input (``head=``) and the feed a plane
     (``head_rows``) that exist only where a model names head keys: the
@@ -135,7 +135,9 @@ def test_untied_row_models_step_text_is_the_parents(monkeypatch, name, sha):
     to the text they had before (sha256 of the StableHLO taken on
     40f9bdb, the parent of the change that added the tied head; Kimi's
     taken again once its routed layers moved a block of consecutive
-    tokens, and a block's routing weights, as one slice)."""
+    tokens, and a block's routing weights, as one slice; both taken
+    again once the step cut batch i's slices of every pass plane first,
+    behind one barrier: test_step_slices.py)."""
     cfg, model = looplm_fixture.config(), None
     if name == "kimi":
         cfg = hybridlm_fixture.config()
